@@ -29,8 +29,6 @@ if _os.environ.get("HVD_LOCK_WITNESS", "").strip() in ("1", "true", "on"):
     _race.maybe_install_from_env()
 del _os
 
-from horovod_tpu.common import compat as _compat  # noqa: F401  (shims first)
-
 from horovod_tpu.common.basics import (  # noqa: F401
     init, shutdown, is_initialized, rank, local_rank, cross_rank, size,
     local_size, cross_size, process_index, process_count, is_homogeneous,

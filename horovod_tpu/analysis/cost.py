@@ -137,9 +137,8 @@ def _hier_for_event(event, config, num_slices, use_registry=True):
         return ""
     if use_registry:
         return _wire.cross_wire_for(event.ps, config)
-    return _wire.resolve_wire_dtype(
-        getattr(config, "wire_dtype_dcn", "")
-        or getattr(config, "wire_dtype", ""))
+    return (getattr(config, "wire_dtype_dcn", "")
+            or getattr(config, "wire_dtype", ""))
 
 
 def _a2a_hier_for_event(event, config, num_slices, use_registry=True):
@@ -171,8 +170,7 @@ def _a2a_hier_for_event(event, config, num_slices, use_registry=True):
         return ""
     if use_registry:
         return _wire.alltoall_cross_wire_for(event.ps, config)
-    return _wire.resolve_wire_dtype(
-        getattr(config, "alltoall_cross_dtype", ""))
+    return getattr(config, "alltoall_cross_dtype", "")
 
 
 def _event_legs(event, world_size, config, use_registry=True,
@@ -207,7 +205,7 @@ def _event_legs(event, world_size, config, use_registry=True,
         flat_len = event.per_rank_elems()
         cfg_wire = getattr(config, "wire_dtype", "")
         req = _wire.wire_dtype_for(event.ps, cfg_wire) if use_registry \
-            else _wire.resolve_wire_dtype(cfg_wire)
+            else cfg_wire
         all_float = all(_is_float_name(d) for d in dtypes)
         hier_cross = _hier_for_event(event, config, num_slices,
                                      use_registry)
@@ -509,9 +507,8 @@ def cost_report(report, *, config=None, num_slices=None,
             cross = ""
             if e.origin != "jit":
                 cross = _wire.cross_wire_for(e.ps, config) if use_registry \
-                    else _wire.resolve_wire_dtype(
-                        getattr(config, "wire_dtype_dcn", "")
-                        or getattr(config, "wire_dtype", ""))
+                    else (getattr(config, "wire_dtype_dcn", "")
+                          or getattr(config, "wire_dtype", ""))
             hh = _wire.hierarchical_wire_bytes(
                 e.per_rank_elems(), len(members), slices_spanned, width,
                 cross_wire=cross)
@@ -532,8 +529,8 @@ def cost_report(report, *, config=None, num_slices=None,
             cross = ""
             if all_float:
                 cross = _wire.alltoall_cross_wire_for(e.ps, config) \
-                    if use_registry else _wire.resolve_wire_dtype(
-                        getattr(config, "alltoall_cross_dtype", ""))
+                    if use_registry else getattr(
+                        config, "alltoall_cross_dtype", "")
             hh = _wire.hierarchical_a2a_bytes(
                 e.per_rank_elems(), len(members), slices_spanned, width,
                 cross_wire=cross)

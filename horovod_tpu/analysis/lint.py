@@ -86,7 +86,7 @@ _BOOTSTRAP_VARS = frozenset({
 # HVD_LOCK_* is the hvdrace runtime-witness namespace (HVD_LOCK_WITNESS,
 # HVD_LOCK_WITNESS_FILE): diagnostic instrumentation toggled per-process
 # by the person debugging, never launcher-propagated config.
-_HARNESS_PREFIXES = ("HVD_BENCH_", "HVD_SENTINEL_", "HVD_LOCK_")
+_HARNESS_PREFIXES = ("HVD_BENCH_", "HVD_LOCK_")
 
 # HVL001/HVL006 (lock-held blocking call / sleep) are retired: hvdrace's
 # HVR202 subsumes both with call-graph-aware hold propagation

@@ -436,7 +436,7 @@ class AutopilotController:
         a2a_cw = cats.get("a2a_cross_dtype")
         if a2a_cw is not None and self._a2a_qcross_armed is None:
             cur = _wire.alltoall_cross_wire_for("global", self._config)
-            if cur != _wire.resolve_wire_dtype(a2a_cw):
+            if cur != a2a_cw:
                 _wire.runtime_sync_alltoall_cross_dtype(a2a_cw, "global")
                 changed = True
         if changed:

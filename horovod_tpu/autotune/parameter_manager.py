@@ -59,20 +59,19 @@ def sweep_categoricals(current_strategy, config_wire_dtype, has_slices,
         ("torus_qcross",) if has_slices else ())
     cats = {"strategy": [current_strategy] + [
         s for s in choices if s != current_strategy]}
-    resolved = _wire.resolve_wire_dtype(config_wire_dtype)
-    if _wire.is_quantized(resolved):
-        first = jnp.dtype(_wire.wire_numpy_type(resolved)).name
+    if _wire.is_quantized(config_wire_dtype):
+        first = jnp.dtype(_wire.wire_numpy_type(config_wire_dtype)).name
         cats["wire_dtype"] = [first, "bfloat16", "float16"]
-    elif resolved:
+    elif config_wire_dtype:
         cats["wire_dtype"] = [
-            resolved, "bfloat16" if resolved == "float16" else "float16"]
+            config_wire_dtype,
+            "bfloat16" if config_wire_dtype == "float16" else "float16"]
     if a2a_strategy and has_slices:
         cats["a2a_strategy"] = [a2a_strategy] + [
             s for s in ("flat", "hier", "hier_qcross")
             if s != a2a_strategy]
-        resolved_a2a = _wire.resolve_wire_dtype(a2a_cross_dtype)
-        if _wire.is_quantized(resolved_a2a):
-            cats["a2a_cross_dtype"] = [resolved_a2a, ""]
+        if _wire.is_quantized(a2a_cross_dtype):
+            cats["a2a_cross_dtype"] = [a2a_cross_dtype, ""]
     return cats
 
 

@@ -42,6 +42,13 @@ def _env_float(name, default):
         return default
 
 
+# <checkout>/.horovod_compile_cache, from the package location: the cache
+# key includes its path, so it must not depend on cwd, pid or time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".horovod_compile_cache")
+
+
 @dataclasses.dataclass
 class Config:
     # --- fusion / cycle (reference common.h:119-121, operations.cc:515,551) ---
@@ -205,11 +212,12 @@ class Config:
     # environment — the default-on donate_buffers above covers only the
     # fusion runtime's host-staged buckets, which alias nothing.
     donate_eager: bool = False
-    # Persistent XLA compilation cache directory (HOROVOD_COMPILE_CACHE_DIR;
-    # "" = off). Wired to jax's compilation cache in basics.init so elastic
-    # re-rendezvous and repeat launches skip recompiles — recovery time is
-    # a perf metric too. See docs/performance.md.
-    compile_cache_dir: str = ""
+    # Persistent XLA compilation cache directory: JAX_COMPILATION_CACHE_DIR
+    # when set (jax reads it itself; basics.init then sets no other), else
+    # HOROVOD_COMPILE_CACHE_DIR, else the fixed checkout path above. Armed
+    # in basics.init so repeat launches and elastic re-rendezvous skip
+    # recompiles. See docs/performance.md.
+    compile_cache_dir: str = DEFAULT_COMPILE_CACHE_DIR
 
     # --- hierarchical control plane (common/control_plane.py) ---
     # flat | hier | "" = auto (hier whenever the slice layout has >1
@@ -339,9 +347,6 @@ class Config:
     # --- Pallas flash-attention kernels (ops/pallas/flash_attention.py) ---
     # Tile-size cap for on-chip sweeps (0 = auto).
     flash_block: int = 0
-    # Re-enable the kernels on non-multiple-of-block shapes (padded
-    # path; off pending silicon sentinel evidence — ROADMAP item 4).
-    flash_allow_padded: bool = False
 
     # --- static cost model (horovod_tpu/analysis/cost.py) ---
     # Per-step DCN byte budget for the static link-tier cost model: when
@@ -351,7 +356,7 @@ class Config:
     dcn_bytes_budget: int = 0
 
     # --- bench/progress plumbing (bench.py, chaos/soak.py) ---
-    # JSONL progress stream consumed by the evidence sentinel ("" = off).
+    # JSONL progress stream, one record per phase mark ("" = off).
     bench_progress_file: str = ""
 
     # --- serving (horovod_tpu/serving; docs/inference.md) ---
@@ -597,8 +602,10 @@ class Config:
         # Eager-path donation only on an EXPLICIT opt-in (see field docs).
         c.donate_eager = "HOROVOD_DONATE_BUFFERS" in os.environ \
             and c.donate_buffers
-        c.compile_cache_dir = os.environ.get("HOROVOD_COMPILE_CACHE_DIR",
-                                             c.compile_cache_dir)
+        c.compile_cache_dir = (
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.environ.get("HOROVOD_COMPILE_CACHE_DIR")
+            or c.compile_cache_dir)
         c.kv_retries = _env_int("HOROVOD_KV_RETRIES", c.kv_retries)
         c.kv_retry_backoff_ms = _env_float("HOROVOD_KV_RETRY_BACKOFF_MS",
                                            c.kv_retry_backoff_ms)
@@ -660,8 +667,6 @@ class Config:
         c.peak_ici_gbs = _env_float("HOROVOD_PEAK_ICI_GBS", c.peak_ici_gbs)
         c.peak_dcn_gbs = _env_float("HOROVOD_PEAK_DCN_GBS", c.peak_dcn_gbs)
         c.flash_block = _env_int("HVD_FLASH_BLOCK", c.flash_block)
-        c.flash_allow_padded = _env_bool("HVD_FLASH_ALLOW_PADDED",
-                                         c.flash_allow_padded)
         c.dcn_bytes_budget = _env_int("HOROVOD_DCN_BYTES_BUDGET",
                                       c.dcn_bytes_budget)
         c.bench_progress_file = os.environ.get("HVD_BENCH_PROGRESS_FILE",

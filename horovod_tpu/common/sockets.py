@@ -1,9 +1,9 @@
-"""Data-plane socket abort: the compat-tier collective abort lever.
+"""Data-plane socket abort: the collective abort lever of the CPU tier.
 
 Reference semantics: on a membership change Horovod ABORTS in-flight gloo
 collectives on every worker (the WorkerNotificationService push flips the
 shutdown flag and the gloo context's pairs are closed, making blocked
-send/recv calls raise instead of waiting out their timeout). jaxlib 0.4.x
+send/recv calls raise instead of waiting out their timeout). jaxlib
 exposes no abort on its gloo CPU collectives — ``make_gloo_tcp_collectives``
 takes no timeout and XLA's collective thunks wait ~30 minutes — so a worker
 blocked in an allreduce against a dead peer it is not directly connected to
@@ -130,14 +130,6 @@ def control_plane_ports():
         tok = tok.strip()
         if tok.isdigit():
             ports.add(int(tok))
-    try:
-        # Historic compat coordinator ports: leaked jax-0.4.x clients hold
-        # live connections to leaked services on the ports of SUPERSEDED
-        # memberships — severing one fires its fatal callback.
-        from horovod_tpu.common import basics
-        ports.update(basics.compat_coordinator_ports())
-    except Exception:  # noqa: BLE001 — never block the abort
-        pass
     try:
         from horovod_tpu.metrics import server as _srv
         p = _srv.http_server_port()

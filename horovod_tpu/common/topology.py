@@ -66,13 +66,9 @@ def _sorted_devices(devices):
 
 
 def _slice_id(d):
-    """TPU slice id of a device in a multi-slice (DCN) job, else None.
-    jax renamed slice_index → partition_index; accept both."""
-    for attr in ("slice_index", "partition_index"):
-        v = getattr(d, attr, None)
-        if v is not None:
-            return v
-    return None
+    """TPU slice id of a device in a multi-slice (DCN) job, else None
+    (single-slice TPU devices and CPU devices carry no ``slice_index``)."""
+    return getattr(d, "slice_index", None)
 
 
 def forced_slices():

@@ -375,23 +375,13 @@ def run(func):
         if consumed_version is None:
             hvd_logging.info(
                 "host removed from membership; exiting cleanly")
-            # Last words: this process exits via os._exit/SystemExit below,
-            # where no atexit dump may ever run.
+            # Last words before SystemExit below.
             _flight.dump("membership_removed")
             # Orderly disconnect before dying: letting interpreter
             # finalization destroy the jax.distributed client (and, on a
             # coordinator, the service with peers still attached) can
             # fire the hardwired fatal callback on us or on survivors.
             basics.teardown_distributed()
-            if basics.elastic_compat_leaks():
-                # Leaked jax-0.4.x compat objects: interpreter
-                # finalization would run their destructors and race their
-                # polling threads (see runner/task.py _compat_exit) —
-                # die without finalizing.
-                import sys
-                sys.stdout.flush()
-                sys.stderr.flush()
-                os._exit(0)
             raise SystemExit(0)
         if os.environ.get("HOROVOD_ELASTIC") and \
                 basics._distributed_client_active():

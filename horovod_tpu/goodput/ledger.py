@@ -550,10 +550,10 @@ _shutdown_done = False
 def shutdown():
     """Final flush: last goodput summary (plus the serving variant when
     it saw traffic) into the journal, optional per-rank summary file,
-    run_end marker. Idempotent — jax-0.4.x compat elastic workers end in
-    ``os._exit`` (runner/task.py), where atexit never runs, so the clean
-    exit path calls this explicitly before ``hvd.shutdown()`` and the
-    atexit registration becomes a no-op fallback for everything else."""
+    run_end marker. Idempotent: launched workers call this explicitly
+    before ``hvd.shutdown()`` (runner/task.py, while the telemetry agent
+    can still contribute the cluster view) and the atexit registration
+    becomes a no-op fallback for everything else."""
     global _shutdown_done
     if not armed or _shutdown_done:
         return

@@ -21,7 +21,7 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
 - ``fusion_kv_rpcs_total{kind}``                    boundary KV set/get (counter)
 - ``dispatch_plan_events_total{event}``             plan cache hit|miss (counter)
 - ``compile_cache_events_total{event}``             XLA persistent-cache
-  request|hit (counter; armed by ``HOROVOD_COMPILE_CACHE_DIR``)
+  request|hit (counter; armed by ``hvd.init()``)
 - ``negotiation_rounds_total``                      exchange() rounds (counter)
 - ``control_plane_rpcs_total{transport,kind}``      every KV RPC (counter)
 - ``control_plane_payload_bytes_total{transport}``  KV payload bytes (counter)
@@ -158,7 +158,7 @@ DISPATCH_PLAN_EVENTS = REGISTRY.counter(
 COMPILE_CACHE_EVENTS = REGISTRY.counter(
     "compile_cache_events_total",
     "JAX persistent-compilation-cache outcomes (event=request|hit). "
-    "Armed when HOROVOD_COMPILE_CACHE_DIR wires the cache up; "
+    "Armed by hvd.init(); "
     "request-minus-hit is the fresh-XLA-compile count.",
     ("event",))
 NEGOTIATION_ROUNDS = REGISTRY.counter(
@@ -451,7 +451,7 @@ def install_compile_cache_listener():
     """Mirror JAX's persistent-compilation-cache monitoring events into
     the registry, so cache effectiveness (and the zero-fresh-compiles
     restart guarantee) is assertable from metrics. Idempotent; installed
-    when ``HOROVOD_COMPILE_CACHE_DIR`` arms the cache (basics.init)."""
+    when ``basics.init`` arms the cache."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return

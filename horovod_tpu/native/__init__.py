@@ -1,9 +1,10 @@
 """ctypes loader for libhvdtpu, the native host-side runtime.
 
-Builds the shared library on first import when a toolchain is present
-(make + g++); everything degrades gracefully to the pure-Python paths when it
-isn't — mirroring how the reference gates features on what was compiled in
-(reference: horovod_*_built checks, operations.cc:1307-1449).
+Builds the shared library on first use when a toolchain is present
+(make + g++); when the build fails, one warning says so and everything runs
+on the pure-Python paths — mirroring how the reference gates features on
+what was compiled in (reference: horovod_*_built checks,
+operations.cc:1307-1449). ``native_built()`` says which runtime is in use.
 """
 
 import ctypes
@@ -60,8 +61,9 @@ def _build():
     except (subprocess.CalledProcessError, FileNotFoundError, OSError,
             subprocess.TimeoutExpired) as e:
         out = getattr(e, "stderr", b"") or b""
-        hvd_logging.debug("native build unavailable: %s %s", e,
-                          out.decode(errors="replace")[-500:])
+        hvd_logging.warning(
+            "native host runtime not built (%s %s); using the pure-Python "
+            "paths", e, out.decode(errors="replace")[-500:])
         try:
             os.unlink(os.path.join(_HERE, tmp))
         except OSError:

@@ -29,28 +29,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific bits are absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # finite big-negative: avoids inf-inf NaNs in the masking
-
-try:  # pre-VMA jax (< 0.7): ShapeDtypeStruct has no ``vma`` kwarg
-    jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-    _SDS_TAKES_VMA = True
-except TypeError:
-    _SDS_TAKES_VMA = False
-
-
-def _out_struct(shape, dtype, vma):
-    """ShapeDtypeStruct carrying the varying-manual-axes set when this jax
-    understands it. On pre-VMA jax the computed ``vma`` is always empty
-    (avals have no ``vma`` attribute), so omitting the kwarg is exact."""
-    if _SDS_TAKES_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
 
 
 def _interpret():
@@ -58,8 +39,9 @@ def _interpret():
 
 
 def _pick_block(length, cap=1024):
-    # 512-row tiles keep the MXU fed far better than 128 (measured on v5e:
-    # 32.1k -> 70.5k tok/s on GPT-2 @4k); 1024 overflows scoped VMEM.
+    # Large tiles keep the MXU fed; 1024 rows need the raised scoped-VMEM
+    # budget of _compiler_params (they compile and run at seq 1024 on a
+    # v5e, PR 21; tile sizes are not re-measured on current code).
     # HVD_FLASH_BLOCK caps the tile lower for on-chip sweeps (the MFU
     # tuning loop: sweep 128/256/512 per model without code edits).
     import os
@@ -72,9 +54,15 @@ def _pick_block(length, cap=1024):
     return None
 
 
+def _vma(*operands):
+    """How the operands vary over the mesh inside a VMA-checked shard_map;
+    the kernel outputs must declare the same."""
+    return frozenset().union(*(jax.typeof(t).vma for t in operands))
+
+
 def _scratch(shape):
     """VMEM scratch accumulator (persists across the sequential innermost
-    grid sweep on one core). Callers guard on ``pltpu is not None``."""
+    grid sweep on one core)."""
     return pltpu.VMEM(shape, jnp.float32)
 
 
@@ -82,7 +70,7 @@ def _compiler_params():
     """Raise mosaic's scoped-VMEM budget (default 16 MB) — the 512-row MXU
     tiles this kernel prefers need ~17-32 MB of stack at long context; v5e
     has far more physical VMEM than the default budget admits."""
-    if pltpu is None or _interpret():
+    if _interpret():
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
@@ -136,9 +124,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     # flow: jc is the literal 0, init/finalize run unconditionally, and
     # the masked trip count below is a compile-time constant. The generic
     # path's pl.when(contributes) + dynamically-clipped fori_loop is only
-    # ever needed when the chunk index is a real grid variable; on padded
-    # single-chunk grids it is the suspected Mosaic compile hang
-    # (docs/troubleshooting.md "Padded flash attention").
+    # ever needed when the chunk index is a real grid variable.
     single = n_kc == 1
     jc = 0 if single else pl.program_id(2)
 
@@ -260,12 +246,10 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
                                block_q=block_q, block_k=block_k,
                                k_chunk=k_chunk, q_offset=q_offset,
                                n_kc=n_kc, kv_valid=kv_valid, masked=masked)
-    # Inside a VMA-checked shard_map the outputs must declare how they vary
-    # over the mesh (they vary exactly like the operands).
-    vma = frozenset().union(*(getattr(jax.typeof(t), "vma", frozenset())
-                              for t in (q, k, v)))
+    vma = _vma(q, k, v)
     o, lse = pl.pallas_call(
         kernel,
+        name="hvd_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -277,8 +261,8 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((bh, lq, d), q.dtype, vma),
-            _out_struct((bh, lq, 1), jnp.float32, vma),
+            jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[_scratch((block_q, 1)), _scratch((block_q, 1)),
                         _scratch((block_q, d))],
@@ -497,15 +481,15 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
     q_blk = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     r_blk = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
     kc_swept = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, j, 0))
-    vma = frozenset().union(*(getattr(jax.typeof(t), "vma", frozenset())
-                              for t in (q, k, v, do)))
+    vma = _vma(q, k, v, do)
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, k_chunk=k_chunk, n_kc=n_kc,
                           **common),
+        name="hvd_flash_bwd_dq",
         grid=(bh, lq // block_q, n_kc),
         in_specs=[q_blk, kc_swept, kc_swept, q_blk, r_blk, r_blk],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_out_struct((bh, lq, d), q.dtype, vma),
+        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
         scratch_shapes=[_scratch((block_q, d))],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
@@ -517,12 +501,13 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, q_chunk=q_chunk, n_qc=n_qc,
                           **common),
+        name="hvd_flash_bwd_dkv",
         grid=(bh, lk // block_k, n_qc),
         in_specs=[qc_swept, k_blk, k_blk, qc_swept, rc_swept, rc_swept],
         out_specs=[pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))],
-        out_shape=[_out_struct((bh, lk, d), k.dtype, vma),
-                   _out_struct((bh, lk, d), v.dtype, vma)],
+        out_shape=[jax.ShapeDtypeStruct((bh, lk, d), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, lk, d), v.dtype, vma=vma)],
         scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
         compiler_params=_compiler_params(),
         interpret=_interpret(),
@@ -638,8 +623,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     arbitrary sequence lengths (e.g. ViT's 196 patches) run the kernels.
     Falls back to :func:`horovod_tpu.parallel.sequence.local_attention`
     (the correctness oracle, same end-aligned causal convention) only
-    where the kernels can't run at all (no pltpu; VMA-checked shard_map
-    under the interpreter).
+    where the kernels can't run at all (a VMA-checked shard_map under the
+    interpreter).
     """
     b, lq, h, d = q.shape
     lk, kv = k.shape[1], k.shape[2]
@@ -661,9 +646,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     # whose internal dynamic_slices the shard_map VMA checker rejects when
     # the operands are device-varying; the plain path is bit-compatible
     # there. On TPU the compiled kernel is opaque to the checker.
-    vma = frozenset().union(*(getattr(jax.typeof(t), "vma", frozenset())
-                              for t in (q, k, v)))
-    if pltpu is None or (_interpret() and vma):
+    if _interpret() and _vma(q, k, v):
         return plain_fallback()
 
     # Pad only genuinely unaligned lengths (e.g. ViT's 196): aligned ones
@@ -671,21 +654,6 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     pad_q = 0 if _pick_block(lq) else (-lq) % 128
     pad_k = 0 if _pick_block(lk) else (-lk) % 128
     lq_p, lk_p = lq + pad_q, lk + pad_k
-
-    # SAFETY GATE: the padded-kernel path once HUNG on real silicon (ViT
-    # 197->256, >20 min with no progress — undiagnosed; the kv_valid
-    # masking/padded-grid interaction under Mosaic is the prime suspect,
-    # see docs/troubleshooting.md "Padded flash attention"). Until it is
-    # validated on-chip, unaligned lengths on REAL TPU fall back to plain
-    # XLA attention; HVD_FLASH_ALLOW_PADDED=1 re-enables the kernels (the
-    # on-chip validation queue runs exactly that, bounded). Interpret mode
-    # (CPU tests) keeps the padded kernels — they are correct there and
-    # serve as the oracle. Reference analog: CUDA kernels are CI-exercised
-    # on hardware before they ship (horovod/common/ops/cuda/).
-    if (pad_q or pad_k) and not _interpret():
-        import os
-        if os.environ.get("HVD_FLASH_ALLOW_PADDED", "0") != "1":
-            return plain_fallback()
 
     def to3(t, pad):
         nh = t.shape[2]

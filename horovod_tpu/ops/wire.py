@@ -47,7 +47,6 @@ stale residuals.
 """
 
 import threading
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -64,44 +63,12 @@ FP8_MAX = 448.0
 QUANTIZED = ("int8", "fp8")
 
 
-def fp8_dtype():
-    """The fp8 wire element type, or None when this jax doesn't have it."""
-    return getattr(jnp, "float8_e4m3fn", None)
-
-
-_warned_fp8 = False
-
-
-def resolve_wire_dtype(name):
-    """Normalize a configured wire dtype string; ``fp8`` degrades to
-    ``bfloat16`` (one-time warning) when the dtype doesn't exist in this
-    jax build — a 16-bit cast wire is the graceful fallback that still
-    halves fp32 bytes."""
-    if not name:
-        return ""
-    if name == "fp8" and fp8_dtype() is None:
-        global _warned_fp8
-        if not _warned_fp8:
-            warnings.warn(
-                "wire_dtype=fp8 requested but this jax build has no "
-                "float8_e4m3fn — falling back to the bfloat16 cast wire",
-                stacklevel=2)
-            _warned_fp8 = True
-        return "bfloat16"
-    return name
-
-
 def quantized_label(dtype_like):
     """``"int8"``/``"fp8"`` when ``dtype_like`` (a wire string, numpy/jnp
-    dtype, or scalar type) names a quantized wire format, else None —
-    including ``"fp8"`` on a build without the dtype (the fallback there
-    is the bf16 CAST wire, which is not a quantized format; callers fall
-    back to their exact/cast path)."""
+    dtype, or scalar type) names a quantized wire format, else None."""
     if dtype_like is None or dtype_like == "":
         return None
     if isinstance(dtype_like, str) and dtype_like in QUANTIZED:
-        if dtype_like == "fp8":
-            return "fp8" if fp8_dtype() is not None else None
         return dtype_like
     try:
         name = jnp.dtype(dtype_like).name
@@ -119,15 +86,14 @@ def is_quantized(name):
 
 
 def wire_numpy_type(name):
-    """Numpy/jnp scalar type for a configured wire dtype string (after the
-    fp8 fallback), or None for the full-precision wire. This is what the
-    fusion runtime stores in ``wire_dtype`` (its bucket keys and boundary
-    payloads serialize it via ``jnp.dtype(...).name``)."""
-    name = resolve_wire_dtype(name)
+    """Numpy/jnp scalar type for a configured wire dtype string, or None
+    for the full-precision wire. This is what the fusion runtime stores in
+    ``wire_dtype`` (its bucket keys and boundary payloads serialize it via
+    ``jnp.dtype(...).name``)."""
     if not name:
         return None
     if name == "fp8":
-        return fp8_dtype()
+        return jnp.float8_e4m3fn
     return jnp.dtype(name).type
 
 
@@ -151,9 +117,8 @@ def symmetric_fp8_quantize(t):
     fp8's mantissa gives ~2 decimal digits but its exponent keeps relative
     error flat across each block's dynamic range — better than int8 on
     heavy-tailed gradient blocks, same 1 byte/element on the wire."""
-    f8 = fp8_dtype()
     scale = jnp.maximum(jnp.max(jnp.abs(t), axis=-1) / FP8_MAX, 1e-30)
-    q = (t / scale[..., None]).astype(f8)
+    q = (t / scale[..., None]).astype(jnp.float8_e4m3fn)
     return q, scale
 
 
@@ -347,7 +312,7 @@ def _normalize(dtype):
     if name not in _ACCEPTED:
         raise ValueError(
             f"wire dtype {dtype!r}: expected one of {_ACCEPTED}")
-    return resolve_wire_dtype(name)
+    return name
 
 
 def set_wire_dtype(dtype, ps_label="global", tier=None):
@@ -389,7 +354,7 @@ def wire_dtype_for(ps_label, default="", tier=None):
     config.wire_dtype`` for the DCN leg)."""
     with _wire_lock:
         v = _wire_registry.get(_registry_key(ps_label, tier))
-    return resolve_wire_dtype(default) if v is None else v[0]
+    return (default or "") if v is None else v[0]
 
 
 def cross_wire_for(ps_label, config):

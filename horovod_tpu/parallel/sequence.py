@@ -26,6 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from horovod_tpu.common import logging as hvd_logging
+
 SP_AXIS = "hvd"  # default: sequence parallelism over the global mesh axis
 
 
@@ -362,7 +364,12 @@ def ring_attention(q, k, v, axis_name=SP_AXIS, causal=False,
         fa = importlib.import_module(
             "horovod_tpu.ops.pallas.flash_attention")
         bq, bk = fa._pick_block(Lq), fa._pick_block(k.shape[1])
-        blocks = (bq, bk) if (bq and bk and fa.pltpu is not None) else None
+        blocks = (bq, bk) if (bq and bk) else None
+        if blocks is None and not fa._interpret():
+            hvd_logging.warning(
+                "ring_attention(use_flash=True): local lengths %d/%d have "
+                "no aligned block; the hops run the jnp block path, not "
+                "the Pallas kernels", Lq, k.shape[1])
         scale = 1.0 / np.sqrt(D)
 
         def to3(t):

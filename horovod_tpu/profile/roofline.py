@@ -38,26 +38,32 @@ PEAKS = {
 }
 
 
+# ``device_kind`` as jax reports it -> PEAKS key. "TPU v5 lite" is what a
+# v5e answers (observed, PR 21); the other spellings are jax's own
+# (jax/_src/mesh_utils.py).
+_TPU_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5e": "v5e",
+    "TPU v5p": "v5p",
+}
+
+
 def detect_chip():
-    """Best-effort chip generation from the local backend: one of the
-    PEAKS keys. Never raises (profiling must not fail the job)."""
+    """PEAKS key of the local backend. Any non-TPU platform is the CPU
+    tier; a TPU whose ``device_kind`` is not in the table raises — a chip
+    measured against another chip's (or the CPU placeholder's) peaks
+    would report a made-up utilization."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return "cpu"
     try:
-        import jax
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return "cpu"
-        kind = (getattr(dev, "device_kind", "") or "").lower()
-        if "v5p" in kind or "v5 p" in kind:
-            return "v5p"
-        if "v5e" in kind or "v5 lite" in kind or "v5litepod" in kind:
-            return "v5e"
-        if "v4" in kind:
-            return "v4"
-        if "v5" in kind:
-            return "v5e"
-        return "cpu"
-    except Exception:  # noqa: BLE001
-        return "cpu"
+        return _TPU_KINDS[dev.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {dev.device_kind!r}: add its peaks "
+            f"to horovod_tpu.profile.roofline.PEAKS/_TPU_KINDS") from None
 
 
 def chip_peaks(chip=None):
@@ -65,7 +71,7 @@ def chip_peaks(chip=None):
     applied. Returns a fresh dict: ``bf16_tflops``, ``hbm_gbs``,
     ``ici_gbs``, ``dcn_gbs``, ``chip`` (+ ``estimate`` on the CPU row)."""
     chip = chip or detect_chip()
-    peaks = dict(PEAKS.get(chip, PEAKS["cpu"]))
+    peaks = dict(PEAKS[chip])
     peaks["chip"] = chip
 
     def _ovr(env, key):

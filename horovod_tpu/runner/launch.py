@@ -164,10 +164,10 @@ def parse_args(argv=None):
     tuning.add_argument("--compile-cache-dir", dest="compile_cache_dir",
                         help="Persistent XLA compile-cache directory "
                              "exported to every worker "
-                             "(HOROVOD_COMPILE_CACHE_DIR). Elastic launches "
-                             "default it to <output-dir or cwd>/"
-                             ".horovod_compile_cache so re-rendezvoused "
-                             "workers skip XLA recompiles.")
+                             "(HOROVOD_COMPILE_CACHE_DIR; "
+                             "JAX_COMPILATION_CACHE_DIR wins when set). "
+                             "Default: <checkout>/.horovod_compile_cache "
+                             "on each host.")
 
     autotune = p.add_argument_group("autotune")
     autotune.add_argument("--autotune", action="store_true", dest="autotune")
@@ -470,19 +470,6 @@ def build_worker_env(base_env, slot_infos_for_host, coordinator_addr,
             str(p) for p in kv_shard_ports)
     if os.environ.get(SECRET_ENV):
         env[SECRET_ENV] = os.environ[SECRET_ENV]
-    # Persistent XLA compile cache: propagate the launcher's dir; elastic
-    # launches (whose whole point is fast recovery — every re-rendezvous
-    # otherwise recompiles every program from scratch) default it to a
-    # stable per-host path under the run's base dir. Workers on different
-    # hosts each keep a local cache at the same relative path.
-    cache_dir = os.environ.get("HOROVOD_COMPILE_CACHE_DIR") \
-        or getattr(args, "compile_cache_dir", None)
-    if not cache_dir and env.get("HOROVOD_ELASTIC"):
-        cache_dir = os.path.join(
-            getattr(args, "output_filename", None) or ".",
-            ".horovod_compile_cache")
-    if cache_dir:
-        env.setdefault("HOROVOD_COMPILE_CACHE_DIR", cache_dir)
     # Flight-recorder collection point: every worker dumps into the same
     # directory so a disruption leaves one analyzable set of per-rank
     # rings (flight.analyze merges them). Elastic launches default it —
@@ -529,7 +516,7 @@ def build_worker_env(base_env, slot_infos_for_host, coordinator_addr,
                 "HOROVOD_PROFILE_STRAGGLER_MIN_MS",
                 "HOROVOD_PEAK_TFLOPS", "HOROVOD_PEAK_HBM_GBS",
                 "HOROVOD_PEAK_ICI_GBS", "HOROVOD_PEAK_DCN_GBS",
-                "HVD_FLASH_BLOCK", "HVD_FLASH_ALLOW_PADDED",
+                "HVD_FLASH_BLOCK",
                 "HVD_BENCH_PROGRESS_FILE", "HOROVOD_DCN_BYTES_BUDGET",
                 "HOROVOD_WIRE_DTYPE", "HOROVOD_WIRE_ERROR_FEEDBACK",
                 "HOROVOD_WIRE_DTYPE_DCN", "HOROVOD_HIERARCHICAL_DISPATCH",
@@ -561,7 +548,11 @@ def build_worker_env(base_env, slot_infos_for_host, coordinator_addr,
                 "HOROVOD_GOODPUT_JOURNAL_S", "HOROVOD_RUN_HISTORY_DIR",
                 "HOROVOD_RUN_ID",
                 "HOROVOD_METRICS", "HOROVOD_METRICS_PORT",
-                "HOROVOD_METRICS_ADDR", "HOROVOD_METRICS_PREFIX"):
+                "HOROVOD_METRICS_ADDR", "HOROVOD_METRICS_PREFIX",
+                # An explicit compile-cache directory rides to every
+                # worker; with none, each uses the fixed path in its own
+                # checkout (common/config.py DEFAULT_COMPILE_CACHE_DIR).
+                "JAX_COMPILATION_CACHE_DIR", "HOROVOD_COMPILE_CACHE_DIR"):
         if os.environ.get(var):
             env.setdefault(var, os.environ[var])
     # On the virtual-CPU tier (tests, dry runs) a rank is a virtual XLA CPU

@@ -21,8 +21,8 @@ import os
 import sys
 
 # `python scripts/chaos_soak.py` puts scripts/ on sys.path, NOT the repo
-# root (same trap as scripts/evidence_sentinel.py) — and the spawned
-# workers re-import horovod_tpu too, so the repo must be on PYTHONPATH.
+# root — and the spawned workers re-import horovod_tpu too, so the repo
+# must be on PYTHONPATH.
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)

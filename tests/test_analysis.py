@@ -1447,7 +1447,7 @@ class TestLintRules:
     def test_declared_knobs_parse_config(self):
         assert "HOROVOD_FUSION_THRESHOLD" in _DECLARED
         assert "HOROVOD_LOG_LEVEL" in _DECLARED       # ISSUE 9 satellite
-        assert "HVD_FLASH_ALLOW_PADDED" in _DECLARED
+        assert "HVD_FLASH_BLOCK" in _DECLARED
         assert "HOROVOD_NOT_A_KNOB" not in _DECLARED
 
 

@@ -1212,7 +1212,7 @@ def _ring_attention_worker():
                            causal=True)
         return jax.lax.pmean(jnp.mean(o.astype(jnp.float32) ** 2), "sp")
 
-    # check_vma=False: the 0.4.x rep-checker can't infer replication
+    # check_vma=False: the VMA checker can't infer replication
     # through grad-of-ppermute chains (the gap dp.py documents). Without
     # the checker, the transpose of the replicated-w broadcast no longer
     # inserts its psum, so the grad is summed explicitly — the
@@ -1264,7 +1264,7 @@ def _sp_gpt_worker():
             jnp.sum(mask.astype(jnp.float32)), "sp")
 
     # check_vma=False: psum-normalized loss and grads ARE replicated, but
-    # the 0.4.x rep-checker can't infer it through the flash-ring's
+    # the VMA checker can't infer it through the flash-ring's
     # ppermute/psum chains (the dp.py gap); rank equality below is the
     # real check.
     val, grads = jax.jit(jax.shard_map(

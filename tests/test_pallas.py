@@ -231,32 +231,13 @@ class TestFlashAttention:
         with pytest.raises(ValueError, match="divide"):
             flash_attention(q, k, k)
 
-    def test_padded_gate_on_tpu_falls_back(self, rng, monkeypatch):
-        """On REAL TPU (simulated: _interpret -> False) unaligned lengths
-        must NOT enter the padded kernels until they are validated on
-        silicon (they hung once on-chip, ViT 197->256): the gate routes to
-        plain attention and the kernel entry point is never called."""
-        import importlib
-        fa = importlib.import_module(
-            "horovod_tpu.ops.pallas.flash_attention")
-        monkeypatch.setattr(fa, "_interpret", lambda: False)
-        monkeypatch.delenv("HVD_FLASH_ALLOW_PADDED", raising=False)
-
-        def boom(*a, **kw):
-            raise AssertionError("padded kernel entered despite the gate")
-        monkeypatch.setattr(fa, "_flash", boom)
-        from horovod_tpu.parallel.sequence import local_attention
-        q, k, v = _qkv(rng, L=100)  # no block divides 100 -> padded path
-        out = fa.flash_attention(q, k, v, causal=True)
-        ref = local_attention(q, k, v, causal=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-4, atol=2e-5)
-
-    def test_padded_gate_override_and_aligned_passthrough(self, rng,
-                                                          monkeypatch):
-        """HVD_FLASH_ALLOW_PADDED=1 re-opens the padded kernels (the
-        on-chip validation queue runs exactly that config), and ALIGNED
-        lengths never take the gate's fallback."""
+    @pytest.mark.parametrize("L", [100, 128])
+    def test_compiled_path_never_leaves_the_kernels(self, rng, monkeypatch,
+                                                    L):
+        """Off the interpreter (simulated: _interpret -> False) every
+        length, aligned or padded (197 -> 256 compiled and matched the
+        oracle on a v5e, PR 21), enters the kernels: nothing on that path
+        may give way to plain attention quietly."""
         import importlib
         fa = importlib.import_module(
             "horovod_tpu.ops.pallas.flash_attention")
@@ -268,14 +249,9 @@ class TestFlashAttention:
         def boom(*a, **kw):
             raise Entered
         monkeypatch.setattr(fa, "_flash", boom)
-        q, k, v = _qkv(rng, L=100)
-        monkeypatch.setenv("HVD_FLASH_ALLOW_PADDED", "1")
-        with pytest.raises(Entered):  # override: kernel path taken
-            fa.flash_attention(q, k, v)
-        monkeypatch.delenv("HVD_FLASH_ALLOW_PADDED")
-        q, k, v = _qkv(rng, L=128)
-        with pytest.raises(Entered):  # aligned: gate must not trigger
-            fa.flash_attention(q, k, v)
+        q, k, v = _qkv(rng, L=L)
+        with pytest.raises(Entered):
+            fa.flash_attention(q, k, v, causal=True)
 
     def test_tp_attention_flash_flag(self, hvd, rng):
         """TPSelfAttention(use_flash=True) == use_flash=False (same params)."""

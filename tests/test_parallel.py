@@ -192,14 +192,10 @@ class TestDcnMesh:
         class D1:
             slice_index = 3
 
-        class D2:
-            partition_index = 5
-
         class D3:
             pass
 
         assert _slice_id(D1()) == 3
-        assert _slice_id(D2()) == 5
         assert _slice_id(D3()) is None
 
 

@@ -64,13 +64,11 @@ class TestQuantizers:
         q, s = wire.symmetric_int8_quantize(jnp.zeros((2, wire.BLOCK)))
         assert np.asarray(wire.dequantize(q, s)).max() == 0.0
 
-    @pytest.mark.skipif(wire.fp8_dtype() is None,
-                        reason="no float8_e4m3fn in this jax")
     def test_fp8_roundtrip_relative_error(self):
         rng = np.random.default_rng(1)
         t = jnp.asarray(rng.standard_normal((2, wire.BLOCK)), jnp.float32)
         q, s = wire.symmetric_fp8_quantize(t)
-        assert q.dtype == wire.fp8_dtype()
+        assert q.dtype == jnp.float8_e4m3fn
         err = np.abs(np.asarray(wire.dequantize(q, s)) - np.asarray(t))
         # e4m3: 3 mantissa bits -> relative error <= 2^-4 per element
         # (plus the scale's own rounding), relative to the block max.
@@ -84,12 +82,9 @@ class TestQuantizers:
         assert wire.quantized_label("bfloat16") is None
         assert wire.quantized_label("") is None
         assert wire.quantized_label(None) is None
-        if wire.fp8_dtype() is not None:
-            assert wire.quantized_label("fp8") == "fp8"
-            assert wire.quantized_label(wire.fp8_dtype()) == "fp8"
-            assert wire.wire_numpy_type("fp8") is wire.fp8_dtype()
-        assert wire.resolve_wire_dtype("") == ""
-        assert wire.resolve_wire_dtype("bfloat16") == "bfloat16"
+        assert wire.quantized_label("fp8") == "fp8"
+        assert wire.quantized_label(jnp.float8_e4m3fn) == "fp8"
+        assert wire.wire_numpy_type("fp8") is jnp.float8_e4m3fn
         assert wire.wire_numpy_type("") is None
         assert jnp.dtype(wire.wire_numpy_type("int8")) == jnp.int8
 
@@ -133,8 +128,6 @@ class TestBlockScaledAllreduce:
 
     @pytest.mark.parametrize("fmt", ["int8", "fp8"])
     def test_matches_exact_psum_within_bound(self, hvd, fmt):
-        if fmt == "fp8" and wire.fp8_dtype() is None:
-            pytest.skip("no float8_e4m3fn in this jax")
         n = hvd.size()
         rng = np.random.default_rng(2)
         x = jnp.asarray(rng.standard_normal((n, 4096)), jnp.float32)
@@ -490,12 +483,9 @@ class TestReviewRegressions:
         finally:
             wire.reset_error_feedback()
 
-    def test_fp8_label_strict_on_dtype_availability(self):
-        if wire.fp8_dtype() is None:
-            assert wire.quantized_label("fp8") is None
-            assert not wire.is_quantized("fp8")
-        else:
-            assert wire.quantized_label("fp8") == "fp8"
+    def test_fp8_is_quantized(self):
+        assert wire.is_quantized("fp8")
+        assert not wire.is_quantized("bfloat16")
 
 
 class TestTuningBoundaryFlip:
