@@ -12,7 +12,9 @@ or the network. It is not a benchmark: the times it prints are information.
 
 Every phase raises on failure and nothing catches it, so any failure, and
 any platform other than ``tpu``, ends the process non-zero before a result
-line is printed. The last line of stdout on success is one JSON object.
+line is printed. On success the last line of stdout is exactly
+``{"ok": true, "device": {"platform", "kind", "count"}}``; the line before
+it is the run's summary (losses, compile seconds, cache hits, no claim).
 """
 
 import importlib.metadata
@@ -185,16 +187,17 @@ def main(per_chip=8, num_layers=12):
     req, hit = cache_events()
     say(f"compile_cache_events_total: request={req} hit={hit}")
     hvd.shutdown()
-    print(json.dumps({
-        "ok": True, "device": device, "size": n,
-        "per_chip_batch": per_chip, "seq": SEQ, "layers": num_layers,
-        "losses": [round(x, 6) for x in losses],
+    say("summary " + json.dumps({
+        "size": n, "per_chip_batch": per_chip, "seq": SEQ,
+        "layers": num_layers, "losses": [round(x, 6) for x in losses],
         "step_compile_s": round(compile_s, 2),
         "step_cache": {"request": step_cache[0], "hit": step_cache[1]},
         "cache": {"dir": jax.config.jax_compilation_cache_dir,
                   "request": req, "hit": hit},
         "step_ms_info": [round(t * 1e3, 1) for t in times],
-        "claim": None}), flush=True)
+        "claim": None}))
+    # The result line the chip check reads: these keys and no others.
+    print(json.dumps({"ok": True, "device": device}), flush=True)
 
 
 if __name__ == "__main__":
