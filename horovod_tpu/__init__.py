@@ -15,6 +15,10 @@ Typical use mirrors ``import horovod.torch as hvd``:
     g = in_jit.allreduce(g, axis_name='hvd')
 """
 
+import time as _time
+
+_import_t0 = _time.time()    # the ``import`` span: first statement to last
+
 from horovod_tpu.version import __version__  # noqa: F401
 
 # HVD_LOCK_WITNESS=1: swap threading.Lock/RLock for hvdrace's recording
@@ -85,3 +89,7 @@ from horovod_tpu.optim import (  # noqa: F401
     DistributedOptimizer, allreduce_gradients_transform, fused_allreduce_tree,
     distributed_value_and_grad, broadcast_parameters, broadcast_object_tree,
 )
+
+trace.add_span(trace.run_tid(), "import", _import_t0,
+               _time.time() - _import_t0)
+del _time, _import_t0

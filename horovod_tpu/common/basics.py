@@ -70,8 +70,23 @@ def init(comm=None, process_sets=None, devices=None):
     horovod/common/gloo/gloo_context.cc:160-230): the launcher exports
     ``HOROVOD_COORDINATOR_ADDR/PORT`` + ``HOROVOD_CROSS_RANK/CROSS_SIZE`` and we
     call ``jax.distributed.initialize`` here.
+
+    The whole of it is the ``init`` span of the ``run`` trace, with
+    children ``init.recorders`` (one child per recorder armed),
+    ``init.distributed``, ``init.compile_cache`` and ``init.topology``
+    (docs/observability.md).
     """
+    if _state is not None:
+        return
+    from horovod_tpu import trace as _trace
+    with _trace.run_span("init"):
+        _init(process_sets, devices)
+
+
+def _init(process_sets, devices):
     global _state
+    from horovod_tpu import trace as _trace
+    span = _trace.run_span
     with _lock:
         if _state is not None:
             return
@@ -87,122 +102,86 @@ def init(comm=None, process_sets=None, devices=None):
             from horovod_tpu.chaos import injector as _chaos_injector
             _chaos_injector.install_from_env()
 
-        # Flight recorder (always-armed crash forensics): configured
-        # before any dispatch so the ring covers init/rendezvous too.
-        # configure() never clears a live ring — elastic in-place
-        # re-init must keep the pre-failure events (they ARE the
-        # evidence a post-mortem needs).
-        from horovod_tpu.flight import recorder as _flight_recorder
-        _flight_recorder.configure(config)
+        # The recorders armed before the bootstrap; the rest follow once
+        # the topology is known (the second ``init.recorders`` below).
+        with span("init.recorders"):
+            # Flight recorder (always-armed crash forensics): configured
+            # before any dispatch so the ring covers init/rendezvous too.
+            # configure() never clears a live ring — elastic in-place
+            # re-init must keep the pre-failure events (they ARE the
+            # evidence a post-mortem needs).
+            with span("init.recorders.flight"):
+                from horovod_tpu.flight import recorder as _flight_recorder
+                _flight_recorder.configure(config)
 
-        # Step profiler: arm the per-step ledger/watchdog/capture knobs
-        # before any dispatch so attribution covers the first step.
-        # Completed records survive re-init (like the flight ring); only
-        # the open window resets (basics.shutdown).
-        from horovod_tpu.profile import ledger as _profile_ledger
-        _profile_ledger.configure(config)
+            # Step profiler: arm the per-step ledger/watchdog/capture
+            # knobs before any dispatch so attribution covers the first
+            # step. Completed records survive re-init (like the flight
+            # ring); only the open window resets (basics.shutdown).
+            with span("init.recorders.profile"):
+                from horovod_tpu.profile import ledger as _profile_ledger
+                _profile_ledger.configure(config)
 
-        # Request/step tracing + declared SLOs: armed next to the flight
-        # recorder (the trace store survives re-init for the same reason
-        # the ring does — a requeued request's spans ARE its history).
-        from horovod_tpu import trace as _trace
-        _trace.configure(config)
-        from horovod_tpu.telemetry import slo as _slo
-        _slo.configure(config)
+            # Request/step tracing + declared SLOs: armed next to the
+            # flight recorder (the trace store survives re-init for the
+            # same reason the ring does — a requeued request's spans ARE
+            # its history).
+            with span("init.recorders.trace_slo"):
+                _trace.configure(config)
+                from horovod_tpu.telemetry import slo as _slo
+                _slo.configure(config)
 
-        # Goodput accounting: start the wall clock before the distributed
-        # bootstrap so rendezvous + compile book to init_compile. The
-        # ledger survives elastic re-init (configure is start-once); the
-        # durable run journal arms after bootstrap, once the rank is
-        # known (rank 0 only).
-        from horovod_tpu.goodput import ledger as _goodput
-        _goodput.configure(config)
+            # Goodput accounting: start the wall clock before the
+            # distributed bootstrap so rendezvous + compile book to
+            # init_compile. The ledger survives elastic re-init
+            # (configure is start-once); the durable run journal arms
+            # after bootstrap, once the rank is known (rank 0 only).
+            with span("init.recorders.goodput"):
+                from horovod_tpu.goodput import ledger as _goodput
+                _goodput.configure(config)
 
-        # Decide on distributed bootstrap from the env alone: probing
-        # jax.process_count() here would initialize the local backend and
-        # forbid jax.distributed.initialize afterwards.
-        if config.coordinator_addr and config.cross_size > 1:
-            target = f"{config.coordinator_addr}:{config.coordinator_port}"
-            replace = False
-            if _distributed_client_active():
-                current = _current_coordinator()
-                if current == target:
-                    replace = False  # our cluster already bootstrapped
-                    # Reused service ⇒ its KV store may hold the previous
-                    # incarnation's last (un-GC'd) negotiation keys; move
-                    # every participant to a fresh epoch namespace.
-                    from horovod_tpu.common import negotiation
-                    negotiation.bump_epoch()
-                else:
-                    # A distributed client that doesn't belong to our
-                    # cluster (user code, an earlier membership): replace.
-                    hvd_logging.warning(
-                        "replacing pre-existing jax.distributed client "
-                        "(%s) with launcher coordinator %s", current, target)
-                    jax.distributed.shutdown()
-                    replace = True
-            else:
-                replace = True
-            if replace:
-                # Backends created before distributed bootstrap (user
-                # warmup, or a previous smaller world during elastic
-                # scale-up) would freeze a stale topology view; clear them
-                # so they rebuild with the cluster's global topology.
-                # Failures propagate: continuing with a stale backend is
-                # the exact wedge this block exists to prevent.
-                from jax._src import xla_bridge as _xb
-                if _xb.backends_are_initialized():
-                    hvd_logging.warning(
-                        "clearing pre-initialized XLA backends before "
-                        "distributed bootstrap")
-                    _clear_backends_and_program_caches()
-                if os.environ.get("HOROVOD_ELASTIC"):
-                    # Elastic membership: a peer dying must surface as a
-                    # recoverable collective error in survivors, not a
-                    # process-fatal coordination abort, and failure
-                    # detection should beat the default 100 s heartbeat
-                    # (reference: NCCL comms marked elastic abort instead
-                    # of hanging, nccl_operations.h:55).
-                    hb = int(os.environ.get(
-                        "HOROVOD_ELASTIC_HEARTBEAT_TIMEOUT", "10"))
-                    jax.config.update("jax_enable_recoverability", True)
-                    jax.distributed.initialize(
-                        coordinator_address=target,
-                        num_processes=config.cross_size,
-                        process_id=config.cross_rank,
-                        heartbeat_timeout_seconds=hb,
-                        shutdown_timeout_seconds=hb)
-                else:
-                    jax.distributed.initialize(
-                        coordinator_address=target,
-                        num_processes=config.cross_size,
-                        process_id=config.cross_rank)
-                # Fresh coordination service: empty KV store, epoch 0 for
-                # every participant (incl. replacement elastic workers).
-                from horovod_tpu.common import negotiation
-                negotiation.reset_epoch()
+        with span("init.distributed"):
+            _bootstrap_distributed(config)
 
         # Persistent XLA compile cache BEFORE the first compile, so every
         # compile this job performs (including the eager collective
         # programs) is eligible: elastic re-rendezvous and repeat launches
         # then skip XLA recompiles entirely (see docs/performance.md).
-        _setup_compile_cache(config.compile_cache_dir)
+        with span("init.compile_cache"):
+            _setup_compile_cache(config.compile_cache_dir)
 
-        topology = build_topology(devices)
-        _state = _State(topology, config)
+        with span("init.topology"):
+            topology = build_topology(devices)
+            _state = _State(topology, config)
 
-        from horovod_tpu.common import process_sets as ps
-        ps._init_table(_state, process_sets)
+            from horovod_tpu.common import process_sets as ps
+            ps._init_table(_state, process_sets)
 
-        if config.timeline_filename:
-            start_timeline(config.timeline_filename,  # hvdrace: disable=HVR202 -- one-shot native lib build at init, bounded by subprocess timeout=120 and cached by native._tried
+        with span("init.recorders"):
+            _arm_recorders(config, topology)  # hvdrace: disable=HVR202 -- start_timeline's one-shot native lib build at init, bounded by subprocess timeout=120 and cached by native._tried
+
+        hvd_logging.info(
+            "horovod_tpu initialized: size=%d local_size=%d cross_size=%d",
+            topology.size, topology.local_size, topology.cross_size)
+        atexit.register(shutdown)
+
+
+def _arm_recorders(config, topology):
+    """The recorders that need the topology: timeline, metrics endpoint,
+    telemetry plane, run-history journal, autopilot. One child span of
+    ``init.recorders`` each (the caller holds that span open)."""
+    from horovod_tpu.trace import run_span as span
+    if config.timeline_filename:
+        with span("init.recorders.timeline"):
+            start_timeline(config.timeline_filename,
                            mark_cycles=config.timeline_mark_cycles)
 
-        # Metrics: arm the always-on registry with this job's knobs and
-        # (optionally) the scrape endpoint. Offset by the LOCAL (per-host)
-        # process rank only — same-host processes must not fight over one
-        # bind, while every host keeps the same base port so a uniform
-        # scrape config works across the fleet.
+    # Metrics: arm the always-on registry with this job's knobs and
+    # (optionally) the scrape endpoint. Offset by the LOCAL (per-host)
+    # process rank only — same-host processes must not fight over one
+    # bind, while every host keeps the same base port so a uniform
+    # scrape config works across the fleet.
+    with span("init.recorders.metrics"):
         from horovod_tpu import metrics as hvd_metrics
         hvd_metrics.set_enabled(config.metrics)
         hvd_metrics.set_prefix(config.metrics_prefix)
@@ -221,22 +200,24 @@ def init(comm=None, process_sets=None, devices=None):
             except OSError as e:  # busy port must not kill training
                 hvd_logging.warning("metrics endpoint failed to bind: %s", e)
 
-        # Cluster telemetry plane: rank → slice-leader → job-view
-        # aggregation over the launcher HTTP-KV (horovod_tpu/telemetry).
-        # Armed after the topology is known (slice membership comes from
-        # it); no-ops on single-process or KV-less runs, where
-        # hvd.cluster_snapshot() serves the local-only view.
+    # Cluster telemetry plane: rank → slice-leader → job-view
+    # aggregation over the launcher HTTP-KV (horovod_tpu/telemetry).
+    # Armed after the topology is known (slice membership comes from
+    # it); no-ops on single-process or KV-less runs, where
+    # hvd.cluster_snapshot() serves the local-only view.
+    with span("init.recorders.telemetry"):
         try:
             from horovod_tpu.telemetry import aggregator as _telemetry
             _telemetry.start_from_config(config, topology)
         except Exception as e:  # noqa: BLE001 — telemetry must not block init
             hvd_logging.warning("telemetry plane failed to start: %s", e)
 
-        # Durable run-history journal (HOROVOD_RUN_HISTORY_DIR): armed on
-        # the coordinator rank once the world shape is known. Arm-once
-        # like the goodput ledger — an elastic re-init keeps appending to
-        # the same run's journal (a new coordinator after a rank-0 death
-        # opens its own).
+    # Durable run-history journal (HOROVOD_RUN_HISTORY_DIR): armed on
+    # the coordinator rank once the world shape is known. Arm-once
+    # like the goodput ledger — an elastic re-init keeps appending to
+    # the same run's journal (a new coordinator after a rank-0 death
+    # opens its own).
+    with span("init.recorders.run_history"):
         try:
             from horovod_tpu.goodput import history as _run_history
             if _run_history.get_journal() is None:
@@ -247,22 +228,86 @@ def init(comm=None, process_sets=None, devices=None):
         except Exception as e:  # noqa: BLE001 — must not block init
             hvd_logging.warning("run-history journal failed to arm: %s", e)
 
-        # Autopilot (HOROVOD_AUTOPILOT): the online controller closing the
-        # signal plane → knobs loop, coordinator rank only (followers
-        # adopt flips at flush boundaries). Armed AFTER telemetry so its
-        # first frame can already read the health plane. An elastic
-        # re-init restarts it under the new membership like the
-        # telemetry agent.
+    # Autopilot (HOROVOD_AUTOPILOT): the online controller closing the
+    # signal plane → knobs loop, coordinator rank only (followers
+    # adopt flips at flush boundaries). Armed AFTER telemetry so its
+    # first frame can already read the health plane. An elastic
+    # re-init restarts it under the new membership like the
+    # telemetry agent.
+    with span("init.recorders.autopilot"):
         try:
             from horovod_tpu.autopilot import controller as _autopilot
             _autopilot.start_from_config(config)
         except Exception as e:  # noqa: BLE001 — must not block init
             hvd_logging.warning("autopilot failed to start: %s", e)
 
-        hvd_logging.info(
-            "horovod_tpu initialized: size=%d local_size=%d cross_size=%d",
-            topology.size, topology.local_size, topology.cross_size)
-        atexit.register(shutdown)
+
+def _bootstrap_distributed(config):
+    """``jax.distributed`` bootstrap of a multi-host launch (the
+    ``init.distributed`` span); nothing to do in a single process."""
+    # Decide on distributed bootstrap from the env alone: probing
+    # jax.process_count() here would initialize the local backend and
+    # forbid jax.distributed.initialize afterwards.
+    if config.coordinator_addr and config.cross_size > 1:
+        target = f"{config.coordinator_addr}:{config.coordinator_port}"
+        replace = False
+        if _distributed_client_active():
+            current = _current_coordinator()
+            if current == target:
+                replace = False  # our cluster already bootstrapped
+                # Reused service ⇒ its KV store may hold the previous
+                # incarnation's last (un-GC'd) negotiation keys; move
+                # every participant to a fresh epoch namespace.
+                from horovod_tpu.common import negotiation
+                negotiation.bump_epoch()
+            else:
+                # A distributed client that doesn't belong to our
+                # cluster (user code, an earlier membership): replace.
+                hvd_logging.warning(
+                    "replacing pre-existing jax.distributed client "
+                    "(%s) with launcher coordinator %s", current, target)
+                jax.distributed.shutdown()
+                replace = True
+        else:
+            replace = True
+        if replace:
+            # Backends created before distributed bootstrap (user
+            # warmup, or a previous smaller world during elastic
+            # scale-up) would freeze a stale topology view; clear them
+            # so they rebuild with the cluster's global topology.
+            # Failures propagate: continuing with a stale backend is
+            # the exact wedge this block exists to prevent.
+            from jax._src import xla_bridge as _xb
+            if _xb.backends_are_initialized():
+                hvd_logging.warning(
+                    "clearing pre-initialized XLA backends before "
+                    "distributed bootstrap")
+                _clear_backends_and_program_caches()
+            if os.environ.get("HOROVOD_ELASTIC"):
+                # Elastic membership: a peer dying must surface as a
+                # recoverable collective error in survivors, not a
+                # process-fatal coordination abort, and failure
+                # detection should beat the default 100 s heartbeat
+                # (reference: NCCL comms marked elastic abort instead
+                # of hanging, nccl_operations.h:55).
+                hb = int(os.environ.get(
+                    "HOROVOD_ELASTIC_HEARTBEAT_TIMEOUT", "10"))
+                jax.config.update("jax_enable_recoverability", True)
+                jax.distributed.initialize(
+                    coordinator_address=target,
+                    num_processes=config.cross_size,
+                    process_id=config.cross_rank,
+                    heartbeat_timeout_seconds=hb,
+                    shutdown_timeout_seconds=hb)
+            else:
+                jax.distributed.initialize(
+                    coordinator_address=target,
+                    num_processes=config.cross_size,
+                    process_id=config.cross_rank)
+            # Fresh coordination service: empty KV store, epoch 0 for
+            # every participant (incl. replacement elastic workers).
+            from horovod_tpu.common import negotiation
+            negotiation.reset_epoch()
 
 
 def _setup_compile_cache(path):

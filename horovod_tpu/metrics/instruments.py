@@ -60,6 +60,11 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   1.0 = consuming the budget exactly, > 1 = burning; computed by
   horovod_tpu/telemetry/slo.py from the declared
   HOROVOD_SLO_TTFT_P99_MS / HOROVOD_SLO_TPS objectives)
+- ``hvd_fused_allreduce_buckets{axis_size}``        collectives one trace
+  of the in-jit ``fused_allreduce_tree`` issues (buckets plus leaves
+  reduced alone; gauge, set while the step is traced, not per step)
+- ``hvd_fused_allreduce_bytes{axis_size}``          bytes those carry a
+  step, padding included (gauge, as above)
 - ``autopilot_decisions_total{lever,outcome}``      autopilot control
   decisions (lever=tuner|overlap|cross_wire|remediate; counter)
 - ``autopilot_remediations_total{cause,outcome}``   autopilot-initiated
@@ -282,6 +287,17 @@ SERVING_FILL = REGISTRY.histogram(
     "continuous batch is full; persistently low fill under a deep queue "
     "means admission is starved — a scheduler bug).",
     buckets=_RATIO_BUCKETS)
+FUSED_ALLREDUCE_BUCKETS = REGISTRY.gauge(
+    "hvd_fused_allreduce_buckets",
+    "Collectives one trace of the in-jit fused_allreduce_tree issues "
+    "(fusion buckets plus leaves reduced alone), by the size of the "
+    "reduced axis. Set while the step is traced, not per step.",
+    ("axis_size",))
+FUSED_ALLREDUCE_BYTES = REGISTRY.gauge(
+    "hvd_fused_allreduce_bytes",
+    "Bytes the collectives of one fused_allreduce_tree carry a step "
+    "(wire dtype, padding included), by the size of the reduced axis.",
+    ("axis_size",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -620,6 +636,15 @@ def record_autopilot_remediation(cause, outcome):
     if not _enabled:
         return
     AUTOPILOT_REMEDIATIONS.labels(cause, outcome).inc()
+
+
+def record_fused_allreduce(axis_size, buckets, nbytes):
+    """What one trace of ``optim.fused_allreduce_tree`` makes: known while
+    the step is traced, so set there once and not per step."""
+    if not _enabled:
+        return
+    FUSED_ALLREDUCE_BUCKETS.labels(axis_size).set(buckets)
+    FUSED_ALLREDUCE_BYTES.labels(axis_size).set(nbytes)
 
 
 def record_telemetry_rpc(phase, n=1):

@@ -225,6 +225,20 @@ def _set_wire_tiers(process_set, wire_nbytes, sched):
 
 
 @contextlib.contextmanager
+def _op_span(tl, op_kind, name):
+    """One eager dispatch under its name: the profiler's annotation
+    ``hvd::<OP>::<name>``, so device profiles correlate with timeline
+    buckets by name (SURVEY §5.1: the reference's NVTX ranges around every
+    enqueue, nvtx_op_range.h), round the timeline's bucket where one is
+    open. Not written to the span store: the dispatch's own record there
+    carries its flight seq (``_timeline_op``)."""
+    with _trace.span(f"{op_kind}::{name}", store=False):
+        with tl.op_span(name, op_kind) if tl is not None \
+                else contextlib.nullcontext():
+            yield
+
+
+@contextlib.contextmanager
 def _timeline_op(name, op_kind, tensors=(), process_set=None,
                  op_label=None, ps_label=None, wire=None):
     """Timeline span + metrics + failure translation around one eager
@@ -298,17 +312,9 @@ def _timeline_op(name, op_kind, tensors=(), process_set=None,
         # is corroborated by op/sig in the analyzer.
         fl_seq = _flight.record_dispatch(op_label, ps_label, nbytes,
                                          _flight.signature(tensors), name)
-    tl = basics.timeline()
-    span = tl.op_span(name, op_kind) if tl is not None \
-        else contextlib.nullcontext()
     try:
-        # TraceAnnotation mirrors the span into jax.profiler XPlane traces,
-        # so device profiles correlate with timeline buckets by name
-        # (SURVEY §5.1: the reference's NVTX ranges around every enqueue,
-        # nvtx_op_range.h).
-        with jax.profiler.TraceAnnotation(f"hvd::{op_kind}::{name}"):
-            with span:
-                yield
+        with _op_span(basics.timeline(), op_kind, name):
+            yield
         if metrics_on or flight_on or profile_on:
             dur = time.perf_counter() - t0
         if metrics_on:
@@ -1156,11 +1162,8 @@ class _DispatchPlan:
             t0p = time.perf_counter()
         try:
             if tl is not None:
-                with jax.profiler.TraceAnnotation(
-                        f"hvd::{self.op_kind}::{name or self.default_name}"):
-                    with tl.op_span(name or self.default_name,
-                                    self.op_kind):
-                        outs = prog(*staged)
+                with _op_span(tl, self.op_kind, name or self.default_name):
+                    outs = prog(*staged)
             else:
                 outs = prog(*staged)
             if metrics_on:
@@ -1293,11 +1296,8 @@ class _WireDispatchPlan(_DispatchPlan):
         tl = basics.timeline()
         try:
             if tl is not None:
-                with jax.profiler.TraceAnnotation(
-                        f"hvd::{self.op_kind}::{name or self.default_name}"):
-                    with tl.op_span(name or self.default_name,
-                                    self.op_kind):
-                        outs = self.program(*args)
+                with _op_span(tl, self.op_kind, name or self.default_name):
+                    outs = self.program(*args)
             else:
                 outs = self.program(*args)
             if ef:

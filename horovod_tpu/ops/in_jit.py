@@ -16,6 +16,7 @@ ranks receive a well-defined value they are expected to ignore, mirroring how
 non-member processes simply don't call the op in the reference.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -44,9 +45,17 @@ def rank(axis_name=HVD_AXIS):
     return lax.axis_index(axis_name)
 
 
+def _wire():
+    """Device scope ``hvd.wire``: round the collective primitive alone, so
+    a profile finds the exchange by where the program put it, whatever the
+    compiler names the op (docs/observability.md)."""
+    return jax.named_scope("hvd.wire")
+
+
 def _gather_select(x, ranks, axis_name):
     """all_gather the full axis, select the process set's slices (static)."""
-    g = lax.all_gather(x, axis_name)  # (world, ...)
+    with _wire():
+        g = lax.all_gather(x, axis_name)  # (world, ...)
     return g[jnp.asarray(np.array(ranks))]  # (set_size, ...)
 
 
@@ -66,19 +75,24 @@ def allreduce(x, op=Average, axis_name=HVD_AXIS, process_set=None,
     if ranks is None:
         n = lax.axis_size(axis_name)
         if op in (Sum, Average):
-            y = lax.psum(x, axis_name)
+            with _wire():
+                y = lax.psum(x, axis_name)
             if op == Average:
                 y = y / jnp.asarray(n, y.dtype)
         elif op == Min:
-            y = lax.pmin(x, axis_name)
+            with _wire():
+                y = lax.pmin(x, axis_name)
         elif op == Max:
-            y = lax.pmax(x, axis_name)
+            with _wire():
+                y = lax.pmax(x, axis_name)
         elif op == Product:
-            g = lax.all_gather(x, axis_name)
+            with _wire():
+                g = lax.all_gather(x, axis_name)
             y = jnp.prod(g, axis=0)
         elif op == Adasum:
             from horovod_tpu.ops.adasum import adasum_tree
-            g = lax.all_gather(x, axis_name)
+            with _wire():
+                g = lax.all_gather(x, axis_name)
             y = adasum_tree([g[i] for i in range(n)])
         else:
             raise ValueError(f"unknown op {op}")
@@ -86,7 +100,9 @@ def allreduce(x, op=Average, axis_name=HVD_AXIS, process_set=None,
         n = len(ranks)
         if op in (Sum, Average):
             mask = _member_mask(ranks, axis_name)
-            y = lax.psum(jnp.where(mask, x, jnp.zeros_like(x)), axis_name)
+            masked = jnp.where(mask, x, jnp.zeros_like(x))
+            with _wire():
+                y = lax.psum(masked, axis_name)
             if op == Average:
                 y = y / jnp.asarray(n, y.dtype)
         elif op in (Min, Max, Product):

@@ -47,10 +47,28 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
     count is ``ceil(group_bytes / threshold)`` per dtype group (one for
     models under the threshold; e.g. BERT-Large's 1.4 GB fp32 gradients at
     the default 64 MB threshold reduce in ~22 buckets).
+
+    Device scopes (docs/observability.md): everything here lies under
+    ``hvd.grad_exchange``; bucket k under ``bucket<k>`` with ``pack``
+    (reshape, concatenate, pad) and ``unpack`` (slice, reshape) round the
+    collective, which ``in_jit.allreduce`` puts under ``hvd.wire``; a leaf
+    reduced alone under ``leaf<i>``. Each trace of this function sets the
+    gauges ``hvd_fused_allreduce_buckets`` / ``hvd_fused_allreduce_bytes``
+    (collectives issued, bytes they carry with padding).
     """
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
+    with jax.named_scope("hvd.grad_exchange"):
+        out = _fused_allreduce_leaves(
+            leaves, ReduceOp(op), axis_name, process_set, compression,
+            prescale_factor, postscale_factor)
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _fused_allreduce_leaves(leaves, op, axis_name, process_set, compression,
+                            prescale_factor, postscale_factor):
+    """The body of :func:`fused_allreduce_tree` over the flat leaves."""
     from horovod_tpu.common import basics
     from horovod_tpu.common.config import Config
     from horovod_tpu.common.exceptions import NotInitializedError
@@ -58,7 +76,6 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
         threshold = basics.config().fusion_threshold
     except NotInitializedError:
         threshold = Config().fusion_threshold
-    op = ReduceOp(op)
     int8_route = (compression is Compression.int8 and process_set is None
                   and op in (Sum, Average))
     if compression is Compression.int8:
@@ -68,11 +85,14 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
         # request from inside a jit trace.
         compressed = [(jnp.asarray(l), None) for l in leaves]
     else:
-        compressed = [compression.compress(jnp.asarray(l)) for l in leaves]
+        with jax.named_scope("pack"):
+            compressed = [compression.compress(jnp.asarray(l))
+                          for l in leaves]
     groups = {}
     for i, (c, _) in enumerate(compressed):
         groups.setdefault(jnp.dtype(c.dtype), []).append(i)
     out = [None] * len(leaves)
+    n_buckets = wire_bytes = 0
     for dt, idxs in groups.items():
         if op == Average and not jnp.issubdtype(dt, jnp.floating):
             raise ValueError(
@@ -84,10 +104,14 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
             # Adasum normalizes per-tensor, and non-float leaves shouldn't be
             # folded into a float buffer: reduce these leaves individually.
             for i in idxs:
-                out[i] = in_jit.allreduce(
-                    compressed[i][0], op=op, axis_name=axis_name,
-                    process_set=process_set, prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor)
+                with jax.named_scope(f"leaf{i}"):
+                    out[i] = in_jit.allreduce(
+                        compressed[i][0], op=op, axis_name=axis_name,
+                        process_set=process_set,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor)
+                n_buckets += 1
+                wire_bytes += compressed[i][0].size * dt.itemsize
             continue
         # Bucket the group at the fusion threshold (reference:
         # HOROVOD_FUSION_THRESHOLD, fusion_buffer_manager.h:40): one giant
@@ -105,40 +129,50 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
             cur_bytes += nbytes
         buckets.append(cur)
         for bucket in buckets:
-            flats = [compressed[i][0].reshape(-1) for i in bucket]
-            buf = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-            total = buf.size
-            # Tile-friendly length (the FUSION_BUFFER_ATOMIC_UNIT move,
-            # common.h:156): without it XLA may factor an odd-length
-            # vector into (huge, 2) and pad the lane dim 64x.
-            pad = (-total) % 1024
-            if pad:
-                buf = jnp.pad(buf, (0, pad))
-            if int8_route and jnp.issubdtype(dt, jnp.floating):
-                # int8 can't ride a plain psum (overflow + per-rank
-                # scales): route the bucket through the two-phase
-                # quantized exchange (shared wrapper so the eager fusion
-                # path can never diverge on scaling order).
-                from horovod_tpu.parallel.strategies import \
-                    scaled_allreduce_int8
-                buf = scaled_allreduce_int8(
-                    buf, axis_name=axis_name, average=(op == Average),
-                    prescale_factor=prescale_factor,
-                    postscale_factor=postscale_factor)
-            else:
-                buf = in_jit.allreduce(buf, op=op, axis_name=axis_name,
-                                       process_set=process_set,
-                                       prescale_factor=prescale_factor,
-                                       postscale_factor=postscale_factor)
-            off = 0
-            for i in bucket:
-                sz = compressed[i][0].size
-                out[i] = jax.lax.slice_in_dim(buf, off, off + sz).reshape(
-                    compressed[i][0].shape)
-                off += sz
-    out = [compression.decompress(o, ctx)
-           for o, (_, ctx) in zip(out, compressed)]
-    return jax.tree_util.tree_unflatten(treedef, out)
+            parts = [compressed[i][0] for i in bucket]
+            with jax.named_scope(f"bucket{n_buckets}"):
+                with jax.named_scope("pack"):
+                    flats = [x.reshape(-1) for x in parts]
+                    buf = jnp.concatenate(flats) if len(flats) > 1 \
+                        else flats[0]
+                    # Tile-friendly length (the FUSION_BUFFER_ATOMIC_UNIT
+                    # move, common.h:156): without it XLA may factor an
+                    # odd-length vector into (huge, 2) and pad the lane
+                    # dim 64x.
+                    pad = (-buf.size) % 1024
+                    if pad:
+                        buf = jnp.pad(buf, (0, pad))
+                n_buckets += 1
+                wire_bytes += buf.size * dt.itemsize
+                if int8_route and jnp.issubdtype(dt, jnp.floating):
+                    # int8 can't ride a plain psum (overflow + per-rank
+                    # scales): route the bucket through the two-phase
+                    # quantized exchange (shared wrapper so the eager
+                    # fusion path can never diverge on scaling order).
+                    from horovod_tpu.parallel.strategies import \
+                        scaled_allreduce_int8
+                    buf = scaled_allreduce_int8(
+                        buf, axis_name=axis_name, average=(op == Average),
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor)
+                else:
+                    buf = in_jit.allreduce(
+                        buf, op=op, axis_name=axis_name,
+                        process_set=process_set,
+                        prescale_factor=prescale_factor,
+                        postscale_factor=postscale_factor)
+                with jax.named_scope("unpack"):
+                    off = 0
+                    for i, x in zip(bucket, parts):
+                        out[i] = jax.lax.slice_in_dim(
+                            buf, off, off + x.size).reshape(x.shape)
+                        off += x.size
+    from horovod_tpu.metrics import instruments as hvd_metrics
+    hvd_metrics.record_fused_allreduce(lax.axis_size(axis_name), n_buckets,
+                                       wire_bytes)
+    with jax.named_scope("unpack"):
+        return [compression.decompress(o, ctx)
+                for o, (_, ctx) in zip(out, compressed)]
 
 
 def allreduce_gradients_transform(op=Average, axis_name=HVD_AXIS,
@@ -285,6 +319,7 @@ def broadcast_parameters(params, root_rank=0, process_set=None,
     The mode is explicit because a replicated leaf whose first dim happens to
     equal the world size is indistinguishable from a stacked one.
     """
+    from horovod_tpu import trace
     from horovod_tpu.common import basics
     from horovod_tpu.common.process_sets import global_process_set
     from horovod_tpu.ops import collective_ops as C
@@ -303,7 +338,11 @@ def broadcast_parameters(params, root_rank=0, process_set=None,
         out = C.broadcast(tiled, root_rank, process_set=process_set)
         return out[0]
 
-    return jax.tree_util.tree_map(bcast_leaf, params)
+    leaves = jax.tree_util.tree_leaves(params)
+    with trace.run_span("broadcast_parameters", args={
+            "leaves": len(leaves),
+            "bytes": sum(getattr(x, "nbytes", 0) for x in leaves)}):
+        return jax.tree_util.tree_map(bcast_leaf, params)
 
 
 def broadcast_object_tree(obj, root_rank=0, process_set=None):

@@ -32,6 +32,7 @@ from flax import struct
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import trace
 from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.ops import in_jit
 
@@ -45,8 +46,23 @@ class TrainState(struct.PyTreeNode):
 
     @classmethod
     def create(cls, params, optimizer, extra=None):
+        with trace.run_span("opt_state_init"):
+            opt_state = optimizer.init(params)
         return cls(step=jnp.zeros((), jnp.int32), params=params,
-                   opt_state=optimizer.init(params), extra=extra)
+                   opt_state=opt_state, extra=extra)
+
+
+def _loss_and_grad(loss_fn, has_aux, params, batch, extra):
+    """``(loss, aux, grads)`` of the local batch under the device scope
+    ``hvd.loss_and_grad``; within it JAX marks the backward pass's ops
+    ``transpose(jvp(...))``."""
+    with jax.named_scope("hvd.loss_and_grad"):
+        if has_aux:
+            (loss, aux), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch, extra)
+            return loss, aux, grads
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        return loss, None, grads
 
 
 def make_train_step(loss_fn: Callable, optimizer, mesh, axis_name=HVD_AXIS,
@@ -76,21 +92,24 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, axis_name=HVD_AXIS,
         opt_state = in_jit.mark_varying(state.opt_state, axis_name)
         extra = in_jit.mark_varying(state.extra, axis_name)
 
-        if has_aux:
-            (loss, aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch, extra)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            aux = None
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        loss = lax.pmean(loss, axis_name)
+        loss, aux, grads = _loss_and_grad(loss_fn, has_aux, params, batch,
+                                          extra)
+        # The DistributedOptimizer's exchange runs inside ``update`` and
+        # names itself ``hvd.grad_exchange``: an op belongs to the
+        # innermost ``hvd.*`` scope on its path.
+        with jax.named_scope("hvd.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        # The means of loss and aux through in_jit.allreduce (Average), so
+        # that every all-reduce of the step lies under ``hvd.wire``: XLA
+        # may combine the loss's with a bucket's and keep either's name.
+        loss = in_jit.allreduce(loss, axis_name=axis_name)
         if has_aux:
             # Per-shard aux (e.g. local batch-norm statistics) diverges across
             # devices; average it so the stored state is truly replicated —
             # the cross-replica running-stats sync SyncBatchNorm does inline.
             aux = jax.tree_util.tree_map(
-                lambda a: lax.pmean(a, axis_name)
+                lambda a: in_jit.allreduce(a, axis_name=axis_name)
                 if jnp.issubdtype(a.dtype, jnp.floating) else a, aux)
         new_state = state.replace(step=state.step + 1, params=params,
                                   opt_state=opt_state,
@@ -105,7 +124,11 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, axis_name=HVD_AXIS,
         local_step, mesh=mesh,
         in_specs=(P(), batch_spec),
         out_specs=(P(), P()), check_vma=False)
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+
+    def hvd_dp_step(state, batch):     # a profile's module: jit_hvd_dp_step
+        return sharded(state, batch)
+
+    return jax.jit(hvd_dp_step, donate_argnums=(0,) if donate else ())
 
 
 def make_eval_step(eval_fn: Callable, mesh, axis_name=HVD_AXIS,
@@ -159,12 +182,8 @@ def make_zero_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
         opt_state = in_jit.mark_varying(state.opt_state, axis_name)
         extra = in_jit.mark_varying(state.extra, axis_name)
 
-        if has_aux:
-            (loss, aux), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, batch, extra)
-        else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-            aux = None
+        loss, aux, grads = _loss_and_grad(loss_fn, has_aux, params, batch,
+                                          extra)
 
         flat_g, _ = jax.flatten_util.ravel_pytree(grads)
         flat_p, unravel = jax.flatten_util.ravel_pytree(params)
@@ -180,8 +199,9 @@ def make_zero_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
         idx = lax.axis_index(axis_name)
         p_shard = lax.dynamic_slice(jnp.pad(flat_p, (0, pad)),
                                     (idx * shard_len,), (shard_len,))
-        updates, opt_state = tx.update(g_shard, opt_state, p_shard)
-        p_shard = optax.apply_updates(p_shard, updates)
+        with jax.named_scope("hvd.optimizer"):
+            updates, opt_state = tx.update(g_shard, opt_state, p_shard)
+            p_shard = optax.apply_updates(p_shard, updates)
         flat_new = lax.all_gather(p_shard, axis_name, tiled=True)
         params = unravel(flat_new[:flat_p.size])
 
@@ -208,7 +228,11 @@ def make_zero_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
         local_step, mesh=mesh,
         in_specs=(state_specs, batch_spec),
         out_specs=(state_specs, P()), check_vma=False)
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
+
+    def hvd_zero_step(state, batch):
+        return sharded(state, batch)
+
+    return jax.jit(hvd_zero_step, donate_argnums=(0,) if donate else ())
 
 
 class ZeroTrainState(TrainState):

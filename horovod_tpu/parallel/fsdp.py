@@ -33,6 +33,7 @@ import optax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from horovod_tpu import trace
 from horovod_tpu.common.topology import HVD_AXIS
 
 
@@ -111,21 +112,29 @@ def make_fsdp_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
                 min_size))(params)
         return params, opt_state
 
+    # Named as make_train_step names its step and phases, so that a
+    # profile of either reads alike (docs/observability.md).
     @functools.partial(jax.jit, donate_argnums=(0, 1) if donate else ())
-    def step_fn(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+    def hvd_fsdp_step(params, opt_state, batch):
+        with jax.named_scope("hvd.loss_and_grad"):
+            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        with jax.named_scope("hvd.optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return init_fn, step_fn
+    return init_fn, hvd_fsdp_step
 
 
 def shard_batch(batch, mesh, axis_name=HVD_AXIS):
-    """Place a host batch with its leading dim split over the mesh axis."""
+    """Place a host batch with its leading dim split over the mesh axis.
+
+    Span ``shard_batch``: in the profiler's trace whenever a session is on;
+    in the span store only under a step trace (``hvd.step_marker``)."""
 
     def leaf(x):
         spec = [axis_name] + [None] * (np.ndim(x) - 1)
         return _place(x, NamedSharding(mesh, P(*spec)))
 
-    return jax.tree.map(leaf, batch)
+    with trace.span("shard_batch", cat="train"):
+        return jax.tree.map(leaf, batch)

@@ -517,9 +517,13 @@ def scaled_allreduce_int8(x, axis_name="hvd", average=False,
     call, so the scaling order can never diverge between them."""
     from horovod_tpu.ops import wire as _wire
     _record_jit_wire(x, axis_name, "int8")
-    out, _ = _wire.block_scaled_allreduce(
-        x, axis_name=axis_name, wire="int8", average=average,
-        prescale_factor=prescale_factor, postscale_factor=postscale_factor)
+    # The quantized exchange as one unit of the wire (its blocks' scales
+    # and both int8 legs), as in_jit.allreduce scopes its psum.
+    with jax.named_scope("hvd.wire"):
+        out, _ = _wire.block_scaled_allreduce(
+            x, axis_name=axis_name, wire="int8", average=average,
+            prescale_factor=prescale_factor,
+            postscale_factor=postscale_factor)
     return out
 
 

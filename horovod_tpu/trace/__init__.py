@@ -25,6 +25,15 @@ the serving frontend, or dump per-rank shards (:func:`dump`) and merge
 them into one Perfetto-loadable view with
 ``python -m horovod_tpu.trace.analyze``.
 
+:func:`span` is the one entry for host spans. Besides the store it always
+enters a ``jax.profiler.TraceAnnotation`` named ``hvd::<name>``: with a
+profiler session on, the span lies in the profiler's trace, on the device
+trace's clock; with none, the annotation costs one check. Spans that belong to no
+request or step (set-up: ``import``, ``init`` and its children,
+``broadcast_parameters``, ``opt_state_init``) live in the process's one
+``run`` trace (:func:`run_tid`). A ``span`` that closes inside another of
+the same trace becomes its child.
+
 Span-tree schema (``tree()``): the root is the request/step; its
 children are PHASE spans (``queue``, ``prefill``, ``decode``,
 ``stream`` — plus ``requeue``/``restore``/``commit`` instants); phase
@@ -43,6 +52,8 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation
+
 from horovod_tpu.common.config import _env_bool, _env_int
 
 armed = _env_bool("HOROVOD_TRACE", True)
@@ -57,10 +68,12 @@ _traces = {}                  # tid -> record
 _rid_index = {}               # str(rid) -> tid
 _order = {}                   # kind -> deque of tids (eviction order)
 _capacity = {"request": _env_int("HOROVOD_TRACE_CAPACITY", 256),
-             "step": 64}
+             "step": 64, "run": 1}
 _MAX_SPANS = 4096             # per-trace span cap (drops counted)
+_RUN_TID = f"t{_SALT}-run"    # the one ``run`` trace of this process
 
-_tls = threading.local()      # .tid — the active trace ref
+_tls = threading.local()      # .tid — the active trace ref;
+                              # .open — span() blocks open on this thread
 
 
 def configure(config):
@@ -74,6 +87,7 @@ def configure(config):
         # here could race a concurrent register()'s eviction decision.
         with _lock:
             _capacity["request"] = cap
+    run_tid()
 
 
 # --- ids and the active context -----------------------------------------
@@ -94,6 +108,14 @@ def set_active(tid):
 
 def clear_active():
     _tls.tid = None
+
+
+def run_tid():
+    """The id of this process's ``run`` trace: the home of spans that
+    belong to no request or step (set-up). Registered on first use and by
+    :func:`configure` (``register`` is idempotent); None while tracing is
+    disarmed."""
+    return register(_RUN_TID, kind="run") if armed else None
 
 
 @contextlib.contextmanager
@@ -144,10 +166,12 @@ def register(tid, rid=None, kind="request", t0=None, args=None):
 
 
 def _append_locked(rec, span):
+    """The span's index in the trace, None where the cap dropped it."""
     if len(rec["spans"]) >= _MAX_SPANS:
         rec["dropped"] += 1
-        return
+        return None
     rec["spans"].append(span)
+    return len(rec["spans"]) - 1
 
 
 def _parent_index_locked(rec, parent, t0):
@@ -169,22 +193,19 @@ def _parent_index_locked(rec, parent, t0):
         if s["name"] == parent:
             return i
         break                         # a different phase started since
-    synth = {"name": parent, "t0": float(t0), "dur": 0.0, "synth": True}
-    if len(spans) >= _MAX_SPANS:
-        rec["dropped"] += 1
-        return None
-    spans.append(synth)
-    return len(spans) - 1
+    return _append_locked(
+        rec, {"name": parent, "t0": float(t0), "dur": 0.0, "synth": True})
 
 
 def add_span(tid, name, t0, dur, parent=None, cat=None, args=None):
-    """One completed span (wall-clock ``t0``, seconds ``dur``)."""
+    """One completed span (wall-clock ``t0``, seconds ``dur``). Returns
+    its index in the trace, None where nothing was written."""
     if not armed or tid is None:
-        return
+        return None
     with _lock:
         rec = _traces.get(tid)
         if rec is None:
-            return
+            return None
         span = {"name": name, "t0": float(t0), "dur": float(dur)}
         if cat:
             span["cat"] = cat
@@ -193,14 +214,23 @@ def add_span(tid, name, t0, dur, parent=None, cat=None, args=None):
         if parent is not None:
             pi = _parent_index_locked(rec, parent, t0)
             if pi is None:
-                return
+                return None
             span["parent"] = pi
             p = rec["spans"][pi]
             if p.get("synth"):
                 p["t0"] = min(p["t0"], span["t0"])
                 p["dur"] = max(p["dur"],
                                span["t0"] + span["dur"] - p["t0"])
-        _append_locked(rec, span)
+        return _append_locked(rec, span)
+
+
+def _set_parent(tid, parent, children):
+    """Spans ``children`` (indices) closed inside span ``parent``."""
+    with _lock:
+        rec = _traces.get(tid)
+        if rec is not None:
+            for i in children:
+                rec["spans"][i]["parent"] = parent
 
 
 def add_instant(tid, name, t=None, cat=None, args=None, barrier=False):
@@ -238,19 +268,46 @@ def finish(tid, dur=None):
 
 
 @contextlib.contextmanager
-def span(name, parent=None, cat=None, tid=None):
-    """Record a span around a block, into ``tid`` or the active trace.
-    No-op (one attribute read) when tracing is off or nothing is
-    active — cheap enough for the ops hot path."""
-    t = tid if tid is not None else get_active()
-    if not armed or t is None:
-        yield
-        return
-    t0 = time.time()
-    try:
-        yield
-    finally:
-        add_span(t, name, t0, time.time() - t0, parent=parent, cat=cat)
+def span(name, parent=None, cat=None, tid=None, args=None, store=True):
+    """A span around a block: always the profiler's annotation
+    ``hvd::<name>`` (one check when no profiler session is on), and a
+    record in ``tid`` or the active trace when there is one. With tracing
+    off, nothing active or ``store=False`` the store is not touched, so
+    the ops hot path and a per-step caller outside ``step_marker`` grow
+    nothing in memory. ``parent`` names a phase as :func:`add_span` takes
+    it; without it, a span that closes inside another ``span`` of the
+    same trace is that one's child."""
+    with TraceAnnotation("hvd::" + name, **(args or {})):
+        t = None
+        if store and armed:
+            t = tid if tid is not None else get_active()
+        if t is None:
+            yield
+            return
+        stack = getattr(_tls, "open", None)
+        if stack is None:
+            stack = _tls.open = []
+        inside = []                   # spans closed inside this one
+        stack.append((t, inside))
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            dur = time.time() - t0
+            stack.pop()
+            i = add_span(t, name, t0, dur, parent=parent, cat=cat,
+                         args=args)
+            if i is not None:
+                if inside:
+                    _set_parent(t, i, inside)
+                if parent is None and stack and stack[-1][0] == t:
+                    stack[-1][1].append(i)
+
+
+def run_span(name, args=None):
+    """A set-up span: :func:`span` into the ``run`` trace, whatever step
+    or request is active."""
+    return span(name, tid=run_tid(), args=args)
 
 
 def step_trace(step):
@@ -360,3 +417,4 @@ def reset():
         _rid_index.clear()
         _order.clear()
     clear_active()
+    _tls.open = []
