@@ -65,6 +65,10 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   reduced alone; gauge, set while the step is traced, not per step)
 - ``hvd_fused_allreduce_bytes{axis_size}``          bytes those carry a
   step, padding included (gauge, as above)
+- ``hvd_flash_tiles{kernel,kind}``                  score tiles per
+  (batch, head) of the last traced flash-attention call
+  (kernel=fwd|bwd_dq|bwd_dkv; kind=total|visited|masked, masked = visited
+  with mask code; gauge, set while the call is traced)
 - ``autopilot_decisions_total{lever,outcome}``      autopilot control
   decisions (lever=tuner|overlap|cross_wire|remediate; counter)
 - ``autopilot_remediations_total{cause,outcome}``   autopilot-initiated
@@ -298,6 +302,14 @@ FUSED_ALLREDUCE_BYTES = REGISTRY.gauge(
     "Bytes the collectives of one fused_allreduce_tree carry a step "
     "(wire dtype, padding included), by the size of the reduced axis.",
     ("axis_size",))
+FLASH_TILES = REGISTRY.gauge(
+    "hvd_flash_tiles",
+    "Score tiles per (batch, head) of the last traced flash-attention "
+    "call, by kernel (fwd|bwd_dq|bwd_dkv) and kind: total, visited (not "
+    "wholly masked) and masked (visited with mask code: the diagonal or "
+    "the padding edge crosses the tile). From the function that gives "
+    "the kernels their loop bounds. Set while the call is traced.",
+    ("kernel", "kind"))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -645,6 +657,16 @@ def record_fused_allreduce(axis_size, buckets, nbytes):
         return
     FUSED_ALLREDUCE_BUCKETS.labels(axis_size).set(buckets)
     FUSED_ALLREDUCE_BYTES.labels(axis_size).set(nbytes)
+
+
+def record_flash_tiles(kernel, counts):
+    """The tile schedule of one flash-attention kernel call, known while
+    it is traced: ``counts`` maps kind (total|visited|masked) to tiles
+    per (batch, head)."""
+    if not _enabled:
+        return
+    for kind, n in counts.items():
+        FLASH_TILES.labels(kernel, kind).set(n)
 
 
 def record_telemetry_rpc(phase, n=1):

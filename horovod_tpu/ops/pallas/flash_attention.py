@@ -5,7 +5,10 @@ models/gpt.py, parallel/tp.py). Tiled online-softmax attention: for each
 query block the kernel streams key/value blocks through VMEM, keeping the
 running max/denominator in registers — O(L) memory instead of materializing
 the (L, L) score matrix, and every matmul lands on the MXU as a
-(block_q x D) @ (D x block_k) tile.
+(block_q x D) @ (D x block_k) tile. Which tiles a kernel visits, and on
+which it runs mask code, is the tile schedule (_key_tile_bounds,
+_query_tile_bounds): a causal call skips the tiles over the diagonal and
+masks only the tiles the diagonal or the padding edge crosses.
 
 The reference framework has no attention code (SURVEY.md §5.7 — Horovod
 operates below the model level); this kernel is part of the TPU build's
@@ -39,19 +42,60 @@ def _interpret():
 
 
 def _pick_block(length, cap=1024):
-    # Large tiles keep the MXU fed; 1024 rows need the raised scoped-VMEM
-    # budget of _compiler_params (they compile and run at seq 1024 on a
-    # v5e, PR 21; tile sizes are not re-measured on current code).
-    # HVD_FLASH_BLOCK caps the tile lower for on-chip sweeps (the MFU
-    # tuning loop: sweep 128/256/512 per model without code edits).
+    """Tile side for a sequence of ``length``: its largest divisor among
+    1024, 512, 256 and 128 up to ``cap``; a sequence under 128 is one tile
+    (any multiple of the 8 sublanes); None otherwise (flash_attention then
+    pads to a multiple of 128: the dK/dV kernel slices its row statistics
+    along lanes, where mosaic wants multiples of 128).
+
+    HVD_FLASH_BLOCK caps the tile lower for on-chip sweeps (128, 256 or
+    512 per model without code edits); it never raises a tile."""
     import os
     env_cap = os.environ.get("HVD_FLASH_BLOCK")
     if env_cap:
-        cap = min(cap, int(env_cap))
-    for b in (cap, 512, 256, 128, 64, 32, 16, 8):
+        cap = min(cap, max(128, int(env_cap)))
+    for b in (1024, 512, 256, 128):
         if b <= cap and length % b == 0:
             return b
-    return None
+    return length if length < 128 and length % 8 == 0 else None
+
+
+# The outer chunk: what one grid step holds of the axis a kernel writes
+# (_pick_chunk). A sequence up to it is one chunk on every axis of every
+# kernel, so its whole tile sweep has static bounds and unrolls (_sweep).
+_OUTER_CHUNK = 1024
+
+# (block_q, block_k) caps of a CAUSAL call whose sweep unrolls, by kernel:
+# what a sweep of 128, 256 and 512 a side picked on a v5e at 8 x 16 heads x
+# 1024 x 64 (PERF.md, PR 27: 14.2 + 9.9 + 11.6 ms a step against 17.8 + 9.9
+# + 13.4 with 256 x 256 in all three). A tile costs a fixed ~240 cycles per
+# 128 rows beside ~5 a vreg, so the forward kernel likes wide key tiles; the
+# dK/dV kernel likes its tile and both accumulators in registers.
+_CAUSAL_TILE = {"fwd": (128, 512), "bwd_dq": (256, 256),
+                "bwd_dkv": (128, 128)}
+
+
+def _pick_tiles(lq, lk, causal, kernel="fwd"):
+    """(block_q, block_k) of one score tile of ``kernel``, or None where a
+    length has no aligned block.
+
+    The largest divisor up to 1024, so a non-causal sequence of up to 1024
+    is one tile (nothing to skip, and only the padding edge to mask), and a
+    longer one is swept in 1024 x 1024 tiles by loops whose bounds follow
+    the chunk index (the diagonal bounds skip whole tiles there already;
+    small tiles in rolled loops took 1.7-2.1x as long at 2048 to 8192:
+    PERF.md, PR 27).
+    Causal, up to _OUTER_CHUNK a side: a tile spanning the sequence
+    computes the whole square for the mask to throw half away, so each side
+    is capped by _CAUSAL_TILE and at half the sequence (never under the
+    MXU's 128 rows): the diagonal bounds of the unrolled sweeps then skip
+    what lies wholly over the diagonal, and tiles wholly under it run the
+    loop body that has no mask code."""
+    small = causal and max(lq, lk) <= _OUTER_CHUNK
+    caps = _CAUSAL_TILE[kernel] if small else (1024, 1024)
+    bq, bk = (_pick_block(n, min(cap, max(128, n // 2)) if small else cap)
+              for n, cap in zip((lq, lk), caps))
+    return (bq, bk) if bq and bk else None
 
 
 def _vma(*operands):
@@ -66,11 +110,14 @@ def _scratch(shape):
     return pltpu.VMEM(shape, jnp.float32)
 
 
-def _compiler_params():
-    """Raise mosaic's scoped-VMEM budget (default 16 MB) — the 512-row MXU
-    tiles this kernel prefers need ~17-32 MB of stack at long context; v5e
-    has far more physical VMEM than the default budget admits."""
-    if _interpret():
+def _compiler_params(interpret):
+    """Raise mosaic's scoped-VMEM budget (default 16 MB). The small causal
+    tiles (at most 64 Ki elements: 256 KiB a float32 temporary) fit the
+    default; what needs the room is a 1024 x 1024 tile (4 MiB a temporary,
+    half a dozen alive: it compiles and runs on a v5e under this limit,
+    PR 21 and PR 27's sweep). v5e has far more physical VMEM than the
+    default admits."""
+    if interpret:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
 
@@ -79,18 +126,137 @@ def _pick_chunk(length, block, cap=4096):
     """Largest multiple of ``block`` dividing ``length``, capped.
 
     The chunk is the unit the grid streams through VMEM (bounding VMEM at
-    O(chunk) so 8k+ contexts fit the ~16 MB scoped budget); within a chunk
-    a register-carried fori_loop sweeps ``block``-sized MXU tiles (grid
-    steps are too fine-grained to carry the softmax state efficiently).
-    """
+    O(chunk) so 8k+ contexts fit the scoped budget); within a chunk
+    register-carried fori_loops sweep ``block``-sized MXU tiles (grid
+    steps are too fine-grained to carry the softmax state efficiently,
+    and cost up to 0.6 us each: PERF.md, PR 25). Both axes are chunked: the
+    axis the kernel accumulates over in chunks of up to 4096, the axis it
+    writes in chunks of up to _OUTER_CHUNK."""
     c = min(length, cap)
     while c > block and length % c:
         c -= block
     return c
 
 
-def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, block_q, block_k):
-    """Combined causal + key-validity masking for one (BQ, BK) score tile.
+# ---------------------------------------------------------------------------
+# The tile schedule: which (block_q, block_k) tiles of the score matrix a
+# kernel visits and which of those need mask code. Element (i, j) is allowed
+# iff j < kv_valid and (not causal or j <= i + q_offset). These two
+# functions give the kernels their loop bounds AND the hvd_flash_tiles gauge
+# its counts; tile indices may be Python ints (static bounds) or traced
+# scalars (the chunk index is a grid variable).
+# ---------------------------------------------------------------------------
+
+def _clip(x, lo, hi):
+    if isinstance(x, int):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
+def _select(cond, a, b):
+    if isinstance(cond, bool):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _key_tile_bounds(q0, block_q, block_k, n_kt, q_offset, kv_valid, causal):
+    """One row of tiles: query rows [q0, q0 + block_q) against ``n_kt`` key
+    tiles. Returns (n_plain, n_vis): tiles [0, n_plain) are wholly allowed
+    (no mask code), [n_plain, n_vis) are crossed by the diagonal or the
+    padding edge, [n_vis, n_kt) are wholly masked and not visited."""
+    n_vis = min(n_kt, -(-kv_valid // block_k))
+    n_plain = min(n_kt, kv_valid // block_k)
+    if causal:
+        # The last row attends keys <= q0 + block_q - 1 + q_offset, the
+        # first (which sees least) keys <= q0 + q_offset.
+        n_vis = _clip(-(-(q0 + block_q + q_offset) // block_k), 0, n_vis)
+        n_plain = _clip((q0 + q_offset + 1) // block_k, 0, n_plain)
+    return n_plain, n_vis
+
+
+def _query_tile_bounds(k0, block_q, block_k, n_qt, q_offset, kv_valid,
+                       causal):
+    """One column of tiles: keys [k0, k0 + block_k) against ``n_qt`` query
+    tiles. Returns (t_first, t_plain): tiles [0, t_first) are wholly masked
+    and not visited, [t_first, t_plain) are crossed, [t_plain, n_qt) are
+    wholly allowed."""
+    t_first = t_plain = 0
+    if causal:
+        t_first = _clip((k0 - q_offset) // block_q, 0, n_qt)
+        t_plain = _clip(-(-(k0 + block_k - 1 - q_offset) // block_q),
+                        0, n_qt)
+    # A key tile of padding alone is never visited; one the padding edge
+    # crosses is masked on every visit.
+    t_first = _select(k0 < kv_valid, t_first, n_qt)
+    t_plain = _select(k0 + block_k <= kv_valid, t_plain, n_qt)
+    return t_first, t_plain
+
+
+def _in_chunk(bounds, c, tpc):
+    """Tile bounds along a whole axis, as indices into its chunk ``c`` of
+    ``tpc`` tiles."""
+    return tuple(_clip(b - c * tpc, 0, tpc) for b in bounds)
+
+
+def tile_counts(kernel, lq, lk, q_offset, kv_valid, block_q, block_k,
+                causal):
+    """Score tiles per (batch, head) of one call of ``kernel``: ``total``,
+    ``visited`` and ``masked`` (visited with mask code). The forward and dQ
+    kernels sweep rows of tiles, the dK/dV kernel columns."""
+    n_qt, n_kt = lq // block_q, lk // block_k
+    if kernel == "bwd_dkv":
+        cols = [_query_tile_bounds(j * block_k, block_q, block_k, n_qt,
+                                   q_offset, kv_valid, causal)
+                for j in range(n_kt)]
+        visited = sum(n_qt - first for first, _ in cols)
+        masked = sum(plain - first for first, plain in cols)
+    else:
+        rows = [_key_tile_bounds(i * block_q, block_q, block_k, n_kt,
+                                 q_offset, kv_valid, causal)
+                for i in range(n_qt)]
+        visited = sum(vis for _, vis in rows)
+        masked = sum(vis - plain for plain, vis in rows)
+    return {"total": n_qt * n_kt, "visited": visited, "masked": masked}
+
+
+def _record_tiles(kernel, *schedule):
+    from horovod_tpu.metrics import instruments as hvd_metrics
+    hvd_metrics.record_flash_tiles(kernel, tile_counts(kernel, *schedule))
+
+
+# A statically bounded sweep of up to this many tiles is unrolled.
+_UNROLL_TILES = 8
+
+
+def _sweep(lo, hi, body, carry):
+    """``carry`` through ``body(t, carry)`` for t in [lo, hi).
+
+    Static bounds (one chunk on both grid axes: every sequence up to 1024)
+    unroll into straight-line code: a tile's chain of matmul, row
+    statistics and matmul is latency-bound, and only unrolled can the
+    scheduler overlap the chains of independent tiles (a rolled loop took
+    1.6x as long at 256 x 256 on a v5e: PERF.md, PR 27). Traced bounds
+    (the chunk index is a grid variable) take a fori_loop."""
+    if isinstance(lo, int) and isinstance(hi, int) \
+            and hi - lo <= _UNROLL_TILES:
+        for t in range(lo, hi):
+            carry = body(t, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _when(cond, fn):
+    """``fn()`` if ``cond``, for a static or a traced condition."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, q_axis=0):
+    """Combined causal + key-validity masking for one score tile, (BQ, BK)
+    or, with ``q_axis=1``, its transpose.
 
     ``masked`` (static) is True when the key axis was padded to a block
     multiple: keys at global position >= kv_valid are padding and must not
@@ -98,184 +264,196 @@ def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, block_q, block_k):
     """
     if not (causal or masked):
         return s
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     ok = None
     if masked:
         ok = k_pos < kv_valid
     if causal:
-        q_pos = q0 + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
         c = q_pos >= k_pos
         ok = c if ok is None else ok & c
     return jnp.where(ok, s, NEG_INF)
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-               *, sm_scale, causal, block_q, block_k, k_chunk, q_offset,
-               n_kc, kv_valid, masked):
-    """One (query-block, key-chunk) grid step of the online softmax.
+               *, sm_scale, causal, block_q, block_k, q_chunk, k_chunk,
+               q_offset, n_qc, n_kc, kv_valid, masked):
+    """One (query-chunk, key-chunk) grid step of the online softmax.
 
     The key-chunk sweep is the INNERMOST grid dimension; the running
     (m, l, acc) state lives in VMEM scratch across chunk steps and in
-    registers within the chunk's fori tile sweep.
+    registers within a query tile's sweep over the chunk's key tiles:
+    first the tiles wholly under the diagonal and inside kv_valid (no
+    mask code in the loop body), then those the mask edge crosses.
     """
-    qi = pl.program_id(1)
-    # Single-chunk grids (n_kc == 1) are specialized to STATIC control
-    # flow: jc is the literal 0, init/finalize run unconditionally, and
-    # the masked trip count below is a compile-time constant. The generic
-    # path's pl.when(contributes) + dynamically-clipped fori_loop is only
-    # ever needed when the chunk index is a real grid variable.
-    single = n_kc == 1
-    jc = 0 if single else pl.program_id(2)
+    # A grid axis of one chunk gives a STATIC chunk index, and with both
+    # static every loop bound below is a compile-time constant.
+    ic = 0 if n_qc == 1 else pl.program_id(1)
+    jc = 0 if n_kc == 1 else pl.program_id(2)
+    tpc = k_chunk // block_k                       # key tiles per chunk
 
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if single:
-        _init()
-    else:
-        pl.when(jc == 0)(_init)
+    _when(jc == 0, _init)
 
-    # End-aligned causal convention (tril with k = Lk - Lq), matching
-    # local_attention and the backward pass: query row i may attend keys
-    # <= i + (Lk - Lq). q_offset = Lk - Lq.
-    q_end = q_offset + (qi + 1) * block_q - 1  # last query row's key bound
-    contributes = None                 # None == statically always-true
-    if causal:
-        contributes = q_end >= jc * k_chunk
-    if masked and not single:
-        c = jc * k_chunk < kv_valid
-        contributes = c if contributes is None else contributes & c
+    for tq in range(q_chunk // block_q):
+        rows = pl.ds(tq * block_q, block_q)
+        q0 = ic * q_chunk + tq * block_q           # first row, this tile
+        n_plain, n_vis = _in_chunk(
+            _key_tile_bounds(q0, block_q, block_k, n_kc * tpc, q_offset,
+                             kv_valid, causal), jc, tpc)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # (BQ, D)
+        def _compute(rows=rows, q0=q0, n_plain=n_plain, n_vis=n_vis):
+            q = q_ref[0, rows, :].astype(jnp.float32) * sm_scale  # (BQ, D)
 
-        def body(t, carry):
-            m, l, acc = carry
-            kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(jnp.float32)
-            vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(jnp.float32)
-            s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = _apply_mask(s, causal=causal, masked=masked,
-                            q0=q_offset + qi * block_q,
-                            k0=jc * k_chunk + t * block_k,
-                            kv_valid=kv_valid, block_q=block_q,
-                            block_k=block_k)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            # Rows where every score is masked give exp(0)=1; zero them.
-            p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
-            l_new = l * corr + jnp.sum(p, axis=-1)
-            acc_new = acc * corr[:, None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+            def body(t, carry, crossed):
+                m, l, acc = carry
+                kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(
+                    jnp.float32)
+                vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(
+                    jnp.float32)
+                s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                if crossed:
+                    # End-aligned causal convention (tril with k = Lk -
+                    # Lq), matching local_attention and the backward pass.
+                    s = _apply_mask(s, causal=causal, masked=masked,
+                                    q0=q_offset + q0,
+                                    k0=jc * k_chunk + t * block_k,
+                                    kv_valid=kv_valid)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                corr = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[:, None])
+                if crossed:
+                    # Rows where every score so far is masked give
+                    # exp(0) = 1; zero them.
+                    p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
+                l_new = l * corr + jnp.sum(p, axis=-1)
+                acc_new = acc * corr[:, None] + jax.lax.dot_general(
+                    p, vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return m_new, l_new, acc_new
 
-        n_t = k_chunk // block_k
-        if masked and single:
-            # The chunk starts at key 0, so the last valid key tile is a
-            # compile-time constant: a static trip count, no dynamic clip.
-            n_t = min(n_t, max(0, (kv_valid + block_k - 1) // block_k))
-        if causal:
-            # Bound the tile sweep at the diagonal within this chunk.
-            n_t = jnp.clip(
-                pl.cdiv(q_end + 1 - jc * k_chunk, block_k), 0, n_t)
-        if masked and not single:
-            # ...and at the last VALID key tile.
-            n_t = jnp.clip(
-                pl.cdiv(kv_valid - jc * k_chunk, block_k), 0, n_t)
-        m, l, acc = jax.lax.fori_loop(
-            0, n_t, body, (m_ref[:, 0], l_ref[:, 0], acc_ref[...]))
-        m_ref[...] = m[:, None]
-        l_ref[...] = l[:, None]
-        acc_ref[...] = acc
+            carry = (m_ref[rows, 0], l_ref[rows, 0], acc_ref[rows, :])
+            carry = _sweep(0, n_plain,
+                           functools.partial(body, crossed=False), carry)
+            m, l, acc = _sweep(n_plain, n_vis,
+                               functools.partial(body, crossed=True), carry)
+            m_ref[rows, :] = m[:, None]
+            l_ref[rows, :] = l[:, None]
+            acc_ref[rows, :] = acc
 
-    if contributes is None:
-        _compute()
-    else:
-        pl.when(contributes)(_compute)
+        _when(n_vis > 0, _compute)
 
     def _finalize():
-        l_safe = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        # lse rides a (1, block_q, 1) block: TPU mosaic requires the
-        # block's last two dims to be (8k, 128k) or equal to the array's —
-        # a trailing singleton satisfies that where (1, block_q) cannot.
-        lse_ref[0] = (m_ref[:, 0] + jnp.log(l_safe))[:, None]
+        l_safe = jnp.maximum(l_ref[...], 1e-30)            # (q_chunk, 1)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        # lse leaves as a (1, 1, q_chunk) row: a (q_chunk, 1) column pads
+        # every element to a 128-lane tile in HBM (64 MiB for 128 x 1024
+        # floats), which the backward kernels then stream back in.
+        lse_ref[0, 0, :] = (m_ref[...] + jnp.log(l_safe))[:, 0]
 
-    if single:
-        _finalize()
-    else:
-        pl.when(jc == n_kc - 1)(_finalize)
+    _when(jc == n_kc - 1, _finalize)
 
 
-def _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
+def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
                 q_offset=None, kv_valid=None, heads=None, kv_heads=None):
     """(B*H, Lq, D) x (B*KV, Lk, D)^2 -> (o, lse).
 
-    ``q_offset``/``kv_valid`` override the end-aligned causal offset and
-    the number of VALID keys when the inputs were padded to block
-    multiples (positions are always in ORIGINAL coordinates).
+    ``block_q``/``block_k`` default to :func:`_pick_tiles`' choice for
+    the forward kernel. ``q_offset``/``kv_valid`` override the end-aligned
+    causal offset and the number of VALID keys when the inputs were padded
+    to block multiples (positions are always in ORIGINAL coordinates).
 
     Grouped-query attention: with ``kv_heads < heads`` the K/V tensors
     carry only the grouped heads and the kernel streams each kv head's
     chunks to its ``heads/kv_heads`` query heads via the BlockSpec index
     map — no materialized broadcast, 1/g the K/V HBM traffic."""
-    bh, lq, d = q.shape
-    lk = k.shape[1]
+    lq, lk = q.shape[1], k.shape[1]
+    if block_q is None:
+        block_q, block_k = _pick_tiles(lq, lk, causal, "fwd")
     if q_offset is None:
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
-    if heads is None or kv_heads is None or heads == kv_heads:
+    _record_tiles("fwd", lq, lk, q_offset, kv_valid, block_q, block_k,
+                  causal)
+    gqa = heads is not None and kv_heads is not None and heads != kv_heads
+    return _fwd_call(
+        q, k, v, causal=causal, sm_scale=sm_scale,
+        tiles=(block_q, block_k),
+        chunks=(_pick_chunk(lq, block_q, _OUTER_CHUNK),
+                _pick_chunk(lk, block_k)),
+        q_offset=q_offset, kv_valid=kv_valid,
+        group=(heads, kv_heads) if gqa else None, interpret=_interpret())
+
+
+# The pallas_calls sit in jitted functions of their own, every choice a
+# static argument, so that the 24 layers of a model trace, lower and hash one
+# kernel and not 24: a kernel body of a dozen unrolled tiles takes 0.1-0.3 s
+# to trace, and traced per layer the three kernels added 50 s to the set-up
+# of gpt2m_1chip (PERF.md, PR 27).
+_CALL_STATICS = ("causal", "sm_scale", "tiles", "chunks", "q_offset",
+                 "kv_valid", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("group",))
+def _fwd_call(q, k, v, *, causal, sm_scale, tiles, chunks, q_offset,
+              kv_valid, group, interpret):
+    bh, lq, d = q.shape
+    lk = k.shape[1]
+    (block_q, block_k), (q_chunk, k_chunk) = tiles, chunks
+    if group is None:
         def kv_map(b, i, j):
             return (b, j, 0)
     else:
+        heads, kv_heads = group
         g = heads // kv_heads
 
         def kv_map(b, i, j):
             return ((b // heads) * kv_heads + (b % heads) // g, j, 0)
-    masked = kv_valid < lk
-    k_chunk = _pick_chunk(lk, block_k)
-    n_kc = lk // k_chunk
-    grid = (bh, lq // block_q, n_kc)
+    n_qc, n_kc = lq // q_chunk, lk // k_chunk
     kernel = functools.partial(_fa_kernel, sm_scale=sm_scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               k_chunk=k_chunk, q_offset=q_offset,
-                               n_kc=n_kc, kv_valid=kv_valid, masked=masked)
+                               q_chunk=q_chunk, k_chunk=k_chunk,
+                               q_offset=q_offset, n_qc=n_qc, n_kc=n_kc,
+                               kv_valid=kv_valid, masked=kv_valid < lk)
     vma = _vma(q, k, v)
     o, lse = pl.pallas_call(
         kernel,
         name="hvd_flash_fwd",
-        grid=grid,
+        grid=(bh, n_qc, n_kc),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, k_chunk, d), kv_map),
             pl.BlockSpec((1, k_chunk, d), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32, vma=vma),
         ],
-        scratch_shapes=[_scratch((block_q, 1)), _scratch((block_q, 1)),
-                        _scratch((block_q, d))],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        scratch_shapes=[_scratch((q_chunk, 1)), _scratch((q_chunk, 1)),
+                        _scratch((q_chunk, d))],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
     )(q, k, v)
-    return o, lse[..., 0]
+    return o, lse[:, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, q_offset=None,
-           kv_valid=None, heads=None, kv_heads=None):
-    """``heads``/``kv_heads`` (static) turn on grouped-query attention:
+def _flash(q, k, v, causal, sm_scale, block_q=None, block_k=None,
+           q_offset=None, kv_valid=None, heads=None, kv_heads=None):
+    """``block_q``/``block_k`` None: each kernel takes its own tile shape
+    from :func:`_pick_tiles`.
+
+    ``heads``/``kv_heads`` (static) turn on grouped-query attention:
     q carries B*heads rows, k/v only B*kv_heads. The forward streams the
     NARROW k/v through the kernel (index-mapped, no broadcast); the
     backward broadcasts once and group-sums dK/dV — forward/serving
@@ -285,8 +463,8 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, q_offset=None,
     return o
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset=None,
-               kv_valid=None, heads=None, kv_heads=None):
+def _flash_fwd(q, k, v, causal, sm_scale, block_q=None, block_k=None,
+               q_offset=None, kv_valid=None, heads=None, kv_heads=None):
     o, lse = _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
                          q_offset, kv_valid, heads=heads, kv_heads=kv_heads)
     return o, (q, k, v, o, lse)
@@ -294,224 +472,234 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, q_offset=None,
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, acc_ref, *, sm_scale, causal, block_q,
-                      block_k, k_chunk, q_offset, n_kc, kv_valid, masked):
-    """dQ pass: (query-block, key-chunk) grid with the dq accumulator in
-    scratch across chunks and a register fori sweep within each chunk."""
-    qi = pl.program_id(1)
-    # Same single-chunk static specialization as _fa_kernel (see there).
-    single = n_kc == 1
-    jc = 0 if single else pl.program_id(2)
+                      block_k, q_chunk, k_chunk, q_offset, n_qc, n_kc,
+                      kv_valid, masked):
+    """dQ pass: (query-chunk, key-chunk) grid with the dq accumulator in
+    scratch across key chunks; per query tile the same two register
+    sweeps over the chunk's key tiles as _fa_kernel (plain, then
+    crossed)."""
+    ic = 0 if n_qc == 1 else pl.program_id(1)
+    jc = 0 if n_kc == 1 else pl.program_id(2)
+    tpc = k_chunk // block_k
 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if single:
-        _init()
-    else:
-        pl.when(jc == 0)(_init)
+    _when(jc == 0, _init)
 
-    q_end = q_offset + (qi + 1) * block_q - 1
-    contributes = None
-    if causal:
-        contributes = q_end >= jc * k_chunk
-    if masked and not single:
-        c = jc * k_chunk < kv_valid
-        contributes = c if contributes is None else contributes & c
+    for tq in range(q_chunk // block_q):
+        rows = pl.ds(tq * block_q, block_q)
+        q0 = ic * q_chunk + tq * block_q
+        n_plain, n_vis = _in_chunk(
+            _key_tile_bounds(q0, block_q, block_k, n_kc * tpc, q_offset,
+                             kv_valid, causal), jc, tpc)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)                   # (BQ, D)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, :, 0]                             # (BQ,)
-        delta = delta_ref[0, :, 0]
+        def _compute(rows=rows, q0=q0, n_plain=n_plain, n_vis=n_vis):
+            q = q_ref[0, rows, :].astype(jnp.float32)              # (BQ, D)
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            lse = lse_ref[0, 0, rows]                              # (BQ,)
+            delta = delta_ref[0, 0, rows]
 
-        def body(t, dq):
-            kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(jnp.float32)
-            vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            s = _apply_mask(s, causal=causal, masked=masked,
-                            q0=q_offset + qi * block_q,
-                            k0=jc * k_chunk + t * block_k,
-                            kv_valid=kv_valid, block_q=block_q,
-                            block_k=block_k)
-            p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - lse[:, None]), 0.0)
-            dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * sm_scale
-            return dq + jax.lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            def body(t, dq, crossed):
+                kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(
+                    jnp.float32)
+                vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(
+                    jnp.float32)
+                s = jax.lax.dot_general(
+                    q, kb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if crossed:
+                    s = _apply_mask(s, causal=causal, masked=masked,
+                                    q0=q_offset + q0,
+                                    k0=jc * k_chunk + t * block_k,
+                                    kv_valid=kv_valid)
+                    p = jnp.where(s > NEG_INF * 0.5,
+                                  jnp.exp(s - lse[:, None]), 0.0)
+                else:
+                    p = jnp.exp(s - lse[:, None])
+                dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = p * (dp - delta[:, None]) * sm_scale
+                return dq + jax.lax.dot_general(
+                    ds, kb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
-        n_t = k_chunk // block_k
-        if masked and single:
-            n_t = min(n_t, max(0, (kv_valid + block_k - 1) // block_k))
-        if causal:
-            n_t = jnp.clip(
-                pl.cdiv(q_end + 1 - jc * k_chunk, block_k), 0, n_t)
-        if masked and not single:
-            n_t = jnp.clip(
-                pl.cdiv(kv_valid - jc * k_chunk, block_k), 0, n_t)
-        acc_ref[...] = jax.lax.fori_loop(0, n_t, body, acc_ref[...])
+            dq = _sweep(0, n_plain, functools.partial(body, crossed=False),
+                        acc_ref[rows, :])
+            acc_ref[rows, :] = _sweep(
+                n_plain, n_vis, functools.partial(body, crossed=True), dq)
 
-    if contributes is None:
-        _compute()
-    else:
-        pl.when(contributes)(_compute)
+        _when(n_vis > 0, _compute)
 
     def _finalize():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
-    if single:
-        _finalize()
-    else:
-        pl.when(jc == n_kc - 1)(_finalize)
+    _when(jc == n_kc - 1, _finalize)
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                       block_q, block_k, q_chunk, q_offset, n_qc, kv_valid,
-                       masked):
-    """dK/dV pass: (key-block, query-chunk) grid; per-key-block accumulators
-    in scratch across query chunks, register fori sweep within."""
-    ki = pl.program_id(1)
-    # Single-chunk static specialization for the QUERY-chunk grid dim
-    # (n_qc == 1): literal jc, unconditional init/finalize. The masked
-    # and causal gates ride ki — a real grid variable — and remain.
-    single = n_qc == 1
-    jc = 0 if single else pl.program_id(2)
+                       block_q, block_k, q_chunk, k_chunk, q_offset, n_qc,
+                       n_kc, kv_valid, masked):
+    """dK/dV pass: (key-chunk, query-chunk) grid; per-key-chunk
+    accumulators in scratch across query chunks; per key tile two register
+    sweeps over the chunk's query tiles: first those the mask edge
+    crosses (the diagonal comes first going down a column), then the
+    plain ones under it.
+
+    The tile is computed TRANSPOSED, (BK, BQ) = k q^T, so that both
+    accumulating products (p^T dO, ds^T q) contract the tile's lane axis
+    as plain matmuls; from the (BQ, BK) tile they contract its row axis,
+    and mosaic transposes p and ds through the XLU on every tile (dK/dV at
+    128 x 128 on a v5e: 15.8 ms a step that way, 11.6 this way: PERF.md,
+    PR 27). lse and delta arrive as (1, q_chunk) rows for it."""
+    ic = 0 if n_kc == 1 else pl.program_id(1)      # key chunk (written)
+    jc = 0 if n_qc == 1 else pl.program_id(2)      # query chunk (swept)
+    tpc = q_chunk // block_q                       # query tiles per chunk
 
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if single:
-        _init()
-    else:
-        pl.when(jc == 0)(_init)
+    _when(jc == 0, _init)
 
-    contributes = None
-    if causal:
-        # Query chunks ending above this key block's diagonal contribute
-        # nothing: rows i attend keys <= i + q_offset.
-        contributes = (q_offset + (jc + 1) * q_chunk - 1) >= ki * block_k
-    if masked:
-        # Entirely-padding key blocks receive zero gradient.
-        c = ki * block_k < kv_valid
-        contributes = c if contributes is None else contributes & c
+    for tk in range(k_chunk // block_k):
+        cols = pl.ds(tk * block_k, block_k)
+        k0 = ic * k_chunk + tk * block_k
+        t_first, t_plain = _in_chunk(
+            _query_tile_bounds(k0, block_q, block_k, n_qc * tpc, q_offset,
+                               kv_valid, causal), jc, tpc)
 
-    def _compute():
-        kb = k_ref[0].astype(jnp.float32)                  # (BK, D)
-        vb = v_ref[0].astype(jnp.float32)
+        def _compute(cols=cols, k0=k0, t_first=t_first, t_plain=t_plain):
+            kb = k_ref[0, cols, :].astype(jnp.float32)             # (BK, D)
+            vb = v_ref[0, cols, :].astype(jnp.float32)
 
-        def body(t, carry):
-            dk, dv = carry
-            qb = q_ref[0, pl.ds(t * block_q, block_q), :].astype(jnp.float32)
-            dob = do_ref[0, pl.ds(t * block_q, block_q), :].astype(
-                jnp.float32)
-            lse_b = lse_ref[0, pl.ds(t * block_q, block_q), 0]
-            delta_b = delta_ref[0, pl.ds(t * block_q, block_q), 0]
-            s = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            s = _apply_mask(s, causal=causal, masked=masked,
-                            q0=q_offset + jc * q_chunk + t * block_q,
-                            k0=ki * block_k, kv_valid=kv_valid,
-                            block_q=block_q, block_k=block_k)
-            p = jnp.where(s > NEG_INF * 0.5,
-                          jnp.exp(s - lse_b[:, None]), 0.0)
-            dv = dv + jax.lax.dot_general(
-                p, dob, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_b[:, None]) * sm_scale
-            dk = dk + jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return dk, dv
+            def body(t, carry, crossed):
+                dk, dv = carry
+                tile = pl.ds(t * block_q, block_q)
+                qb = q_ref[0, tile, :].astype(jnp.float32)
+                dob = do_ref[0, tile, :].astype(jnp.float32)
+                lse_b = lse_ref[0, :, tile]                        # (1, BQ)
+                delta_b = delta_ref[0, :, tile]
+                s = jax.lax.dot_general(
+                    kb, qb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * sm_scale
+                if crossed:
+                    s = _apply_mask(s, causal=causal, masked=masked,
+                                    q0=q_offset + jc * q_chunk + t * block_q,
+                                    k0=k0, kv_valid=kv_valid, q_axis=1)
+                    p = jnp.where(s > NEG_INF * 0.5,
+                                  jnp.exp(s - lse_b), 0.0)
+                else:
+                    p = jnp.exp(s - lse_b)
+                dv = dv + jax.lax.dot_general(
+                    p, dob, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(vb, dob, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_b) * sm_scale
+                dk = dk + jax.lax.dot_general(
+                    ds, qb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                return dk, dv
 
-        n_t = q_chunk // block_q
-        if causal:
-            # First query row attending key block ki within this chunk.
-            t0 = jnp.clip(
-                (ki * block_k - q_offset - jc * q_chunk) // block_q, 0, n_t)
-        else:
-            t0 = 0
-        dk, dv = jax.lax.fori_loop(
-            t0, n_t, body, (dk_acc[...], dv_acc[...]))
-        dk_acc[...] = dk
-        dv_acc[...] = dv
+            carry = _sweep(t_first, t_plain,
+                           functools.partial(body, crossed=True),
+                           (dk_acc[cols, :], dv_acc[cols, :]))
+            dk, dv = _sweep(t_plain, tpc,
+                            functools.partial(body, crossed=False), carry)
+            dk_acc[cols, :] = dk
+            dv_acc[cols, :] = dv
 
-    if contributes is None:
-        _compute()
-    else:
-        pl.when(contributes)(_compute)
+        _when(t_first < tpc, _compute)
 
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
-    if single:
-        _finalize()
-    else:
-        pl.when(jc == n_qc - 1)(_finalize)
+    _when(jc == n_qc - 1, _finalize)
 
 
-def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q, block_k,
-                 q_offset=None, kv_valid=None):
-    """Fused O(L)-memory backward: (dq, dk, dv) via two pallas_calls."""
-    bh, lq, d = q.shape
-    lk = k.shape[1]
+def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
+                 block_k=None, q_offset=None, kv_valid=None):
+    """Fused O(L)-memory backward: (dq, dk, dv) via two pallas_calls, each
+    with :func:`_pick_tiles`' tile shape for it unless one is given."""
+    lq, lk = q.shape[1], k.shape[1]
     if q_offset is None:
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
-    masked = kv_valid < lk
-    k_chunk = _pick_chunk(lk, block_k)
-    q_chunk = _pick_chunk(lq, block_q)
-    n_kc = lk // k_chunk
-    n_qc = lq // q_chunk
+    tiles = {}
+    for kernel in ("bwd_dq", "bwd_dkv"):
+        tiles[kernel] = (block_q, block_k) if block_q else \
+            _pick_tiles(lq, lk, causal, kernel)
+        _record_tiles(kernel, lq, lk, q_offset, kv_valid, *tiles[kernel],
+                      causal)
+    (dq_q, dq_k), (dkv_q, dkv_k) = tiles["bwd_dq"], tiles["bwd_dkv"]
+    # Each kernel streams the axis it accumulates over in chunks of up to
+    # 4096 and writes the other in chunks of up to _OUTER_CHUNK.
+    return _bwd_call(
+        q, k, v, o, lse, do, causal=causal, sm_scale=sm_scale,
+        tiles=(tiles["bwd_dq"], tiles["bwd_dkv"]),
+        chunks=((_pick_chunk(lq, dq_q, _OUTER_CHUNK),
+                 _pick_chunk(lk, dq_k)),
+                (_pick_chunk(lq, dkv_q),
+                 _pick_chunk(lk, dkv_k, _OUTER_CHUNK))),
+        q_offset=q_offset, kv_valid=kv_valid, interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
+              q_offset, kv_valid, interpret):
+    bh, lq, d = q.shape
+    lk = k.shape[1]
+    # The row statistics travel as (BH, 1, Lq) rows: see _fa_kernel.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (BH, Lq, 1)
-    lse3 = lse[..., None]                                  # (BH, Lq, 1)
-    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, q_offset=q_offset, kv_valid=kv_valid,
-                  masked=masked)
-    q_blk = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    r_blk = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-    kc_swept = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, j, 0))
+                    axis=-1)[:, None, :]
+    lse = lse[:, None, :]
     vma = _vma(q, k, v, do)
+
+    def kernel(body, tile, chunk):
+        (block_q, block_k), (q_chunk, k_chunk) = tile, chunk
+        return functools.partial(
+            body, sm_scale=sm_scale, causal=causal, block_q=block_q,
+            block_k=block_k, q_chunk=q_chunk, k_chunk=k_chunk,
+            n_qc=lq // q_chunk, n_kc=lk // k_chunk, q_offset=q_offset,
+            kv_valid=kv_valid, masked=kv_valid < lk)
+
+    # dQ: grid over query chunks; key chunks stream innermost.
+    q_chunk, k_chunk = chunks[0]
+    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0))
+    r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i))
+    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, j, 0))
     dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, k_chunk=k_chunk, n_kc=n_kc,
-                          **common),
+        kernel(_fa_bwd_dq_kernel, tiles[0], chunks[0]),
         name="hvd_flash_bwd_dq",
-        grid=(bh, lq // block_q, n_kc),
-        in_specs=[q_blk, kc_swept, kc_swept, q_blk, r_blk, r_blk],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        grid=(bh, lq // q_chunk, lk // k_chunk),
+        in_specs=[q_blk, k_blk, k_blk, q_blk, r_blk, r_blk],
+        out_specs=q_blk,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
-        scratch_shapes=[_scratch((block_q, d))],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-    )(q, k, v, do, lse3, delta)
-    # dK/dV: grid over key blocks; query chunks stream innermost.
-    qc_swept = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, j, 0))
-    rc_swept = pl.BlockSpec((1, q_chunk, 1), lambda b, i, j: (b, j, 0))
-    k_blk = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
+        scratch_shapes=[_scratch((q_chunk, d))],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
+    # dK/dV: grid over key chunks; query chunks stream innermost.
+    q_chunk, k_chunk = chunks[1]
+    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, j, 0))
+    r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, j))
+    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, i, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, q_chunk=q_chunk, n_qc=n_qc,
-                          **common),
+        kernel(_fa_bwd_dkv_kernel, tiles[1], chunks[1]),
         name="hvd_flash_bwd_dkv",
-        grid=(bh, lk // block_k, n_qc),
-        in_specs=[qc_swept, k_blk, k_blk, qc_swept, rc_swept, rc_swept],
-        out_specs=[pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))],
+        grid=(bh, lk // k_chunk, lq // q_chunk),
+        in_specs=[q_blk, k_blk, k_blk, q_blk, r_blk, r_blk],
+        out_specs=[k_blk, k_blk],
         out_shape=[jax.ShapeDtypeStruct((bh, lk, d), k.dtype, vma=vma),
                    jax.ShapeDtypeStruct((bh, lk, d), v.dtype, vma=vma)],
-        scratch_shapes=[_scratch((block_k, d)), _scratch((block_k, d))],
-        compiler_params=_compiler_params(),
-        interpret=_interpret(),
-    )(q, k, v, do, lse3, delta)
+        scratch_shapes=[_scratch((k_chunk, d)), _scratch((k_chunk, d))],
+        compiler_params=_compiler_params(interpret),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
@@ -649,11 +837,11 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     if _interpret() and _vma(q, k, v):
         return plain_fallback()
 
-    # Pad only genuinely unaligned lengths (e.g. ViT's 196): aligned ones
-    # keep their unpadded, unmasked kernels (no pad copy, no mask work).
+    # Pad only genuinely unaligned lengths (e.g. ViT's 196) to the next
+    # multiple of 128: aligned ones keep their unpadded, unmasked kernels
+    # (no pad copy, no mask work).
     pad_q = 0 if _pick_block(lq) else (-lq) % 128
     pad_k = 0 if _pick_block(lk) else (-lk) % 128
-    lq_p, lk_p = lq + pad_q, lk + pad_k
 
     def to3(t, pad):
         nh = t.shape[2]
@@ -668,6 +856,6 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     # kv != h: grouped-query — the kernels stream the NARROW k/v (1/g the
     # HBM traffic); no broadcast is materialized on the forward path.
     out = _flash(to3(q, pad_q), to3(k, pad_k), to3(v, pad_k), causal,
-                 sm_scale, _pick_block(lq_p), _pick_block(lk_p),
-                 lk - lq, lk, h, kv)
+                 sm_scale, q_offset=lk - lq, kv_valid=lk, heads=h,
+                 kv_heads=kv)
     return from3(out)

@@ -169,7 +169,7 @@ def _block_attn_fwd(q3, ks, vs, causal, scale, blocks, heads=None,
                                                         gqa_repeat3)
     gqa = heads is not None and kv_heads is not None and heads != kv_heads
     if blocks is not None and not _interpret():
-        return _fa_forward(q3, ks, vs, causal, scale, *blocks,
+        return _fa_forward(q3, ks, vs, causal, scale,
                            heads=heads if gqa else None,
                            kv_heads=kv_heads if gqa else None)
     if gqa:
@@ -203,7 +203,7 @@ def _block_attn_bwd(q3, ks, vs, out3, lse, do3, causal, scale, blocks,
         vs = gqa_repeat3(vs, b, kv_heads, g)
     if blocks is not None and not _interpret():
         dq, dk, dv = _fa_backward(q3, ks, vs, out3, lse, do3, causal,
-                                  scale, *blocks)
+                                  scale)
     else:
         dq, dk, dv = _jnp_block_bwd(q3, ks, vs, out3, lse, do3, causal,
                                     scale)
@@ -363,8 +363,10 @@ def ring_attention(q, k, v, axis_name=SP_AXIS, causal=False,
         import importlib
         fa = importlib.import_module(
             "horovod_tpu.ops.pallas.flash_attention")
-        bq, bk = fa._pick_block(Lq), fa._pick_block(k.shape[1])
-        blocks = (bq, bk) if (bq and bk) else None
+        # None where a local length has no aligned tile; each hop picks
+        # its own tile shape from its own mask (the diagonal hop alone is
+        # causal).
+        blocks = fa._pick_tiles(Lq, k.shape[1], False)
         if blocks is None and not fa._interpret():
             hvd_logging.warning(
                 "ring_attention(use_flash=True): local lengths %d/%d have "

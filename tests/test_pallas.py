@@ -268,6 +268,264 @@ class TestFlashAttention:
                                    rtol=2e-4, atol=2e-5)
 
 
+def _fa():
+    # the package re-exports the same-named function, shadowing the
+    # submodule attribute — import the module explicitly
+    import importlib
+    return importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+
+
+# (lq, lk, q_offset, kv_valid, block_q, block_k): square, cross lengths
+# both ways, ring-hop and negative offsets, padded keys, unequal blocks.
+_SCHEDULES = [
+    (128, 128, 0, 128, 32, 32), (128, 128, 0, 128, 32, 64),
+    (128, 128, 0, 128, 64, 16), (128, 128, 0, 128, 128, 128),
+    (128, 128, 0, 100, 32, 32), (128, 128, 0, 100, 64, 32),
+    (128, 128, 0, 97, 32, 64), (64, 128, 64, 128, 32, 32),
+    (64, 128, 64, 128, 16, 64), (128, 64, -64, 64, 32, 32),
+    (128, 64, -64, 64, 64, 16), (128, 64, -64, 50, 32, 32),
+    (64, 64, -64, 64, 32, 32), (64, 64, -200, 64, 32, 32),
+    (64, 64, 64, 64, 32, 32), (64, 64, 500, 64, 16, 32),
+    (96, 160, 17, 150, 32, 32), (96, 160, -5, 160, 48, 32),
+    (128, 128, 0, 0, 32, 32), (128, 256, 28, 228, 64, 128),
+]
+
+
+class TestTileSchedule:
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("lq,lk,q_offset,kv_valid,bq,bk", _SCHEDULES)
+    def test_bounds_match_brute_force(self, lq, lk, q_offset, kv_valid, bq,
+                                      bk, causal):
+        """Every tile not visited is wholly masked, every tile visited
+        without mask code is wholly allowed, every other tile is visited
+        with it — by the row bounds (forward, dQ) and by the column bounds
+        (dK/dV), which must name the same tiles."""
+        fa = _fa()
+        i, j = np.arange(lq)[:, None], np.arange(lk)[None, :]
+        ok = np.broadcast_to(j < kv_valid, (lq, lk))
+        if causal:
+            ok = ok & (j <= i + q_offset)
+        n_qt, n_kt = lq // bq, lk // bk
+
+        def want(a, b):
+            tile = ok[a * bq:(a + 1) * bq, b * bk:(b + 1) * bk]
+            return "plain" if tile.all() else \
+                "masked" if tile.any() else "skipped"
+        by_rows, by_cols = {}, {}
+        for a in range(n_qt):
+            n_plain, n_vis = fa._key_tile_bounds(
+                a * bq, bq, bk, n_kt, q_offset, kv_valid, causal)
+            assert 0 <= n_plain <= n_vis <= n_kt
+            for b in range(n_kt):
+                by_rows[a, b] = ("plain" if b < n_plain else
+                                 "masked" if b < n_vis else "skipped")
+        for b in range(n_kt):
+            t_first, t_plain = fa._query_tile_bounds(
+                b * bk, bq, bk, n_qt, q_offset, kv_valid, causal)
+            assert 0 <= t_first <= t_plain <= n_qt
+            for a in range(n_qt):
+                by_cols[a, b] = ("skipped" if a < t_first else
+                                 "masked" if a < t_plain else "plain")
+        expected = {(a, b): want(a, b)
+                    for a in range(n_qt) for b in range(n_kt)}
+        assert by_rows == expected
+        assert by_cols == expected
+        kinds = list(expected.values())
+        for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+            assert fa.tile_counts(kernel, lq, lk, q_offset, kv_valid, bq,
+                                  bk, causal) == {
+                "total": n_qt * n_kt,
+                "visited": len(kinds) - kinds.count("skipped"),
+                "masked": kinds.count("masked")}
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_traced_bounds_equal_static(self, causal):
+        """The bounds a kernel computes from a grid variable (a traced tile
+        origin) equal the static ones."""
+        fa = _fa()
+        args = (32, 64, 4, -40, 200, causal)
+        for q0 in range(0, 256, 32):
+            got = jax.jit(lambda x: fa._key_tile_bounds(x, *args))(
+                jnp.int32(q0))
+            assert tuple(int(g) for g in got) \
+                == fa._key_tile_bounds(q0, *args)
+        args = (32, 64, 8, -40, 200, causal)
+        for k0 in range(0, 256, 64):
+            got = jax.jit(lambda x: fa._query_tile_bounds(x, *args))(
+                jnp.int32(k0))
+            assert tuple(int(g) for g in got) \
+                == fa._query_tile_bounds(k0, *args)
+
+    @pytest.mark.parametrize("lq,lk,causal,want", [
+        (1024, 1024, False, [(1024, 1024)] * 3),
+        (512, 512, False, [(512, 512)] * 3),
+        (128, 128, False, [(128, 128)] * 3),
+        (256, 256, False, [(256, 256)] * 3),
+        (2048, 2048, False, [(1024, 1024)] * 3),
+        (384, 384, False, [(128, 128)] * 3),
+        (1024, 1024, True, [(128, 512), (256, 256), (128, 128)]),
+        (2048, 2048, True, [(1024, 1024)] * 3),
+        (8192, 8192, True, [(1024, 1024)] * 3),
+        (1024, 2048, True, [(1024, 1024)] * 3),
+        (512, 512, True, [(128, 256), (256, 256), (128, 128)]),
+        (256, 256, True, [(128, 128)] * 3),
+        (128, 128, True, [(128, 128)] * 3),
+        (256, 1024, True, [(128, 512), (128, 256), (128, 128)]),
+        (100, 100, True, [None] * 3)])
+    def test_tile_shape_follows_the_mask(self, monkeypatch, lq, lk, causal,
+                                         want):
+        """Non-causal calls keep the largest divisor up to 1024, and so do
+        sequences of several chunks (their sweeps are rolled loops, where
+        small tiles lose); a causal tile of a sequence up to 1024 is
+        smaller than the sequence so the diagonal bounds engage, each
+        kernel with the shape measured for it."""
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        assert [_fa()._pick_tiles(lq, lk, causal, kernel)
+                for kernel in ("fwd", "bwd_dq", "bwd_dkv")] == want
+
+    @pytest.mark.parametrize("length,want", [
+        (1024, 1024), (2048, 1024), (1536, 512), (384, 128), (1152, 128),
+        (96, 96), (64, 64), (8, 8), (200, None), (1088, None), (12, None)])
+    def test_tile_sides_are_lane_aligned(self, monkeypatch, length, want):
+        """A side is a multiple of 128 or the whole of a short sequence
+        (the dK/dV kernel slices its row statistics along lanes); any
+        other length is padded to a multiple of 128 by flash_attention."""
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        assert _fa()._pick_block(length) == want
+
+    def test_env_caps_the_tile(self, monkeypatch):
+        fa = _fa()
+        monkeypatch.setenv("HVD_FLASH_BLOCK", "128")
+        assert fa._pick_tiles(1024, 1024, True, "fwd") == (128, 128)
+        assert fa._pick_tiles(1024, 1024, False) == (128, 128)
+        monkeypatch.setenv("HVD_FLASH_BLOCK", "512")
+        assert fa._pick_tiles(1024, 1024, True, "bwd_dq") == (256, 256)
+        assert fa._pick_tiles(1024, 1024, False) == (512, 512)
+
+    def test_gauge_reads_the_schedule_at_1024(self, monkeypatch):
+        """A traced causal call at 1024 sets hvd_flash_tiles to what the
+        schedule function says: visited < total, masked < visited; a
+        non-causal aligned call reads visited = total, masked = 0."""
+        from horovod_tpu import metrics
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+
+        def gauge():
+            series = metrics.snapshot()["hvd_flash_tiles"]["series"]
+            out = {}
+            for s in series:
+                out.setdefault(s["labels"]["kernel"], {})[
+                    s["labels"]["kind"]] = s["value"]
+            return out
+
+        def trace(causal):
+            q = jax.ShapeDtypeStruct((2, 1024, 64), jnp.bfloat16)
+            r = jax.ShapeDtypeStruct((2, 1024), jnp.float32)
+            jax.eval_shape(lambda q, k, v: fa._fa_forward(
+                q, k, v, causal, 0.125), q, q, q)
+            jax.eval_shape(lambda q, k, v, o, lse, do: fa._fa_backward(
+                q, k, v, o, lse, do, causal, 0.125), q, q, q, q, r, q)
+        trace(True)
+        got = gauge()
+        for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+            c = got[kernel]
+            assert c == fa.tile_counts(
+                kernel, 1024, 1024, 0, 1024,
+                *fa._pick_tiles(1024, 1024, True, kernel), True)
+            assert c["masked"] < c["visited"] < c["total"]
+        trace(False)
+        for kernel, c in gauge().items():
+            assert c == {"total": 1, "visited": 1, "masked": 0}, kernel
+
+
+# (lq, lk, q_offset, kv_valid, block_q, block_k, chunk cap): tiles forced
+# small so that skipped, plain and masked tiles are all present; with a
+# chunk cap the grid streams several chunks per axis and every bound is
+# computed from grid variables.
+_TILED = [
+    (128, 128, None, None, 32, 32, None),
+    (128, 128, None, None, 32, 64, None),
+    (128, 128, None, None, 64, 32, None),
+    (128, 128, None, 100, 32, 32, None),       # L = 100 padded to 128
+    (64, 128, None, None, 32, 32, None),       # cross lengths
+    (128, 64, None, None, 32, 32, None),       # fully masked rows (causal)
+    (128, 64, None, 50, 32, 16, None),
+    (64, 64, -64, None, 32, 32, None),         # ring hop wholly over
+    (64, 64, 64, None, 32, 32, None),          # ring hop wholly under
+    (128, 128, None, None, 32, 32, 64),
+    (128, 128, None, 100, 32, 32, 64),
+    (64, 128, None, None, 16, 32, 32),
+    (128, 64, None, 50, 32, 16, 32),
+]
+
+
+class TestTiledKernels:
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("lq,lk,q_offset,kv_valid,bq,bk,chunk", _TILED)
+    def test_forward_and_gradients_match_oracles(
+            self, rng, monkeypatch, lq, lk, q_offset, kv_valid, bq, bk,
+            chunk, causal):
+        """_fa_forward and _fa_backward (called directly: CPU's custom VJP
+        takes the jnp backward) against the jnp oracles."""
+        fa = _fa()
+        if chunk:
+            pick = fa._pick_chunk
+            monkeypatch.setattr(
+                fa, "_pick_chunk",
+                lambda length, block, cap=4096: pick(length, block,
+                                                     min(cap, chunk)))
+        counts = fa.tile_counts(
+            "fwd", lq, lk, lk - lq if q_offset is None else q_offset,
+            lk if kv_valid is None else kv_valid, bq, bk, causal)
+        if causal and q_offset is None and kv_valid is None and lq == lk:
+            assert 0 < counts["masked"] < counts["visited"] \
+                < counts["total"]
+        H, D = 2, 16
+        q = jnp.asarray(rng.standard_normal((H, lq, D)), np.float32)
+        k = jnp.asarray(rng.standard_normal((H, lk, D)), np.float32)
+        v = jnp.asarray(rng.standard_normal((H, lk, D)), np.float32)
+        do = jnp.asarray(rng.standard_normal((H, lq, D)), np.float32)
+        sm = 1.0 / D ** 0.5
+        o, lse = fa._fa_forward(q, k, v, causal, sm, bq, bk, q_offset,
+                                kv_valid)
+        o_ref, lse_ref = fa._jnp_block_fwd(q, k, v, causal, sm, q_offset,
+                                           kv_valid)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=2e-4, atol=2e-5)
+        live = np.asarray(lse_ref) > -1e29      # rows that attend a key
+        np.testing.assert_allclose(np.asarray(lse)[live],
+                                   np.asarray(lse_ref)[live],
+                                   rtol=2e-4, atol=2e-5)
+        got = fa._fa_backward(q, k, v, o_ref, lse_ref, do, causal, sm, bq,
+                              bk, q_offset, kv_valid)
+        want = fa._jnp_block_bwd(q, k, v, o_ref, lse_ref, do, causal, sm,
+                                 q_offset, kv_valid)
+        for a, b, nm in zip(got, want, "q k v".split()):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
+                err_msg=f"d{nm} mismatch (causal={causal})")
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("kv_valid", [None, 100])
+    def test_grouped_kv_through_small_tiles(self, rng, causal, kv_valid):
+        """Narrow K/V streamed by the index map, several tiles a side."""
+        fa = _fa()
+        B, H, KV, L, D = 2, 4, 2, 128, 16
+        q = jnp.asarray(rng.standard_normal((B * H, L, D)), np.float32)
+        k = jnp.asarray(rng.standard_normal((B * KV, L, D)), np.float32)
+        v = jnp.asarray(rng.standard_normal((B * KV, L, D)), np.float32)
+        sm = 1.0 / D ** 0.5
+        o, lse = fa._fa_forward(q, k, v, causal, sm, 32, 32,
+                                kv_valid=kv_valid, heads=H, kv_heads=KV)
+        wide = [fa.gqa_repeat3(t, B, KV, H // KV) for t in (k, v)]
+        o_ref, lse_ref = fa._jnp_block_fwd(q, *wide, causal, sm,
+                                           kv_valid=kv_valid)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   rtol=2e-4, atol=2e-5)
+
+
 class TestScaleKernels:
     def test_scale_buffer(self, rng):
         from horovod_tpu.ops.pallas import scale_buffer

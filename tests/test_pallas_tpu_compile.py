@@ -1,0 +1,76 @@
+"""The flash kernels compiled for a described TPU v5e, without the chip.
+
+The Pallas interpreter (tests/test_pallas.py) checks the kernels'
+arithmetic; it cannot see what mosaic refuses: a slice not aligned to the
+(8, 128) tiling, more scoped VMEM than a kernel may use. These tests lower
+and compile forward and backward at the shapes the models call them with,
+for a chip that is described and not attached. Nothing runs: they say
+nothing about results or times.
+
+The topology is described inside a fixture (never at import: one process
+at a time may load the TPU's library, and every xdist worker imports every
+test file), and all such tests live in this one file.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no compiler here, or another process has it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def fa(monkeypatch):
+    """The kernel module, steered onto its TPU branch (compiled kernels,
+    fused backward): the process's backend is still the CPU."""
+    mod = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(mod, "_interpret", lambda: False)
+    monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+    return mod
+
+
+# (batch, lq, lk, heads, kv heads, head size, causal, dtype)
+_CALLS = {
+    "gpt2_medium_1024_causal": (8, 1024, 1024, 16, 16, 64, True, "bfloat16"),
+    "gpt2_1024_causal_float32": (2, 1024, 1024, 12, 12, 64, True, "float32"),
+    "bert_large_128": (32, 128, 128, 16, 16, 64, False, "bfloat16"),
+    "bert_large_512": (8, 512, 512, 16, 16, 64, False, "bfloat16"),
+    "one_tile_1024_noncausal": (2, 1024, 1024, 16, 16, 64, False, "bfloat16"),
+    "vit_196_padded_to_256": (8, 196, 196, 12, 12, 64, False, "bfloat16"),
+    "short_96_causal": (4, 96, 96, 4, 4, 64, True, "bfloat16"),
+    "causal_300_padded_to_384": (2, 300, 300, 8, 8, 64, True, "bfloat16"),
+    "llama_gqa_2048_causal": (2, 2048, 2048, 16, 4, 128, True, "bfloat16"),
+    "causal_8192_chunked": (1, 8192, 8192, 8, 8, 64, True, "bfloat16"),
+    "noncausal_8192_chunked": (1, 8192, 8192, 8, 8, 64, False, "bfloat16"),
+    "prefill_256_on_1024_keys": (2, 256, 1024, 8, 8, 64, True, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_CALLS))
+def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
+    b, lq, lk, h, kv, d, causal, dtype = _CALLS[call]
+
+    def shape(length, heads):
+        return jax.ShapeDtypeStruct((b, length, heads, d), jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal).astype(
+            jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(lq, h), shape(lk, kv), shape(lk, kv)).compile().as_text()
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert kernel in hlo, f"{kernel} is not in the compiled program"
