@@ -36,7 +36,7 @@ class Norms:
     the rule on tiny gradients see it."""
 
     def __init__(self, shapes, cfg, fused):
-        fresh = weights.params_fn(shapes, cfg["assumed"]["init_std"])
+        fresh = weights.params_fn(shapes, cfg)
 
         def norms(tree):
             out = {}
@@ -115,7 +115,7 @@ def plain(tree):
 def reference_readings(ref, norms, shapes, seed, cfg, batches):
     """The reference's first three steps on ``batches`` (host batches, in
     order): losses, first-gradient norms, parameter-change norms."""
-    params = weights.make_params(shapes, seed, cfg["assumed"]["init_std"])
+    params = weights.make_params(shapes, seed, cfg)
     opt = ref.init_opt(params)
     losses, grad_norms = [], None
     for k, batch in enumerate(batches[:CHECK_STEPS], start=1):

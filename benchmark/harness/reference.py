@@ -1,26 +1,26 @@
-"""Plain float32 reference of both architectures, and its AdamW.
+"""The plain float32 reference: a training step of any architecture of
+``benchmark/archs/``, and its AdamW.
 
 Straightforward ``jax.numpy``: no kernels, no fused buckets, no sharding.
 It imports nothing of ``horovod_tpu`` and takes nothing the program made:
-it states the names and shapes of the parameters itself (``param_shapes``),
-gets their values from the seed (``weights.make_params``) and its batches
-from the seed (``traffic.Batches``).
+the architecture's file states the names and shapes of the parameters
+itself (``param_shapes``), their values come from the seed
+(``weights.make_params``) and the batches from the seed
+(``traffic.Batches``).
 
-Two architectures, told apart by the configuration file's ``arch``:
-
-- ``pre_ln_causal_decoder`` (GPT-2): x + attn(ln(x)), x + mlp(ln(x)), final
-  norm, untied head, next-token cross entropy over positions 0..S-2.
-- ``post_ln_encoder_mlm_nsp`` (BERT): ln(x + attn(x)), ln(x + mlp(x)), MLM
-  head on every position plus NSP head on the pooled first position.
-
-Both follow the program's departures from the published models (tanh gelu,
-norm epsilon 1e-6, fused qkv laid out [q | k | v], no dropout), which the
-configuration files list.
+The architecture is the configuration file's ``arch``, found by name
+(``harness/arch.py`` says what its file states). Here is what every
+architecture shares: the three precisions of a matrix product, the helpers
+an architecture's file may import (``ln``, ``gelu``, ``attention``,
+``xent``), the step in blocks of rows and layer by layer, and AdamW.
 
 So that a step at the timed sizes fits beside nothing else on the chip, a
 step is computed in blocks of rows and layer by layer: the forward pass
 keeps each layer's input, the backward pass walks the layers in reverse and
-recomputes each layer inside its own ``jax.vjp``.
+recomputes each layer inside its own ``jax.vjp``. A layer is of a kind
+(``net.kind_of(i)``); a block is jitted once per kind, so a model whose
+layers differ compiles one program per kind of layer and a model of one
+kind compiles one.
 
 ``operands`` sets the precision of every matrix product: ``float32`` (at
 ``highest``), ``bfloat16`` (what the program states), ``fp8`` (e4m3
@@ -33,7 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import flops
+from . import arch
 
 LN_EPS = 1e-6
 
@@ -43,58 +43,13 @@ LN_EPS = 1e-6
 def param_shapes(cfg):
     """The parameter tree as nested dicts of shape tuples, under the
     program's names."""
-    s = flops.sizes(cfg)
-    h, f, v = s["hidden"], s["inner"], s["vocab_rows"]
-    ln = {"scale": (h,), "bias": (h,)}
-
-    def dense(i, o):
-        return {"kernel": (i, o), "bias": (o,)}
-
-    if cfg["arch"] == "pre_ln_causal_decoder":
-        layer = {
-            "ln_attn": ln, "ln_mlp": ln,
-            "attention": {
-                "qkv": {"shard": dense(h, 3 * h)},
-                "out": {"shard": {"kernel": (h, h)}, "bias": (h,)}},
-            "mlp": {
-                "in": {"shard": dense(h, f)},
-                "out": {"shard": {"kernel": (f, h)}, "bias": (h,)}},
-        }
-        tree = {"embed": {"tok_emb": {"embedding": (v, h)},
-                          "pos_emb": (cfg["n_positions"], h)},
-                "head": {"ln_f": ln, "lm_head": {"kernel": (h, v)}}}
-        for i in range(s["layers"]):
-            tree[f"layer_{i}"] = layer
-        return tree
-    if cfg["arch"] == "post_ln_encoder_mlm_nsp":
-        layer = {
-            "attention": {"qkv": dense(h, 3 * h), "out": dense(h, h)},
-            "ln_attn": ln, "mlp_in": dense(h, f), "mlp_out": dense(f, h),
-            "ln_mlp": ln,
-        }
-        bert = {"tok_emb": {"embedding": (v, h)},
-                "pos_emb": {"embedding": (cfg["max_position_embeddings"], h)},
-                "type_emb": {"embedding": (cfg["type_vocab_size"], h)},
-                "ln_emb": ln, "pooler": dense(h, h)}
-        for i in range(s["layers"]):
-            bert[f"layer_{i}"] = layer
-        return {"bert": bert, "mlm_transform": dense(h, h), "mlm_ln": ln,
-                "mlm_head": dense(h, v), "nsp_head": dense(h, 2)}
-    raise ValueError(f"unknown arch {cfg['arch']!r}")
+    return arch.of(cfg).param_shapes(cfg)
 
 
 def fused_parts(cfg):
     """Leaves that fuse several matrices: path -> equal parts along the last
-    axis. The qkv projection is [q | k | v]."""
-    s = flops.sizes(cfg)
-    out = {}
-    for i in range(s["layers"]):
-        if cfg["arch"] == "pre_ln_causal_decoder":
-            base = (f"layer_{i}", "attention", "qkv", "shard")
-        else:
-            base = ("bert", f"layer_{i}", "attention", "qkv")
-        out[base + ("kernel",)] = out[base + ("bias",)] = 3
-    return out
+    axis."""
+    return arch.of(cfg).fused_parts(cfg)
 
 
 # -- arithmetic --------------------------------------------------------------
@@ -130,7 +85,7 @@ def _mm_fp8_bwd(spec, saved, g):
 _mm_fp8.defvjp(_mm_fp8_fwd, _mm_fp8_bwd)
 
 
-def _mm(operands):
+def product(operands):
     """The matrix product ``einsum(spec, a, b)`` at one precision."""
     if operands == "float32":
         return functools.partial(jnp.einsum,
@@ -146,18 +101,18 @@ def _mm(operands):
     raise ValueError(f"unknown operand precision {operands!r}")
 
 
-def _ln(x, p):
+def ln(x, p):
     mean = jnp.mean(x, -1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)
     return (x - mean) * jax.lax.rsqrt(var + LN_EPS) * p["scale"] + p["bias"]
 
 
-def _gelu(x):
+def gelu(x):
     return 0.5 * x * (1.0 + jnp.tanh(
         0.7978845608028654 * (x + 0.044715 * x ** 3)))
 
 
-def _attention(mm, x, w_qkv, b_qkv, w_out, b_out, heads, causal):
+def attention(mm, x, w_qkv, b_qkv, w_out, b_out, heads, causal):
     b, s, h = x.shape
     d = h // heads
     qkv = mm("bsh,hk->bsk", x, w_qkv) + b_qkv
@@ -171,115 +126,10 @@ def _attention(mm, x, w_qkv, b_qkv, w_out, b_out, heads, causal):
     return mm("bsh,hk->bsk", out, w_out) + b_out
 
 
-def _xent(logits, labels):
+def xent(logits, labels):
     logz = jax.nn.logsumexp(logits, -1)
     picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     return logz - picked
-
-
-class _Decoder:
-    """GPT-2: embed, pre-LN blocks, head + loss. Each method takes its own
-    sub-tree of the parameters."""
-
-    def __init__(self, cfg, mm):
-        self.heads, self.mm = flops.sizes(cfg)["heads"], mm
-        self.layers = flops.sizes(cfg)["layers"]
-
-    def split(self, params):
-        return (params["embed"],
-                [params[f"layer_{i}"] for i in range(self.layers)],
-                params["head"])
-
-    def join(self, embed, layers, head):
-        tree = {"embed": embed, "head": head}
-        tree.update({f"layer_{i}": g for i, g in enumerate(layers)})
-        return tree
-
-    def embed(self, p, batch):
-        ids = batch["ids"]
-        return (p["tok_emb"]["embedding"][ids]
-                + p["pos_emb"][:ids.shape[1]][None])
-
-    def block(self, p, x):
-        a = p["attention"]
-        x = x + _attention(
-            self.mm, _ln(x, p["ln_attn"]), a["qkv"]["shard"]["kernel"],
-            a["qkv"]["shard"]["bias"], a["out"]["shard"]["kernel"],
-            a["out"]["bias"], self.heads, causal=True)
-        m = p["mlp"]
-        y = _gelu(self.mm("bsh,hf->bsf", _ln(x, p["ln_mlp"]),
-                          m["in"]["shard"]["kernel"])
-                  + m["in"]["shard"]["bias"])
-        return x + self.mm("bsf,fh->bsh", y, m["out"]["shard"]["kernel"]) \
-            + m["out"]["bias"]
-
-    def head_loss(self, p, x, batch):
-        """Sum of the next-token losses of these rows, and their count."""
-        logits = self.mm("bsh,hv->bsv", _ln(x, p["ln_f"]),
-                         p["lm_head"]["kernel"])
-        losses = _xent(logits[:, :-1], batch["ids"][:, 1:])
-        return jnp.sum(losses) / losses[0].size
-
-
-class _Encoder:
-    """BERT: embeddings + norm, post-LN blocks, MLM + NSP heads + loss."""
-
-    def __init__(self, cfg, mm):
-        self.heads, self.mm = flops.sizes(cfg)["heads"], mm
-        self.layers = flops.sizes(cfg)["layers"]
-
-    def split(self, params):
-        b = params["bert"]
-        embed = {k: b[k] for k in ("tok_emb", "pos_emb", "type_emb",
-                                   "ln_emb")}
-        head = {k: params[k] for k in ("mlm_transform", "mlm_ln", "mlm_head",
-                                       "nsp_head")}
-        head["pooler"] = b["pooler"]
-        return embed, [b[f"layer_{i}"] for i in range(self.layers)], head
-
-    def join(self, embed, layers, head):
-        head = dict(head)
-        bert = dict(embed, pooler=head.pop("pooler"))
-        bert.update({f"layer_{i}": g for i, g in enumerate(layers)})
-        return dict(head, bert=bert)
-
-    def embed(self, p, batch):
-        ids = batch["ids"]
-        x = (p["tok_emb"]["embedding"][ids]
-             + p["pos_emb"]["embedding"][:ids.shape[1]][None]
-             + p["type_emb"]["embedding"][0])
-        return _ln(x, p["ln_emb"])
-
-    def block(self, p, x):
-        a = p["attention"]
-        x = _ln(x + _attention(
-            self.mm, x, a["qkv"]["kernel"], a["qkv"]["bias"],
-            a["out"]["kernel"], a["out"]["bias"], self.heads, causal=False),
-            p["ln_attn"])
-        y = _gelu(self.mm("bsh,hf->bsf", x, p["mlp_in"]["kernel"])
-                  + p["mlp_in"]["bias"])
-        y = self.mm("bsf,fh->bsh", y, p["mlp_out"]["kernel"]) \
-            + p["mlp_out"]["bias"]
-        return _ln(x + y, p["ln_mlp"])
-
-    def head_loss(self, p, x, batch):
-        """Per row: mean MLM loss over its positions plus its NSP loss,
-        summed over these rows."""
-        pooled = jnp.tanh(self.mm("bh,hk->bk", x[:, 0],
-                                  p["pooler"]["kernel"])
-                          + p["pooler"]["bias"])
-        t = _gelu(self.mm("bsh,hk->bsk", x, p["mlm_transform"]["kernel"])
-                  + p["mlm_transform"]["bias"])
-        mlm = self.mm("bsh,hv->bsv", _ln(t, p["mlm_ln"]),
-                      p["mlm_head"]["kernel"]) + p["mlm_head"]["bias"]
-        nsp = self.mm("bh,hk->bk", pooled, p["nsp_head"]["kernel"]) \
-            + p["nsp_head"]["bias"]
-        return (jnp.sum(jnp.mean(_xent(mlm, batch["mlm"]), -1))
-                + jnp.sum(_xent(nsp, batch["nsp"])))
-
-
-_ARCHS = {"pre_ln_causal_decoder": _Decoder,
-          "post_ln_encoder_mlm_nsp": _Encoder}
 
 
 # -- a training step, in blocks of rows and layer by layer -------------------
@@ -293,33 +143,34 @@ class Reference:
 
     def __init__(self, cfg, operands="float32", rows_per_block=4):
         self.cfg = cfg
-        self.net = _ARCHS[cfg["arch"]](cfg, _mm(operands))
+        self.net = arch.of(cfg).Net(cfg, product(operands))
         self.rows_per_block = rows_per_block
         net = self.net
         self._embed = jax.jit(net.embed)
-        self._block = jax.jit(net.block)
+        self._block = jax.jit(net.block, static_argnums=0)
         self._head = jax.jit(jax.value_and_grad(net.head_loss, (0, 1)))
 
-        def block_vjp(p, x, dy):
-            return jax.vjp(net.block, p, x)[1](dy)
+        def block_vjp(kind, p, x, dy):
+            return jax.vjp(functools.partial(net.block, kind), p, x)[1](dy)
 
         def embed_vjp(p, batch, dy):
             return jax.vjp(lambda q: net.embed(q, batch), p)[1](dy)[0]
 
-        self._block_vjp = jax.jit(block_vjp)
+        self._block_vjp = jax.jit(block_vjp, static_argnums=0)
         self._embed_vjp = jax.jit(embed_vjp)
         self._adam = jax.jit(self._adam_update)
 
     def _rows_loss_and_grad(self, parts, batch):
         """Sum over these rows of the per-row loss, and its gradient."""
         embed_p, layer_ps, head_p = parts
+        kinds = [self.net.kind_of(i) for i in range(len(layer_ps))]
         xs = [self._embed(embed_p, batch)]
-        for p in layer_ps:
-            xs.append(self._block(p, xs[-1]))
+        for kind, p in zip(kinds, layer_ps):
+            xs.append(self._block(kind, p, xs[-1]))
         loss, (g_head, dx) = self._head(head_p, xs.pop(), batch)
         g_layers = []
-        for p in reversed(layer_ps):
-            g, dx = self._block_vjp(p, xs.pop(), dx)
+        for kind, p in zip(reversed(kinds), reversed(layer_ps)):
+            g, dx = self._block_vjp(kind, p, xs.pop(), dx)
             g_layers.append(g)
         g_embed = self._embed_vjp(embed_p, batch, dx)
         return loss, (g_embed, g_layers[::-1], g_head)

@@ -26,6 +26,7 @@ COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
     r"|collective-broadcast")
 _SUFFIX = re.compile(r"([.\-_]\d+)+$")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
 
 
 def op_name(event_name):
@@ -34,10 +35,30 @@ def op_name(event_name):
     return event_name.split(" = ", 1)[0].lstrip("%")
 
 
-class Op(collections.namedtuple("Op", "name start_ns end_ns")):
+def opcode(event_name):
+    """The instruction's opcode out of the same line: the first lower-case
+    word that opens a bracket after the result's shape (``%psum.197 =
+    f32[51511296]{0:T(1024)} all-reduce(f32[...`` -> ``all-reduce``); None
+    where the event's name is not an HLO line."""
+    line = event_name.split(" = ", 1)
+    found = _OPCODE.search(" " + line[1]) if len(line) == 2 else None
+    return found.group(1) if found else None
+
+
+class Op(collections.namedtuple("Op", "name start_ns end_ns opcode",
+                                defaults=(None,))):
     @property
     def seconds(self):
         return (self.end_ns - self.start_ns) * 1e-9
+
+    @property
+    def is_collective(self):
+        """By the instruction's opcode or its name, whichever says so: XLA
+        leaves an all-reduce that it does not combine under the JAX
+        primitive's name (``psum.197``), and wraps some collectives in an
+        ``async-start`` named after them."""
+        return bool(COLLECTIVE.search(self.name)
+                    or COLLECTIVE.search(self.opcode or ""))
 
 
 def find_xplane(trace_dir):
@@ -108,10 +129,10 @@ class Chip:
         return sum(o.seconds for o in self.ops if rx.search(o.name))
 
     def collective_exposed_s(self):
-        coll = [(o.start_ns, o.end_ns) for o in self.ops
-                if COLLECTIVE.search(o.name)]
-        rest = [(o.start_ns, o.end_ns) for o in self.ops
-                if not COLLECTIVE.search(o.name)]
+        coll, rest = [], []
+        for o in self.ops:
+            (coll if o.is_collective else rest).append(
+                (o.start_ns, o.end_ns))
         return _subtract_seconds(coll, rest) if coll else 0.0
 
     def gaps(self):
@@ -140,7 +161,7 @@ class TraceSummary:
             if OPS_LINE not in lines or MODULES_LINE not in lines:
                 continue
             ops = [Op(op_name(e.name), int(e.start_ns),
-                      int(e.start_ns + e.duration_ns))
+                      int(e.start_ns + e.duration_ns), opcode(e.name))
                    for e in lines[OPS_LINE].events]
             modules = [Op(e.name, int(e.start_ns),
                           int(e.start_ns + e.duration_ns))
@@ -176,7 +197,10 @@ class TraceSummary:
         gaps = collections.Counter()
         for c in self.chips:
             for o in c.ops:
-                ops[group_name(o.name)] += o.seconds / len(self.chips)
+                # a collective under what it is: ``psum.197`` with the
+                # ``all-reduce.N`` it is one of
+                kind = o.opcode if o.is_collective and o.opcode else o.name
+                ops[group_name(kind)] += o.seconds / len(self.chips)
             for seconds, before, after in c.gaps():
                 gaps[f"after:{group_name(before)}"] += \
                     seconds / len(self.chips)

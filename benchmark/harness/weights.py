@@ -9,6 +9,8 @@ reference states the same names and shapes on its own
 import jax
 import jax.numpy as jnp
 
+from . import arch
+
 
 def key(seed):
     seed = int(seed)
@@ -38,14 +40,25 @@ def unflatten(items):
     return tree
 
 
-def params_fn(shapes, std):
+def params_fn(shapes, cfg):
     """A traceable ``key -> parameters`` for a tree of shapes: float32, norm
-    scales 1, biases 0, everything else normal(0, std) cut from one vector
-    drawn from the key."""
+    scales 1, biases 0, everything else normal(0, ``assumed.init_std``) cut
+    from one vector drawn from the key. A leaf for which the architecture's
+    file states a rule of its own (``fresh_leaf``: ``harness/arch.py``) is
+    made by that rule, from the key folded with the leaf's position."""
     items = flatten(shapes)
+    std = cfg["assumed"]["init_std"]
+    rule = getattr(arch.of(cfg), "fresh_leaf", None)
+    own = {}
+    if rule is not None:
+        for i, (path, shape) in enumerate(items):
+            fn = rule(cfg, path, shape)
+            if fn is not None:
+                own[path] = (i, fn)
 
     def make(key):
-        random = [(p, s) for p, s in items if p[-1] not in ("scale", "bias")]
+        random = [(p, s) for p, s in items
+                  if p not in own and p[-1] not in ("scale", "bias")]
         total = sum(_size(s) for _, s in random)
         flat = jax.random.normal(key, (total,), jnp.float32) * std
         out, off = {}, 0
@@ -54,7 +67,12 @@ def params_fn(shapes, std):
             out[path] = flat[off:off + n].reshape(shape)
             off += n
         for path, shape in items:
-            if path[-1] == "scale":
+            if path in own:
+                i, fn = own[path]
+                leaf = jnp.asarray(fn(jax.random.fold_in(key, i)),
+                                   jnp.float32)
+                out[path] = jnp.broadcast_to(leaf, shape)
+            elif path[-1] == "scale":
                 out[path] = jnp.ones(shape, jnp.float32)
             elif path[-1] == "bias":
                 out[path] = jnp.zeros(shape, jnp.float32)
@@ -63,9 +81,9 @@ def params_fn(shapes, std):
     return make
 
 
-def make_params(shapes, seed, std):
+def make_params(shapes, seed, cfg):
     """The parameters of ``seed``, made on the device in one jitted call."""
-    return jax.jit(params_fn(shapes, std))(key(seed))
+    return jax.jit(params_fn(shapes, cfg))(key(seed))
 
 
 def _size(shape):

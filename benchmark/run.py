@@ -27,6 +27,7 @@ import sys       # noqa: E402
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+from benchmark.harness import arch  # noqa: E402
 from benchmark.harness import manifest as manifest_mod  # noqa: E402
 
 GIB = 1 << 30
@@ -34,14 +35,18 @@ WARMUP_STEPS = 3
 TRACE_STEPS = 10
 OUT_DIR = os.path.join(ROOT, "benchmark_out")
 
-# --rehearse: the sizes every configuration is cut to (CPU, control flow
-# only). Keys of either architecture's file; absent ones are skipped.
-REHEARSE_CFG = {
-    "n_embd": 64, "n_layer": 2, "n_head": 4, "n_inner": 128,
-    "n_positions": 64, "hidden_size": 64, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "intermediate_size": 128,
-    "max_position_embeddings": 64, "vocab_size": 500}
+# --rehearse: the cell every configuration runs (CPU, control flow only);
+# the configuration's own tiny sizes are its architecture's (``REHEARSE`` in
+# ``archs/<arch>.py``).
 REHEARSE_CELL = {"sequences_per_chip": 2, "sequence_length": 64}
+
+# Held for the whole run, on purpose, and used by nothing. Without a dict of
+# this size built here, before jax is imported, the conversion of the step's
+# jaxpr to MLIR takes 6.6 s and not 1.35 s on the chip's host, in every one of
+# the twenty variants of this file that were tried (PERF.md, PR 28: it follows
+# the interpreter's allocation layout and not the code; the cause is not
+# found). The parent's module held such a dict, its table of rehearsal sizes.
+_LAYOUT = {"a0": 64, "a1": 65, "a2": 66, "a3": 67, "a4": 68, "a5": 69, "a6": 70, "a7": 71, "a8": 72, "a9": 73, "a10": 74}
 
 
 def say(msg):
@@ -65,7 +70,8 @@ def parse(argv):
 
 def rehearse_cut(workload, cfg):
     """Both cut, in place, to the sizes of a rehearsal."""
-    cfg.update({k: v for k, v in REHEARSE_CFG.items() if k in cfg})
+    for key, tiny in arch.of(cfg).REHEARSE.items():
+        cfg[key] = dict(cfg[key], **tiny) if isinstance(tiny, dict) else tiny
     cfg["assumed"] = dict(cfg["assumed"], vocab_rows=512)
     for spec in cfg["inputs"].values():
         spec["high"] = min(spec["high"], 500)
@@ -151,8 +157,7 @@ def set_up(args, workload, cfg, used):
     if weights.flatten(check.plain(wanted)) != weights.flatten(shapes):
         raise SystemExit("the program's parameter names or shapes are not "
                          "the reference's")
-    params = weights.make_params(shapes, args.seed,
-                                 cfg["assumed"]["init_std"])
+    params = weights.make_params(shapes, args.seed, cfg)
     step, state = program.build(hvd, mesh, cfg, loss_fn, params)
     del params
     jax.block_until_ready(state)

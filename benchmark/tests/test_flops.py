@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from benchmark.harness import flops, peaks
+from benchmark.harness import arch, flops, peaks
 from conftest import ROOT
 
 
@@ -22,7 +22,7 @@ def test_gpt2_medium_counts_by_hand():
     block = 3_145_728 + 1_048_576 + 8_388_608
     assert block == 12_582_912
     head = 1024 * 50304
-    assert flops.matmul_params(cfg) == (24 * block + head, 0)
+    assert arch.of(cfg).matmul_params(cfg) == 24 * block + head
     assert 24 * block + head == 353_501_184
     tokens = 8 * 1024
     matmul = 6 * 353_501_184 * tokens             # 17.375 TFLOP
@@ -47,13 +47,20 @@ def test_gpt2_medium_counts_by_hand():
 
 def test_bert_large_counts():
     cfg = _cfg("bert_large")
-    per_token, per_seq = flops.matmul_params(cfg)
+    per_token, per_seq = arch.of(cfg).matmul_params(cfg)
     assert per_token == 24 * 12_582_912 + 1024 * 1024 + 1024 * 30522
     assert per_seq == 1024 * 1024 + 2 * 1024
     total = flops.step_flops(cfg, 32, 128)
     assert total == pytest.approx(8.37e12, rel=1e-3)
-    share = flops.attention_flops_per_token(cfg, 128) * 4096 / total
+    share = arch.of(cfg).attention_flops_per_token(cfg, 128) * 4096 / total
     assert share < 0.03     # the cell's why: attention under 3% of FLOPs
+
+
+def test_least_seconds_takes_the_larger_bound_of_each_pass():
+    row = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
+    work = {"fwd": {"flops": 200, "bytes": 10},     # 2 s by FLOPs, 1 by bytes
+            "bwd": {"flops": 100, "bytes": 50}}     # 1 s by FLOPs, 5 by bytes
+    assert flops.least_seconds(work, row) == pytest.approx(7.0)
 
 
 def test_unknown_device_kind_raises():

@@ -44,7 +44,7 @@ def test_loss_and_gradient_match_the_program(config):
     cfg["assumed"]["compute_dtype"] = "float32"
     model, loss_fn = program.load_model_builder(cfg["model"])(cfg)
     shapes = reference.param_shapes(cfg)
-    params = weights.make_params(shapes, 7, cfg["assumed"]["init_std"])
+    params = weights.make_params(shapes, 7, cfg)
     batch = traffic.Batches(cfg, workload, 7).next()
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.value_and_grad(loss_fn)(

@@ -74,7 +74,6 @@ def main(argv=None):
     norms = check.Norms(shapes, cfg, reference.fused_parts(cfg))
     refs = {p: reference.Reference(cfg, p)
             for p in ("float32", "bfloat16", "fp8")}
-    std = cfg["assumed"]["init_std"]
     compiled, out = None, []
     per_chip = workload["sequences_per_chip"]
 
@@ -94,7 +93,7 @@ def main(argv=None):
         if not args.reference_only:
             step, state = program.build(
                 hvd, mesh, cfg, loss_fn,
-                weights.make_params(shapes, seed, std))
+                weights.make_params(shapes, seed, cfg))
             if compiled is None:
                 compiled, secs = program.compile_step(
                     step, state, shard_batch(batches[0], mesh))

@@ -6,11 +6,14 @@
 Keeps, of every TPU plane, the lines the reduction reads (``Steps``,
 ``XLA Modules``, ``XLA Ops``, ``Async XLA Ops``) for the first ``steps``
 (default 2) runs of the step's module plus the few ops of the next run that
-start within 2 us, so that the window's cut is exercised; event names are
-cut to the instruction's name. Writes ``<out prefix>.xplane.pb.gz`` and
-``<out prefix>.expected.json``: the numbers the reduction has to give,
+start within 2 us, so that the window's cut is exercised; event names (on
+a TPU the whole HLO line) are cut to ``<instruction> = <opcode>(``, the
+result's shape and the operands left out. Writes ``<out prefix>.xplane.pb.gz``
+and ``<out prefix>.expected.json``: the numbers the reduction has to give,
 worked out here straight from the protobuf, by other code than the
-reduction's. Needs tensorflow's ``xplane_pb2`` (a tool, not part of a run).
+reduction's: a collective is here what the profiler's own ``hlo_category``
+of the instruction calls one (the reduction reads the opcode out of the
+line). Needs tensorflow's ``xplane_pb2`` (a tool, not part of a run).
 """
 
 import gzip
@@ -25,6 +28,33 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
 KEEP = ("Steps", "XLA Modules", "XLA Ops", "Async XLA Ops")
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter"
                         r"|collective-permute|all-to-all")
+CATEGORY_STAT = "hlo_category"
+
+
+def cut_line(line):
+    """``%psum.197 = f32[8]{0:T(8)} all-reduce(f32[8] %x), ...`` ->
+    ``%psum.197 = all-reduce(``: the shape ends at the first space outside
+    every bracket, the opcode at the bracket after it."""
+    name, _, rest = line.partition(" = ")
+    depth = 0
+    for i, c in enumerate(rest):
+        depth += (c in "([{") - (c in ")]}")
+        if c == " " and depth == 0:
+            return f"{name} = {rest[i + 1:].split('(', 1)[0]}("
+    return name
+
+
+def categories(plane):
+    """{event metadata id: the profiler's ``hlo_category``}."""
+    wanted = {k for k, m in plane.stat_metadata.items()
+              if m.name == CATEGORY_STAT}
+    out = {}
+    for key, meta in plane.event_metadata.items():
+        for stat in meta.stats:
+            if stat.metadata_id in wanted:
+                out[key] = stat.str_value or \
+                    plane.stat_metadata[stat.ref_value].name
+    return out
 
 
 def union(intervals):
@@ -76,23 +106,26 @@ def main(argv):
         for k in used:
             meta = plane.event_metadata[k]
             new.event_metadata[k].id = meta.id
-            new.event_metadata[k].name = meta.name.split(" = ")[0]
+            new.event_metadata[k].name = cut_line(meta.name)
         start = runs[0].offset_ps
         end = runs[steps - 1].offset_ps + runs[steps - 1].duration_ps
+        category = categories(plane)
         ops = [(e.offset_ps, e.offset_ps + e.duration_ps,
-                plane.event_metadata[e.metadata_id].name.split(" = ")[0])
+                plane.event_metadata[e.metadata_id].name.split(" = ")[0],
+                bool(COLLECTIVE.search(category.get(e.metadata_id, ""))))
                for e in lines["XLA Ops"].events if e.offset_ps < cut
                + 2_000_000]
         inside = [o for o in ops if o[0] >= start and o[1] <= end]
-        coll = [(a, b) for a, b, n in inside if COLLECTIVE.search(n)]
-        rest = [(a, b) for a, b, n in inside if not COLLECTIVE.search(n)]
+        coll = [(a, b) for a, b, _, c in inside if c]
+        rest = [(a, b) for a, b, _, c in inside if not c]
         expected["chips"].append({
             "plane": plane.name, "steps": steps, "window_ps": end - start,
-            "busy_ps": union((a, b) for a, b, _ in inside),
-            "flash_ps": sum(b - a for a, b, n in inside
+            "busy_ps": union((a, b) for a, b, _, _ in inside),
+            "flash_ps": sum(b - a for a, b, n, _ in inside
                             if "hvd_flash_" in n),
-            "flash_calls": sum(1 for _, _, n in inside
+            "flash_calls": sum(1 for _, _, n, _ in inside
                                if "hvd_flash_" in n),
+            "collectives": sorted(n for _, _, n, c in inside if c),
             "collective_ps": sum(b - a for a, b in coll),
             "collective_exposed_ps": union(coll + rest) - union(rest),
             "ops_total": len(ops), "ops_inside": len(inside)})
