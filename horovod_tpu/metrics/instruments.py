@@ -69,6 +69,14 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   (batch, head) of the last traced flash-attention call
   (kernel=fwd|bwd_dq|bwd_dkv; kind=total|visited|masked, masked = visited
   with mask code; gauge, set while the call is traced)
+- ``hvd_moe_experts{kind}``                         experts of the last
+  traced ``parallel.moe.DroplessMoE`` call (kind=routed|held|per_token:
+  the router's width, the experts this layer holds, the experts a token
+  is routed to; gauge, set while the call is traced)
+- ``hvd_moe_buffer_rows_per_token{axis_size}``      rows the grouped
+  product's buffer of that call carries, per token of the call (gauge, as
+  above; axis_size = chips the experts are exchanged over, 1 without an
+  exchange)
 - ``autopilot_decisions_total{lever,outcome}``      autopilot control
   decisions (lever=tuner|overlap|cross_wire|remediate; counter)
 - ``autopilot_remediations_total{cause,outcome}``   autopilot-initiated
@@ -310,6 +318,20 @@ FLASH_TILES = REGISTRY.gauge(
     "the padding edge crosses the tile). From the function that gives "
     "the kernels their loop bounds. Set while the call is traced.",
     ("kernel", "kind"))
+MOE_EXPERTS = REGISTRY.gauge(
+    "hvd_moe_experts",
+    "Experts of the last traced DroplessMoE call, by kind: routed (the "
+    "router's width), held (the experts this layer holds) and per_token "
+    "(the experts a token is routed to). Set while the call is traced.",
+    ("kind",))
+MOE_BUFFER_ROWS_PER_TOKEN = REGISTRY.gauge(
+    "hvd_moe_buffer_rows_per_token",
+    "Rows the grouped product's buffer of the last traced DroplessMoE "
+    "call carries, per token of the call: the rows expected are "
+    "per_token x held / routed, the rest is room for imbalance. By the "
+    "chips the experts are exchanged over (1 without an exchange). Set "
+    "while the call is traced.",
+    ("axis_size",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -657,6 +679,18 @@ def record_fused_allreduce(axis_size, buckets, nbytes):
         return
     FUSED_ALLREDUCE_BUCKETS.labels(axis_size).set(buckets)
     FUSED_ALLREDUCE_BYTES.labels(axis_size).set(nbytes)
+
+
+def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
+                     axis_size=1):
+    """What one trace of ``parallel.moe.DroplessMoE`` makes: known while
+    the call is traced, so set there once and not per step."""
+    if not _enabled:
+        return
+    for kind, n in (("routed", routed), ("held", held),
+                    ("per_token", per_token)):
+        MOE_EXPERTS.labels(kind).set(n)
+    MOE_BUFFER_ROWS_PER_TOKEN.labels(axis_size).set(buffer_rows / tokens)
 
 
 def record_flash_tiles(kernel, counts):

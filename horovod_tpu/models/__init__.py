@@ -8,6 +8,9 @@ from horovod_tpu.models.vgg import VGG, VGG11, VGG13, VGG16, VGG19  # noqa: F401
 from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.vit import ViT, ViTConfig  # noqa: F401
 from horovod_tpu.models.llama import Llama, LlamaBlock, LlamaConfig  # noqa: F401
+from horovod_tpu.models.smallthinker import (  # noqa: F401
+    SmallThinker, SmallThinkerBlock, SmallThinkerConfig,
+)
 from horovod_tpu.models.t5 import (  # noqa: F401
     T5, T5Config, t5_beam_decode, t5_generate, t5_greedy_decode,
 )

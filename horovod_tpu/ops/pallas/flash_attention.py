@@ -8,7 +8,10 @@ the (L, L) score matrix, and every matmul lands on the MXU as a
 (block_q x D) @ (D x block_k) tile. Which tiles a kernel visits, and on
 which it runs mask code, is the tile schedule (_key_tile_bounds,
 _query_tile_bounds): a causal call skips the tiles over the diagonal and
-masks only the tiles the diagonal or the padding edge crosses.
+masks only the tiles the diagonal or the padding edge crosses; with a
+sliding ``window`` it also skips the tiles wholly behind the window and
+masks the tiles the window's edge crosses (_key_window_bounds,
+_query_window_bounds).
 
 The reference framework has no attention code (SURVEY.md §5.7 — Horovod
 operates below the model level); this kernel is part of the TPU build's
@@ -141,7 +144,8 @@ def _pick_chunk(length, block, cap=4096):
 # ---------------------------------------------------------------------------
 # The tile schedule: which (block_q, block_k) tiles of the score matrix a
 # kernel visits and which of those need mask code. Element (i, j) is allowed
-# iff j < kv_valid and (not causal or j <= i + q_offset). These two
+# iff j < kv_valid and (not causal or j <= i + q_offset) and (no window or
+# j > i + q_offset - window: the query counts among its window). These
 # functions give the kernels their loop bounds AND the hvd_flash_tiles gauge
 # its counts; tile indices may be Python ints (static bounds) or traced
 # scalars (the chunk index is a grid variable).
@@ -157,6 +161,18 @@ def _select(cond, a, b):
     if isinstance(cond, bool):
         return a if cond else b
     return jnp.where(cond, a, b)
+
+
+def _least(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
+def _most(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
 
 
 def _key_tile_bounds(q0, block_q, block_k, n_kt, q_offset, kv_valid, causal):
@@ -192,30 +208,97 @@ def _query_tile_bounds(k0, block_q, block_k, n_qt, q_offset, kv_valid,
     return t_first, t_plain
 
 
-def _in_chunk(bounds, c, tpc):
-    """Tile bounds along a whole axis, as indices into its chunk ``c`` of
-    ``tpc`` tiles."""
-    return tuple(_clip(b - c * tpc, 0, tpc) for b in bounds)
+def _key_window_bounds(q0, block_q, block_k, n_kt, q_offset, window):
+    """The same row of tiles against the window's edge. Returns (n_skip,
+    n_inside): tiles [0, n_skip) lie wholly behind the window of every row
+    and are not visited, [n_skip, n_inside) are crossed by the edge,
+    [n_inside, n_kt) lie wholly on the window's side of it."""
+    # The first row (whose window reaches back furthest) sees keys >
+    # q0 + q_offset - window, the last keys > q0 + block_q - 1 + q_offset
+    # - window.
+    n_skip = _clip((q0 + q_offset - window + 1) // block_k, 0, n_kt)
+    n_inside = _clip(-(-(q0 + block_q + q_offset - window) // block_k),
+                     0, n_kt)
+    return n_skip, n_inside
+
+
+def _query_window_bounds(k0, block_q, block_k, n_qt, q_offset, window):
+    """The same column of tiles against the window's edge. Returns
+    (t_inside, t_end): tiles [0, t_inside) lie wholly on the window's side
+    of the edge, [t_inside, t_end) are crossed by it, [t_end, n_qt) see
+    none of these keys and are not visited."""
+    # Key k0 (which leaves the window first) is seen by rows < k0 + window
+    # - q_offset, key k0 + block_k - 1 by rows < k0 + block_k - 1 + window
+    # - q_offset.
+    t_inside = _clip((k0 + window - q_offset) // block_q, 0, n_qt)
+    t_end = _clip(-(-(k0 + block_k - 1 + window - q_offset) // block_q),
+                  0, n_qt)
+    return t_inside, t_end
+
+
+def _key_sweeps(q0, block_q, block_k, n_kt, q_offset, kv_valid, causal,
+                window):
+    """(n_skip, n_low, n_plain, n_vis) of one row of tiles: [n_skip, n_low)
+    is swept with mask code (the window's edge), [n_low, n_plain) without,
+    [n_plain, n_vis) with (the diagonal, the padding edge); nothing else is
+    visited. A tile that both edges cross is in a masked sweep. Without a
+    window the first sweep is empty: 0, 0, and _key_tile_bounds' two."""
+    n_plain, n_vis = _key_tile_bounds(q0, block_q, block_k, n_kt, q_offset,
+                                      kv_valid, causal)
+    if window is None:
+        return 0, 0, n_plain, n_vis
+    n_skip, n_low = (_least(b, n_vis) for b in _key_window_bounds(
+        q0, block_q, block_k, n_kt, q_offset, window))
+    return n_skip, n_low, _most(n_plain, n_low), n_vis
+
+
+def _query_sweeps(k0, block_q, block_k, n_qt, q_offset, kv_valid, causal,
+                  window):
+    """(t_first, t_plain, t_high, t_end) of one column of tiles:
+    [t_first, t_plain) is swept with mask code (the diagonal, the padding
+    edge), [t_plain, t_high) without, [t_high, t_end) with (the window's
+    edge). Without a window the last sweep is empty: t_high = t_end =
+    n_qt."""
+    t_first, t_plain = _query_tile_bounds(k0, block_q, block_k, n_qt,
+                                          q_offset, kv_valid, causal)
+    if window is None:
+        return t_first, t_plain, n_qt, n_qt
+    t_inside, t_end = _query_window_bounds(k0, block_q, block_k, n_qt,
+                                           q_offset, window)
+    t_end = _most(t_end, t_first)
+    t_plain = _least(t_plain, t_end)
+    return t_first, t_plain, _most(_least(t_inside, t_end), t_plain), t_end
+
+
+def _in_chunk(bounds, c, tpc, n_tiles):
+    """Tile bounds along a whole axis of ``n_tiles``, as indices into its
+    chunk ``c`` of ``tpc`` tiles. A static bound at the axis's start or end
+    stays static at every chunk's start or end, so that a sweep that is
+    empty on the whole axis (no window) is no code in any chunk."""
+    def one(b):
+        if isinstance(b, int) and b in (0, n_tiles):
+            return 0 if b == 0 else tpc
+        return _clip(b - c * tpc, 0, tpc)
+    return tuple(one(b) for b in bounds)
 
 
 def tile_counts(kernel, lq, lk, q_offset, kv_valid, block_q, block_k,
-                causal):
+                causal, window=None):
     """Score tiles per (batch, head) of one call of ``kernel``: ``total``,
     ``visited`` and ``masked`` (visited with mask code). The forward and dQ
     kernels sweep rows of tiles, the dK/dV kernel columns."""
     n_qt, n_kt = lq // block_q, lk // block_k
     if kernel == "bwd_dkv":
-        cols = [_query_tile_bounds(j * block_k, block_q, block_k, n_qt,
-                                   q_offset, kv_valid, causal)
-                for j in range(n_kt)]
-        visited = sum(n_qt - first for first, _ in cols)
-        masked = sum(plain - first for first, plain in cols)
+        sweeps = [_query_sweeps(j * block_k, block_q, block_k, n_qt,
+                                q_offset, kv_valid, causal, window)
+                  for j in range(n_kt)]
     else:
-        rows = [_key_tile_bounds(i * block_q, block_q, block_k, n_kt,
-                                 q_offset, kv_valid, causal)
-                for i in range(n_qt)]
-        visited = sum(vis for _, vis in rows)
-        masked = sum(vis - plain for plain, vis in rows)
+        sweeps = [_key_sweeps(i * block_q, block_q, block_k, n_kt,
+                              q_offset, kv_valid, causal, window)
+                  for i in range(n_qt)]
+    # Either way the four bounds are: masked, plain, masked.
+    visited = sum(d - a for a, _, _, d in sweeps)
+    masked = sum((b - a) + (d - c) for a, b, c, d in sweeps)
     return {"total": n_qt * n_kt, "visited": visited, "masked": masked}
 
 
@@ -254,13 +337,15 @@ def _when(cond, fn):
         pl.when(cond)(fn)
 
 
-def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, q_axis=0):
+def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, q_axis=0,
+                window=None):
     """Combined causal + key-validity masking for one score tile, (BQ, BK)
     or, with ``q_axis=1``, its transpose.
 
     ``masked`` (static) is True when the key axis was padded to a block
     multiple: keys at global position >= kv_valid are padding and must not
     receive weight. ``q0``/``k0`` are the tile's global row/key offsets.
+    ``window`` (causal only) keeps the keys j with q - window < j <= q.
     """
     if not (causal or masked):
         return s
@@ -271,20 +356,23 @@ def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, q_axis=0):
     if causal:
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
         c = q_pos >= k_pos
+        if window is not None:
+            c &= k_pos > q_pos - window
         ok = c if ok is None else ok & c
     return jnp.where(ok, s, NEG_INF)
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                *, sm_scale, causal, block_q, block_k, q_chunk, k_chunk,
-               q_offset, n_qc, n_kc, kv_valid, masked):
+               q_offset, n_qc, n_kc, kv_valid, masked, window):
     """One (query-chunk, key-chunk) grid step of the online softmax.
 
     The key-chunk sweep is the INNERMOST grid dimension; the running
     (m, l, acc) state lives in VMEM scratch across chunk steps and in
     registers within a query tile's sweep over the chunk's key tiles:
-    first the tiles wholly under the diagonal and inside kv_valid (no
-    mask code in the loop body), then those the mask edge crosses.
+    with a window first the tiles its edge crosses, then the tiles wholly
+    under the diagonal and inside kv_valid (no mask code in the loop
+    body), then those the diagonal or the padding edge crosses.
     """
     # A grid axis of one chunk gives a STATIC chunk index, and with both
     # static every loop bound below is a compile-time constant.
@@ -302,11 +390,12 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     for tq in range(q_chunk // block_q):
         rows = pl.ds(tq * block_q, block_q)
         q0 = ic * q_chunk + tq * block_q           # first row, this tile
-        n_plain, n_vis = _in_chunk(
-            _key_tile_bounds(q0, block_q, block_k, n_kc * tpc, q_offset,
-                             kv_valid, causal), jc, tpc)
+        n_skip, n_low, n_plain, n_vis = _in_chunk(
+            _key_sweeps(q0, block_q, block_k, n_kc * tpc, q_offset,
+                        kv_valid, causal, window), jc, tpc, n_kc * tpc)
 
-        def _compute(rows=rows, q0=q0, n_plain=n_plain, n_vis=n_vis):
+        def _compute(rows=rows, q0=q0, n_skip=n_skip, n_low=n_low,
+                     n_plain=n_plain, n_vis=n_vis):
             q = q_ref[0, rows, :].astype(jnp.float32) * sm_scale  # (BQ, D)
 
             def body(t, carry, crossed):
@@ -323,7 +412,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                     s = _apply_mask(s, causal=causal, masked=masked,
                                     q0=q_offset + q0,
                                     k0=jc * k_chunk + t * block_k,
-                                    kv_valid=kv_valid)
+                                    kv_valid=kv_valid, window=window)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 corr = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new[:, None])
@@ -338,7 +427,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                 return m_new, l_new, acc_new
 
             carry = (m_ref[rows, 0], l_ref[rows, 0], acc_ref[rows, :])
-            carry = _sweep(0, n_plain,
+            carry = _sweep(n_skip, n_low,
+                           functools.partial(body, crossed=True), carry)
+            carry = _sweep(n_low, n_plain,
                            functools.partial(body, crossed=False), carry)
             m, l, acc = _sweep(n_plain, n_vis,
                                functools.partial(body, crossed=True), carry)
@@ -346,7 +437,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             l_ref[rows, :] = l[:, None]
             acc_ref[rows, :] = acc
 
-        _when(n_vis > 0, _compute)
+        _when(n_vis > n_skip, _compute)
 
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)            # (q_chunk, 1)
@@ -360,13 +451,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
 
 def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
-                q_offset=None, kv_valid=None, heads=None, kv_heads=None):
+                q_offset=None, kv_valid=None, heads=None, kv_heads=None,
+                window=None):
     """(B*H, Lq, D) x (B*KV, Lk, D)^2 -> (o, lse).
 
     ``block_q``/``block_k`` default to :func:`_pick_tiles`' choice for
     the forward kernel. ``q_offset``/``kv_valid`` override the end-aligned
     causal offset and the number of VALID keys when the inputs were padded
     to block multiples (positions are always in ORIGINAL coordinates).
+    ``window`` (causal only): row i sees keys j with i + q_offset - window
+    < j <= i + q_offset.
 
     Grouped-query attention: with ``kv_heads < heads`` the K/V tensors
     carry only the grouped heads and the kernel streams each kv head's
@@ -379,15 +473,17 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
     _record_tiles("fwd", lq, lk, q_offset, kv_valid, block_q, block_k,
-                  causal)
+                  causal, window)
     gqa = heads is not None and kv_heads is not None and heads != kv_heads
     return _fwd_call(
         q, k, v, causal=causal, sm_scale=sm_scale,
         tiles=(block_q, block_k),
         chunks=(_pick_chunk(lq, block_q, _OUTER_CHUNK),
                 _pick_chunk(lk, block_k)),
-        q_offset=q_offset, kv_valid=kv_valid,
+        q_offset=q_offset, kv_valid=kv_valid, window=window,
         group=(heads, kv_heads) if gqa else None, interpret=_interpret())
 
 
@@ -397,12 +493,12 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
 # to trace, and traced per layer the three kernels added 50 s to the set-up
 # of gpt2m_1chip (PERF.md, PR 27).
 _CALL_STATICS = ("causal", "sm_scale", "tiles", "chunks", "q_offset",
-                 "kv_valid", "interpret")
+                 "kv_valid", "window", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("group",))
 def _fwd_call(q, k, v, *, causal, sm_scale, tiles, chunks, q_offset,
-              kv_valid, group, interpret):
+              kv_valid, window, group, interpret):
     bh, lq, d = q.shape
     lk = k.shape[1]
     (block_q, block_k), (q_chunk, k_chunk) = tiles, chunks
@@ -420,7 +516,8 @@ def _fwd_call(q, k, v, *, causal, sm_scale, tiles, chunks, q_offset,
                                block_q=block_q, block_k=block_k,
                                q_chunk=q_chunk, k_chunk=k_chunk,
                                q_offset=q_offset, n_qc=n_qc, n_kc=n_kc,
-                               kv_valid=kv_valid, masked=kv_valid < lk)
+                               kv_valid=kv_valid, masked=kv_valid < lk,
+                               window=window)
     vma = _vma(q, k, v)
     o, lse = pl.pallas_call(
         kernel,
@@ -447,9 +544,11 @@ def _fwd_call(q, k, v, *, causal, sm_scale, tiles, chunks, q_offset,
     return o, lse[:, 0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, sm_scale, block_q=None, block_k=None,
-           q_offset=None, kv_valid=None, heads=None, kv_heads=None):
+           q_offset=None, kv_valid=None, heads=None, kv_heads=None,
+           window=None):
     """``block_q``/``block_k`` None: each kernel takes its own tile shape
     from :func:`_pick_tiles`.
 
@@ -459,25 +558,28 @@ def _flash(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     backward broadcasts once and group-sums dK/dV — forward/serving
     bandwidth is where GQA pays."""
     o, _ = _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                       q_offset, kv_valid, heads=heads, kv_heads=kv_heads)
+                       q_offset, kv_valid, heads=heads, kv_heads=kv_heads,
+                       window=window)
     return o
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q=None, block_k=None,
-               q_offset=None, kv_valid=None, heads=None, kv_heads=None):
+               q_offset=None, kv_valid=None, heads=None, kv_heads=None,
+               window=None):
     o, lse = _fa_forward(q, k, v, causal, sm_scale, block_q, block_k,
-                         q_offset, kv_valid, heads=heads, kv_heads=kv_heads)
+                         q_offset, kv_valid, heads=heads, kv_heads=kv_heads,
+                         window=window)
     return o, (q, k, v, o, lse)
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, acc_ref, *, sm_scale, causal, block_q,
                       block_k, q_chunk, k_chunk, q_offset, n_qc, n_kc,
-                      kv_valid, masked):
+                      kv_valid, masked, window):
     """dQ pass: (query-chunk, key-chunk) grid with the dq accumulator in
-    scratch across key chunks; per query tile the same two register
-    sweeps over the chunk's key tiles as _fa_kernel (plain, then
-    crossed)."""
+    scratch across key chunks; per query tile the same register sweeps
+    over the chunk's key tiles as _fa_kernel (the window's edge, plain,
+    the diagonal)."""
     ic = 0 if n_qc == 1 else pl.program_id(1)
     jc = 0 if n_kc == 1 else pl.program_id(2)
     tpc = k_chunk // block_k
@@ -490,11 +592,12 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     for tq in range(q_chunk // block_q):
         rows = pl.ds(tq * block_q, block_q)
         q0 = ic * q_chunk + tq * block_q
-        n_plain, n_vis = _in_chunk(
-            _key_tile_bounds(q0, block_q, block_k, n_kc * tpc, q_offset,
-                             kv_valid, causal), jc, tpc)
+        n_skip, n_low, n_plain, n_vis = _in_chunk(
+            _key_sweeps(q0, block_q, block_k, n_kc * tpc, q_offset,
+                        kv_valid, causal, window), jc, tpc, n_kc * tpc)
 
-        def _compute(rows=rows, q0=q0, n_plain=n_plain, n_vis=n_vis):
+        def _compute(rows=rows, q0=q0, n_skip=n_skip, n_low=n_low,
+                     n_plain=n_plain, n_vis=n_vis):
             q = q_ref[0, rows, :].astype(jnp.float32)              # (BQ, D)
             do = do_ref[0, rows, :].astype(jnp.float32)
             lse = lse_ref[0, 0, rows]                              # (BQ,)
@@ -512,7 +615,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     s = _apply_mask(s, causal=causal, masked=masked,
                                     q0=q_offset + q0,
                                     k0=jc * k_chunk + t * block_k,
-                                    kv_valid=kv_valid)
+                                    kv_valid=kv_valid, window=window)
                     p = jnp.where(s > NEG_INF * 0.5,
                                   jnp.exp(s - lse[:, None]), 0.0)
                 else:
@@ -524,12 +627,14 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     ds, kb, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
 
-            dq = _sweep(0, n_plain, functools.partial(body, crossed=False),
+            dq = _sweep(n_skip, n_low, functools.partial(body, crossed=True),
                         acc_ref[rows, :])
+            dq = _sweep(n_low, n_plain,
+                        functools.partial(body, crossed=False), dq)
             acc_ref[rows, :] = _sweep(
                 n_plain, n_vis, functools.partial(body, crossed=True), dq)
 
-        _when(n_vis > 0, _compute)
+        _when(n_vis > n_skip, _compute)
 
     def _finalize():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
@@ -540,12 +645,12 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
                        block_q, block_k, q_chunk, k_chunk, q_offset, n_qc,
-                       n_kc, kv_valid, masked):
+                       n_kc, kv_valid, masked, window):
     """dK/dV pass: (key-chunk, query-chunk) grid; per-key-chunk
-    accumulators in scratch across query chunks; per key tile two register
+    accumulators in scratch across query chunks; per key tile register
     sweeps over the chunk's query tiles: first those the mask edge
     crosses (the diagonal comes first going down a column), then the
-    plain ones under it.
+    plain ones under it, then with a window those its edge crosses.
 
     The tile is computed TRANSPOSED, (BK, BQ) = k q^T, so that both
     accumulating products (p^T dO, ds^T q) contract the tile's lane axis
@@ -566,11 +671,12 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     for tk in range(k_chunk // block_k):
         cols = pl.ds(tk * block_k, block_k)
         k0 = ic * k_chunk + tk * block_k
-        t_first, t_plain = _in_chunk(
-            _query_tile_bounds(k0, block_q, block_k, n_qc * tpc, q_offset,
-                               kv_valid, causal), jc, tpc)
+        t_first, t_plain, t_high, t_end = _in_chunk(
+            _query_sweeps(k0, block_q, block_k, n_qc * tpc, q_offset,
+                          kv_valid, causal, window), jc, tpc, n_qc * tpc)
 
-        def _compute(cols=cols, k0=k0, t_first=t_first, t_plain=t_plain):
+        def _compute(cols=cols, k0=k0, t_first=t_first, t_plain=t_plain,
+                     t_high=t_high, t_end=t_end):
             kb = k_ref[0, cols, :].astype(jnp.float32)             # (BK, D)
             vb = v_ref[0, cols, :].astype(jnp.float32)
 
@@ -587,7 +693,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 if crossed:
                     s = _apply_mask(s, causal=causal, masked=masked,
                                     q0=q_offset + jc * q_chunk + t * block_q,
-                                    k0=k0, kv_valid=kv_valid, q_axis=1)
+                                    k0=k0, kv_valid=kv_valid, q_axis=1,
+                                    window=window)
                     p = jnp.where(s > NEG_INF * 0.5,
                                   jnp.exp(s - lse_b), 0.0)
                 else:
@@ -606,12 +713,14 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             carry = _sweep(t_first, t_plain,
                            functools.partial(body, crossed=True),
                            (dk_acc[cols, :], dv_acc[cols, :]))
-            dk, dv = _sweep(t_plain, tpc,
-                            functools.partial(body, crossed=False), carry)
+            carry = _sweep(t_plain, t_high,
+                           functools.partial(body, crossed=False), carry)
+            dk, dv = _sweep(t_high, t_end,
+                            functools.partial(body, crossed=True), carry)
             dk_acc[cols, :] = dk
             dv_acc[cols, :] = dv
 
-        _when(t_first < tpc, _compute)
+        _when(t_first < t_end, _compute)
 
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
@@ -621,7 +730,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
-                 block_k=None, q_offset=None, kv_valid=None):
+                 block_k=None, q_offset=None, kv_valid=None, window=None):
     """Fused O(L)-memory backward: (dq, dk, dv) via two pallas_calls, each
     with :func:`_pick_tiles`' tile shape for it unless one is given."""
     lq, lk = q.shape[1], k.shape[1]
@@ -634,7 +743,7 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
         tiles[kernel] = (block_q, block_k) if block_q else \
             _pick_tiles(lq, lk, causal, kernel)
         _record_tiles(kernel, lq, lk, q_offset, kv_valid, *tiles[kernel],
-                      causal)
+                      causal, window)
     (dq_q, dq_k), (dkv_q, dkv_k) = tiles["bwd_dq"], tiles["bwd_dkv"]
     # Each kernel streams the axis it accumulates over in chunks of up to
     # 4096 and writes the other in chunks of up to _OUTER_CHUNK.
@@ -645,12 +754,13 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
                  _pick_chunk(lk, dq_k)),
                 (_pick_chunk(lq, dkv_q),
                  _pick_chunk(lk, dkv_k, _OUTER_CHUNK))),
-        q_offset=q_offset, kv_valid=kv_valid, interpret=_interpret())
+        q_offset=q_offset, kv_valid=kv_valid, window=window,
+        interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS)
 def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
-              q_offset, kv_valid, interpret):
+              q_offset, kv_valid, window, interpret):
     bh, lq, d = q.shape
     lk = k.shape[1]
     # The row statistics travel as (BH, 1, Lq) rows: see _fa_kernel.
@@ -665,7 +775,7 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
             body, sm_scale=sm_scale, causal=causal, block_q=block_q,
             block_k=block_k, q_chunk=q_chunk, k_chunk=k_chunk,
             n_qc=lq // q_chunk, n_kc=lk // k_chunk, q_offset=q_offset,
-            kv_valid=kv_valid, masked=kv_valid < lk)
+            kv_valid=kv_valid, masked=kv_valid < lk, window=window)
 
     # dQ: grid over query chunks; key chunks stream innermost.
     q_chunk, k_chunk = chunks[0]
@@ -703,7 +813,7 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
     return dq, dk, dv
 
 
-def _mask_jnp(s, causal, q_offset, kv_valid):
+def _mask_jnp(s, causal, q_offset, kv_valid, window=None):
     """Full-matrix analog of _apply_mask for the jnp oracles."""
     lq, lk = s.shape[1], s.shape[2]
     if q_offset is None:
@@ -714,21 +824,25 @@ def _mask_jnp(s, causal, q_offset, kv_valid):
     if kv_valid < lk:
         ok = (jnp.arange(lk) < kv_valid)[None, :]
     if causal:
-        c = (q_offset + jnp.arange(lq))[:, None] >= jnp.arange(lk)[None, :]
+        q_pos = (q_offset + jnp.arange(lq))[:, None]
+        c = q_pos >= jnp.arange(lk)[None, :]
+        if window is not None:
+            c &= jnp.arange(lk)[None, :] > q_pos - window
         ok = c if ok is None else ok & c
     if ok is None:
         return s
     return jnp.where(ok[None], s, NEG_INF)
 
 
-def _jnp_block_fwd(q3, k3, v3, causal, scale, q_offset=None, kv_valid=None):
+def _jnp_block_fwd(q3, k3, v3, causal, scale, q_offset=None, kv_valid=None,
+                   window=None):
     """jnp oracle for one attention block on (BH, Lq, D): returns
     (o, lse) with the same contract as the forward kernel (end-aligned
     causal, per-row logsumexp, optional key-validity bound). Shared by the
     interpret-mode paths here and the ring hops in parallel/sequence.py."""
     s = jnp.einsum("bqd,bkd->bqk", q3.astype(jnp.float32),
                    k3.astype(jnp.float32)) * scale
-    s = _mask_jnp(s, causal, q_offset, kv_valid)
+    s = _mask_jnp(s, causal, q_offset, kv_valid, window)
     m = jnp.max(s, axis=-1)
     p = jnp.where(s > NEG_INF * 0.5, jnp.exp(s - m[..., None]), 0.0)
     l = jnp.maximum(jnp.sum(p, axis=-1), 1e-30)
@@ -738,14 +852,14 @@ def _jnp_block_fwd(q3, k3, v3, causal, scale, q_offset=None, kv_valid=None):
 
 
 def _jnp_block_bwd(q3, k3, v3, o3, lse, do3, causal, scale,
-                   q_offset=None, kv_valid=None):
+                   q_offset=None, kv_valid=None, window=None):
     """jnp oracle for the block backward against a given logsumexp: with
     the block's own lse this is exact flash backward; with a ring-wide lse
     it yields the hop's contribution to the global gradient."""
     qf, kf, vf, of, dof = (t.astype(jnp.float32)
                            for t in (q3, k3, v3, o3, do3))
     s = jnp.einsum("bqd,bkd->bqk", qf, kf) * scale
-    s = _mask_jnp(s, causal, q_offset, kv_valid)
+    s = _mask_jnp(s, causal, q_offset, kv_valid, window)
     # Masked entries have s = NEG_INF and a fully-masked row has
     # lse ~= NEG_INF, where exp(s - lse) would blow up instead of vanishing
     # — zero them explicitly (the forward kernel does the same).
@@ -777,7 +891,7 @@ def gqa_fold3(t3, b, kv, g):
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, kv_valid,
-               heads, kv_heads, res, do):
+               heads, kv_heads, window, res, do):
     q, k, v, o, lse = res
     gqa = heads is not None and kv_heads is not None and heads != kv_heads
     if gqa:
@@ -790,10 +904,12 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, kv_valid,
         v = gqa_repeat3(v, b, kv_heads, g)
     if not _interpret():
         dq, dk, dv = _fa_backward(q, k, v, o, lse, do, causal, sm_scale,
-                                  block_q, block_k, q_offset, kv_valid)
+                                  block_q, block_k, q_offset, kv_valid,
+                                  window)
     else:
         dq, dk, dv = _jnp_block_bwd(q, k, v, o, lse, do, causal, sm_scale,
-                                    q_offset=q_offset, kv_valid=kv_valid)
+                                    q_offset=q_offset, kv_valid=kv_valid,
+                                    window=window)
     if gqa:
         dk, dv = gqa_fold3(dk, b, kv_heads, g), gqa_fold3(dv, b, kv_heads, g)
     return dq, dk, dv
@@ -802,9 +918,13 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, q_offset, kv_valid,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal=False, sm_scale=None):
+def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
     """Tiled attention over (B, L, H, D) tensors (the layout used throughout
     this codebase, e.g. parallel/sequence.py).
+
+    ``window`` (with ``causal``): a sliding window, query t sees the keys j
+    with t - window < j <= t (the query counts among its window); tiles
+    wholly behind the window are skipped like those over the diagonal.
 
     Lengths with no aligned block size are PADDED to the next block
     multiple and the padding masked inside the kernels (``kv_valid``), so
@@ -821,6 +941,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
             f"kv heads {kv} must divide query heads {h} (grouped-query)")
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
 
     def plain_fallback():
         """local_attention with any custom scale folded into q (it scales
@@ -828,7 +950,7 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
         from horovod_tpu.parallel.sequence import local_attention
         q_adj = q if sm_scale == 1.0 / (d ** 0.5) \
             else q * (sm_scale * d ** 0.5)
-        return local_attention(q_adj, k, v, causal=causal)
+        return local_attention(q_adj, k, v, causal=causal, window=window)
 
     # Interpret mode (CPU tests) lowers the kernel body to ordinary JAX ops,
     # whose internal dynamic_slices the shard_map VMA checker rejects when
@@ -857,5 +979,5 @@ def flash_attention(q, k, v, causal=False, sm_scale=None):
     # HBM traffic); no broadcast is materialized on the forward path.
     out = _flash(to3(q, pad_q), to3(k, pad_k), to3(v, pad_k), causal,
                  sm_scale, q_offset=lk - lq, kv_valid=lk, heads=h,
-                 kv_heads=kv)
+                 kv_heads=kv, window=window)
     return from3(out)

@@ -19,7 +19,7 @@ from horovod_tpu.parallel.tp import (  # noqa: F401
 from horovod_tpu.parallel.pp import (  # noqa: F401
     pipeline, pipeline_1f1b, split_microbatches, stack_stage_params,
 )
-from horovod_tpu.parallel.moe import MoEMlp  # noqa: F401
+from horovod_tpu.parallel.moe import DroplessMoE, MoEMlp  # noqa: F401
 from horovod_tpu.parallel.composite import (  # noqa: F401
     CompositeGPT, CompositeLlama, build_mesh3d, build_mesh4d,
 )

@@ -20,8 +20,18 @@ strategy). This module builds the strategy TPU-first:
 Call (and init) inside ``shard_map`` with the ``ep`` axis bound; outside an
 axis context the layer degrades to ep=1 (all experts local), which is the
 correctness oracle used in tests.
+
+:class:`DroplessMoE` is the second layer of this file, for the fine-grained
+mixtures of 2025's open models (64 experts, 6 a token): many-of-many routing
+with no capacity and no dropped token, gated experts, a router that may
+read another tensor than the experts do, and a layer that is TOLD which
+contiguous run of the experts it holds and computes their part of the sum.
+Dispatch is a sort of the (token, choice) pairs by expert and the experts'
+products are grouped over the experts held (``lax.ragged_dot``), so no
+``(T, E, C)`` tensor exists.
 """
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -199,3 +209,127 @@ class MoEMlp(nn.Module):
 
         out = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), y)
         return out.reshape(orig_shape), aux
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inverse, n_live, k):
+    """Rows of ``x`` (T, d) in the order of the sorted (token, choice)
+    pairs: row r is token ``order[r] // k``. The transpose of this gather is
+    a scatter-add over T * k rows; ``order`` is a permutation, so the
+    gradient is written as the gather by its inverse and a sum over a
+    token's k choices. Rows from ``n_live`` on belong to no expert held
+    here and bring no gradient (the grouped products leave them
+    unwritten)."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inverse, n_live, k):
+    return x[order // k], (inverse, n_live)
+
+
+def _dispatch_bwd(k, res, g):
+    inverse, n_live = res
+    live = jnp.arange(g.shape[0])[:, None] < n_live
+    g = jnp.where(live, g, 0)[inverse]
+    return g.reshape(-1, k, g.shape[-1]).sum(1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _undo_dispatch(y, order, inverse):
+    """Sorted rows (T * k, d) back in (token, choice) order; the gradient
+    is the gather by ``order``, as above."""
+    return y[inverse]
+
+
+def _undo_dispatch_fwd(y, order, inverse):
+    return y[inverse], order
+
+
+def _undo_dispatch_bwd(order, g):
+    return g[order], None, None
+
+
+_undo_dispatch.defvjp(_undo_dispatch_fwd, _undo_dispatch_bwd)
+
+
+class DroplessMoE(nn.Module):
+    """Sparse feed-forward layer of gated experts, ``top_k`` of
+    ``num_experts`` a token, none dropped, for a layer that holds
+    ``experts_held`` contiguous experts from ``first_expert`` on (all of
+    them by default).
+
+    The router (``num_experts`` wide, float32) reads ``router_input``
+    (``x`` when None); a token's weights are the softmax of its ``top_k``
+    largest logits. The layer returns ``sum over the chosen experts held
+    here of w_e * (relu(x W_gate,e) * (x W_up,e)) W_down,e``: the whole
+    layer when it holds every expert, else this share's partial sum, which
+    the shares of the other holders complete (summed by the caller's
+    exchange; on one chip there is none and nothing stands in for it).
+
+    Every (token, choice) pair gets a row of the grouped products' buffer,
+    T * top_k rows, so total imbalance drops nothing; the rows of pairs
+    routed elsewhere are sorted behind the held experts' groups, where the
+    grouped product does not visit them.
+    """
+    num_experts: int
+    top_k: int
+    hidden_size: int
+    intermediate_size: int
+    experts_held: Optional[int] = None
+    first_expert: int = 0
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x, router_input=None):
+        E, k = self.num_experts, self.top_k
+        d, f = self.hidden_size, self.intermediate_size
+        held = E if self.experts_held is None else self.experts_held
+        if not 0 < k <= E or held < 1 or self.first_expert < 0 \
+                or self.first_expert + held > E:
+            raise ValueError(
+                f"top_k {k} of {E} experts, holding {held} from "
+                f"{self.first_expert}: not a share of the experts")
+        xt = x.reshape(-1, d)
+        rt = xt if router_input is None else router_input.reshape(-1, d)
+        T = xt.shape[0]
+        from horovod_tpu.metrics import instruments as hvd_metrics
+        hvd_metrics.record_moe_layer(E, held, k, T * k, T)
+
+        with jax.named_scope("moe.route"):
+            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              precision=lax.Precision.HIGHEST,
+                              name="router")(rt.astype(jnp.float32))
+            top, chosen = lax.top_k(logits, k)                     # (T, k)
+            weights = jax.nn.softmax(top, axis=-1)
+
+        with jax.named_scope("moe.dispatch"):
+            local = chosen - self.first_expert
+            here = (local >= 0) & (local < held)                   # (T, k)
+            # Pairs routed elsewhere sort behind the last expert held.
+            group = jnp.where(here, local, held).reshape(-1)
+            order = jnp.argsort(group, stable=True)
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(T * k, dtype=order.dtype))
+            sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
+            rows = _dispatch(xt.astype(self.dtype), order, inverse,
+                             jnp.sum(sizes), k)
+
+        w_gate_up = self.param("w_gate_up", nn.initializers.lecun_normal(),
+                               (held, d, 2 * f), jnp.float32)
+        w_down = self.param("w_down", nn.initializers.lecun_normal(),
+                            (held, f, d), jnp.float32)
+        with jax.named_scope("moe.experts"):
+            h = lax.ragged_dot(rows, jnp.asarray(w_gate_up, self.dtype),
+                               sizes)
+            gate, up = jnp.split(h, 2, axis=-1)
+            y = lax.ragged_dot(nn.relu(gate) * up,
+                               jnp.asarray(w_down, self.dtype), sizes)
+
+        with jax.named_scope("moe.combine"):
+            y = _undo_dispatch(y, order, inverse).reshape(T, k, d)
+            y = jnp.where(here[..., None], y, 0)
+            out = jnp.einsum("tk,tkd->td", weights, y.astype(jnp.float32))
+        return out.astype(self.dtype).reshape(x.shape)

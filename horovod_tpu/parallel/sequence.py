@@ -40,16 +40,21 @@ def _attention_weights(q, k, scale, mask=None):
     return s
 
 
-def local_attention(q, k, v, causal=False):
+def local_attention(q, k, v, causal=False, window=None):
     """Plain softmax attention on local (unsharded) tensors; the correctness
     oracle for the parallel schemes. ``k``/``v`` may carry fewer (grouped)
-    heads than ``q`` — they are broadcast here, locally."""
+    heads than ``q`` — they are broadcast here, locally. ``window`` (with
+    ``causal``): each query sees its last ``window`` keys, itself
+    included."""
     k, v = broadcast_kv_heads(q, k, v)
     scale = 1.0 / np.sqrt(q.shape[-1])
     mask = None
     if causal:
         Lq, Lk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)[None, None]
+        mask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq - window)
+        mask = mask[None, None]
     s = _attention_weights(q, k, scale, mask)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)) \

@@ -165,11 +165,13 @@ def apply_rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
-def plain_attention(q, k, v, out_dtype, mask=None, bias=None, causal=False):
+def plain_attention(q, k, v, out_dtype, mask=None, bias=None, causal=False,
+                    window=None):
     """The ONE plain-XLA attend kernel (scaled scores, optional additive
     bias, -1e9 causal/key masking, fp32 softmax) shared by self- and
     cross-attention. q/k/v: (B, L, h, d); ``mask``: (B, Lk) True on valid
-    keys; ``bias``: (h, Lq, Lk) added to scores."""
+    keys; ``bias``: (h, Lq, Lk) added to scores; ``window`` (causal only):
+    each query sees its last ``window`` keys, itself included."""
     head_dim = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(head_dim)
     if bias is not None:
@@ -177,6 +179,8 @@ def plain_attention(q, k, v, out_dtype, mask=None, bias=None, causal=False):
     if causal:
         Lq, Lk = q.shape[1], k.shape[1]
         cmask = jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq)
+        if window is not None:
+            cmask &= ~jnp.tril(jnp.ones((Lq, Lk), bool), k=Lk - Lq - window)
         scores = jnp.where(cmask[None, None], scores,
                            jnp.asarray(-1e9, scores.dtype))
     if mask is not None:
@@ -199,7 +203,12 @@ class TPSelfAttention(nn.Module):
     decode mode); K/V are broadcast to the query heads at attend time.
     ``rope_theta`` replaces additive position embeddings with rotary ones
     applied to Q/K inside the block (global positions are derived from the
-    sp shard index / the decode cache cursor, so RoPE composes with both).
+    sp shard index / the decode cache cursor, so RoPE composes with both);
+    None is the no-position case. ``head_dim`` states the head size where
+    it is not ``hidden_size / num_heads`` (28 heads of 128 at hidden 2560).
+    ``window`` (causal, full-sequence, no sp): each query attends its last
+    ``window`` keys, itself included; the flash kernels skip the tiles
+    behind it.
     """
     num_heads: int
     hidden_size: int
@@ -215,6 +224,8 @@ class TPSelfAttention(nn.Module):
     num_kv_heads: Optional[int] = None   # None -> MHA (= num_heads)
     rope_theta: Optional[float] = None   # None -> no rotary embedding
     use_bias: bool = True
+    head_dim: Optional[int] = None       # None -> hidden_size // num_heads
+    window: Optional[int] = None         # None -> every earlier key
 
     def _decode_attend(self, q, k, v, bias=None, pos=None):
         """Cached decode against the KV cache: ``s`` query tokens per call
@@ -357,6 +368,10 @@ class TPSelfAttention(nn.Module):
             # broadcasting — if at all — on the far side of the exchange.
             k = jnp.repeat(k, g, axis=2)
             v = jnp.repeat(v, g, axis=2)
+        if self.window is not None and (self.sp_axis is not None
+                                        or not self.causal):
+            raise ValueError("a sliding window needs causal=True and no "
+                             "sp_axis")
         if self.sp_axis is not None:
             # Sequence parallelism: x carries this chip's token shard; the
             # QKV/out projections are token-local, the attention itself
@@ -380,9 +395,11 @@ class TPSelfAttention(nn.Module):
             raise ValueError(f"unknown sp_impl {self.sp_impl!r}")
         if self.use_flash and mask is None:
             from horovod_tpu.ops.pallas import flash_attention
-            return flash_attention(q, k, v, causal=self.causal)
+            return flash_attention(q, k, v, causal=self.causal,
+                                   window=self.window)
         return plain_attention(q, k, v, out_dtype=self.dtype, mask=mask,
-                               bias=bias, causal=self.causal)
+                               bias=bias, causal=self.causal,
+                               window=self.window)
 
     @nn.compact
     def __call__(self, x, mask=None, bias=None, pos=None):
@@ -398,7 +415,7 @@ class TPSelfAttention(nn.Module):
                 f"{self.num_heads}")
         local_heads = self.num_heads // n
         local_kv = kv_heads // n
-        head_dim = self.hidden_size // self.num_heads
+        head_dim = self.head_dim or self.hidden_size // self.num_heads
 
         # Column-parallel fused QKV: shard s's local output is
         # [q_s | k_s | v_s] for its heads [s*local_heads, (s+1)*local_heads)
@@ -417,9 +434,11 @@ class TPSelfAttention(nn.Module):
 
         q, k, v = heads(q), heads(k), heads(v)
         if self.decode:
-            if self.sp_axis is not None or mask is not None:
+            if self.sp_axis is not None or mask is not None \
+                    or self.window is not None:
                 raise ValueError(
-                    "decode mode supports neither sp_axis nor masks")
+                    "decode mode supports neither sp_axis, masks nor a "
+                    "sliding window")
             if bias is not None and x.shape[1] != 1:
                 raise ValueError(
                     f"decode with an attention bias (T5 relative "

@@ -185,7 +185,7 @@ class TestFlashAttention:
         o, lse = fa._fa_forward(q, k, v, causal, sm, bq, bk)
         got = fa._fa_backward(q, k, v, o, lse, do, causal, sm, bq, bk)
         want = fa._flash_bwd(causal, sm, bq, bk, None, None, None, None,
-                             (q, k, v, o, lse), do)
+                             None, (q, k, v, o, lse), do)
         for a, b, nm in zip(got, want, "q k v".split()):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5,
@@ -524,6 +524,130 @@ class TestTiledKernels:
                                    rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                    rtol=2e-4, atol=2e-5)
+
+
+# (length, window): shorter than, equal to and longer than the sequence, and
+# a window shorter than one tile.
+_WINDOWS = [(256, 96), (256, 256), (256, 400), (256, 20)]
+
+
+class TestSlidingWindow:
+    """``window``: query t sees keys j with t - window < j <= t. Against
+    plain masked attention (``local_attention``), with grouped K/V and
+    heads of 128."""
+
+    @pytest.mark.parametrize("length,window", _WINDOWS)
+    def test_forward_and_gradients_match_plain_masked_attention(
+            self, rng, length, window):
+        from horovod_tpu.ops.pallas import flash_attention
+        from horovod_tpu.parallel.sequence import local_attention
+        B, H, KV, D = 1, 4, 2, 128
+        q = jnp.asarray(rng.standard_normal((B, length, H, D)), np.float32)
+        k, v = (jnp.asarray(rng.standard_normal((B, length, KV, D)),
+                            np.float32) for _ in range(2))
+
+        def plain(q, k, v):
+            """The mask written out, not ``local_attention``'s."""
+            g = H // KV
+            kk, vv = jnp.repeat(k, g, 2), jnp.repeat(v, g, 2)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / D ** 0.5
+            t, j = jnp.arange(length)[:, None], jnp.arange(length)[None, :]
+            s = jnp.where((j <= t) & (j > t - window), s, -jnp.inf)
+            return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), vv)
+
+        out = flash_attention(q, k, v, causal=True, window=window)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(plain(q, k, v)),
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(local_attention(q, k, v, causal=True, window=window)),
+            np.asarray(plain(q, k, v)), rtol=2e-4, atol=2e-5)
+        w = jnp.asarray(rng.standard_normal(out.shape), np.float32)
+        got = jax.grad(lambda *a: jnp.sum(w * flash_attention(
+            *a, causal=True, window=window)), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(w * plain(*a)), (0, 1, 2))(q, k, v)
+        for a, b, nm in zip(got, want, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-4,
+                                       err_msg=f"d{nm}")
+
+    @pytest.mark.parametrize("chunk", [None, 64])
+    @pytest.mark.parametrize("length,window", _WINDOWS)
+    def test_backward_kernels_match_the_jnp_oracle(
+            self, rng, monkeypatch, length, window, chunk):
+        """The TPU kernels through the interpreter (the CPU's custom VJP
+        takes the jnp backward), 32 x 32 tiles so that skipped, plain and
+        twice-masked tiles all occur; with a chunk cap every bound comes
+        from a grid variable."""
+        fa = _fa()
+        if chunk:
+            pick = fa._pick_chunk
+            monkeypatch.setattr(
+                fa, "_pick_chunk",
+                lambda n, block, cap=4096: pick(n, block, min(cap, chunk)))
+        H, D, b = 2, 128, 32
+        q, k, v, do = (jnp.asarray(rng.standard_normal((H, length, D)),
+                                   np.float32) for _ in range(4))
+        sm = 1.0 / D ** 0.5
+        o, lse = fa._fa_forward(q, k, v, True, sm, b, b, window=window)
+        o_ref, lse_ref = fa._jnp_block_fwd(q, k, v, True, sm, window=window)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   rtol=2e-4, atol=2e-5)
+        got = fa._fa_backward(q, k, v, o_ref, lse_ref, do, True, sm, b, b,
+                              window=window)
+        want = fa._jnp_block_bwd(q, k, v, o_ref, lse_ref, do, True, sm,
+                                 window=window)
+        for a, c, nm in zip(got, want, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=2e-4, atol=2e-5,
+                                       err_msg=f"d{nm}")
+
+    @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+    @pytest.mark.parametrize(
+        "lq,lk,q_offset,kv_valid,bq,bk,window",
+        [(128, 128, 0, 128, 32, 32, 48), (128, 128, 0, 100, 16, 32, 40),
+         (256, 256, 0, 256, 32, 64, 100), (64, 128, 64, 128, 16, 32, 40),
+         (8192, 8192, 0, 8192, 1024, 1024, 4096),
+         (128, 128, 0, 128, 32, 32, None)])
+    def test_schedule_visits_and_masks_the_least_tiles(
+            self, kernel, lq, lk, q_offset, kv_valid, bq, bk, window):
+        """Visited are exactly the tiles the mask leaves something of,
+        masked exactly those it cuts: nothing behind the window is visited
+        and only the two edges are masked."""
+        fa = _fa()
+        t = np.arange(lq)[:, None] + q_offset
+        j = np.arange(lk)[None, :]
+        ok = (j <= t) & (j < kv_valid)
+        if window is not None:
+            ok &= j > t - window
+        tiles = ok.reshape(lq // bq, bq, lk // bk, bk)
+        some, whole = tiles.any((1, 3)), tiles.all((1, 3))
+        assert fa.tile_counts(kernel, lq, lk, q_offset, kv_valid, bq, bk,
+                              True, window) == {
+            "total": some.size, "visited": int(some.sum()),
+            "masked": int((some & ~whole).sum())}
+
+    def test_no_window_keeps_the_schedule_of_1024(self):
+        """``window=None`` is the schedule the causal cells at 1024 had
+        before the window: tiles per (batch, head) total / visited /
+        masked."""
+        fa = _fa()
+        want = {"fwd": (16, 12, 8), "bwd_dq": (16, 10, 4),
+                "bwd_dkv": (64, 36, 8)}
+        for kernel, counts in want.items():
+            got = fa.tile_counts(kernel, 1024, 1024, 0, 1024,
+                                 *fa._pick_tiles(1024, 1024, True, kernel),
+                                 True, None)
+            assert tuple(got[k] for k in ("total", "visited", "masked")) \
+                == counts
+
+    def test_window_needs_causal(self, rng):
+        from horovod_tpu.ops.pallas import flash_attention
+        q, k, v = _qkv(rng)
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(q, k, v, causal=False, window=16)
 
 
 class TestScaleKernels:

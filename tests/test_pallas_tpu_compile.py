@@ -41,7 +41,7 @@ def fa(monkeypatch):
     return mod
 
 
-# (batch, lq, lk, heads, kv heads, head size, causal, dtype)
+# (batch, lq, lk, heads, kv heads, head size, causal, dtype[, window])
 _CALLS = {
     "gpt2_medium_1024_causal": (8, 1024, 1024, 16, 16, 64, True, "bfloat16"),
     "gpt2_1024_causal_float32": (2, 1024, 1024, 12, 12, 64, True, "float32"),
@@ -55,20 +55,26 @@ _CALLS = {
     "causal_8192_chunked": (1, 8192, 8192, 8, 8, 64, True, "bfloat16"),
     "noncausal_8192_chunked": (1, 8192, 8192, 8, 8, 64, False, "bfloat16"),
     "prefill_256_on_1024_keys": (2, 256, 1024, 8, 8, 64, True, "bfloat16"),
+    # a ninth field is the sliding window
+    "gqa_28_on_4_8192_window_4096": (2, 8192, 8192, 28, 4, 128, True,
+                                     "bfloat16", 4096),
+    "gqa_28_on_4_8192_causal": (2, 8192, 8192, 28, 4, 128, True, "bfloat16"),
+    "window_300_on_1024": (2, 1024, 1024, 8, 8, 64, True, "bfloat16", 300),
 }
 
 
 @pytest.mark.parametrize("call", sorted(_CALLS))
 def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
-    b, lq, lk, h, kv, d, causal, dtype = _CALLS[call]
+    b, lq, lk, h, kv, d, causal, dtype, *window = _CALLS[call]
+    window = window[0] if window else None
 
     def shape(length, heads):
         return jax.ShapeDtypeStruct((b, length, heads, d), jnp.dtype(dtype),
                                     sharding=one_chip)
 
     def loss(q, k, v):
-        return fa.flash_attention(q, k, v, causal=causal).astype(
-            jnp.float32).sum()
+        return fa.flash_attention(q, k, v, causal=causal,
+                                  window=window).astype(jnp.float32).sum()
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         shape(lq, h), shape(lk, kv), shape(lk, kv)).compile().as_text()
