@@ -73,10 +73,17 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   traced ``parallel.moe.DroplessMoE`` call (kind=routed|held|per_token:
   the router's width, the experts this layer holds, the experts a token
   is routed to; gauge, set while the call is traced)
-- ``hvd_moe_buffer_rows_per_token{axis_size}``      rows the grouped
-  product's buffer of that call carries, per token of the call (gauge, as
-  above; axis_size = chips the experts are exchanged over, 1 without an
-  exchange)
+- ``hvd_moe_buffer_rows_per_token{axis_size}``      rows the buffer of
+  that call carries, per token of the call: the buffer sized for the rows
+  expected (``parallel.moe.buffer_rows``), not the top_k rows a token of
+  the overflow path (gauge, as above; axis_size = chips the experts are
+  exchanged over, 1 without an exchange)
+- ``hvd_moe_overflow_calls{axis_size}``             DroplessMoE calls whose
+  live rows outnumbered that buffer and ran over all top_k rows a token.
+  Set to 0 when a layer with such an overflow path is traced and NOT
+  raised from the device: the branch is chosen there, and a host callback
+  in it keeps the whole step out of the persistent compile cache (gauge;
+  absent where no traced layer has the path)
 - ``autopilot_decisions_total{lever,outcome}``      autopilot control
   decisions (lever=tuner|overlap|cross_wire|remediate; counter)
 - ``autopilot_remediations_total{cause,outcome}``   autopilot-initiated
@@ -331,6 +338,16 @@ MOE_BUFFER_ROWS_PER_TOKEN = REGISTRY.gauge(
     "per_token x held / routed, the rest is room for imbalance. By the "
     "chips the experts are exchanged over (1 without an exchange). Set "
     "while the call is traced.",
+    ("axis_size",))
+MOE_OVERFLOW_CALLS = REGISTRY.gauge(
+    "hvd_moe_overflow_calls",
+    "DroplessMoE calls whose live rows outnumbered the buffer sized for "
+    "the rows expected and ran over every (token, choice) pair instead. "
+    "Set to 0 when a layer with such a path is traced; the device picks "
+    "the branch and reports nothing back (a host callback would keep the "
+    "step out of the persistent compile cache), so the series says that "
+    "the path exists, not how often it ran. By the chips the experts are "
+    "exchanged over.",
     ("axis_size",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
@@ -684,13 +701,17 @@ def record_fused_allreduce(axis_size, buckets, nbytes):
 def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
                      axis_size=1):
     """What one trace of ``parallel.moe.DroplessMoE`` makes: known while
-    the call is traced, so set there once and not per step."""
+    the call is traced, so set there once and not per step. A buffer of
+    fewer rows than the call's pairs has an overflow path, whose series
+    appears here."""
     if not _enabled:
         return
     for kind, n in (("routed", routed), ("held", held),
                     ("per_token", per_token)):
         MOE_EXPERTS.labels(kind).set(n)
     MOE_BUFFER_ROWS_PER_TOKEN.labels(axis_size).set(buffer_rows / tokens)
+    if buffer_rows < per_token * tokens:
+        MOE_OVERFLOW_CALLS.labels(axis_size).set(0)
 
 
 def record_flash_tiles(kernel, counts):

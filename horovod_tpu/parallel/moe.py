@@ -32,7 +32,8 @@ products are grouped over the experts held (``lax.ragged_dot``), so no
 """
 
 import functools
-from typing import Any, Optional
+import math
+from typing import Any, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -211,48 +212,176 @@ class MoEMlp(nn.Module):
         return out.reshape(orig_shape), aux
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, order, inverse, n_live, k):
-    """Rows of ``x`` (T, d) in the order of the sorted (token, choice)
-    pairs: row r is token ``order[r] // k``. The transpose of this gather is
-    a scatter-add over T * k rows; ``order`` is a permutation, so the
-    gradient is written as the gather by its inverse and a sum over a
-    token's k choices. Rows from ``n_live`` on belong to no expert held
-    here and bring no gradient (the grouped products leave them
-    unwritten)."""
-    return x[order // k]
+# DroplessMoE's buffer of rows: room for SLACK times the rows expected live,
+# in whole row tiles of the grouped product. Constants, not options: random
+# routing keeps the live rows within 1 % of expected and a training router
+# moved them by about 12 % (PERF.md, PR 29); what 1.5 does not cover takes
+# the overflow path, which is exact, so no value is wrong, only slower.
+SLACK = 1.5
+ROW_TILE = 512
 
 
-def _dispatch_fwd(x, order, inverse, n_live, k):
-    return x[order // k], (inverse, n_live)
+def buffer_rows(tokens, per_token, held, routed):
+    """Rows of the buffer a DroplessMoE call moves: ``SLACK`` times the
+    ``tokens * per_token * held / routed`` expected live, rounded up to
+    ``ROW_TILE``, and never more than one a (token, choice) pair."""
+    pairs = tokens * per_token
+    expected = math.ceil(SLACK * pairs * held / routed)
+    return min(pairs, -(-expected // ROW_TILE) * ROW_TILE)
 
 
-def _dispatch_bwd(k, res, g):
-    inverse, n_live = res
-    live = jnp.arange(g.shape[0])[:, None] < n_live
-    g = jnp.where(live, g, 0)[inverse]
-    return g.reshape(-1, k, g.shape[-1]).sum(1), None, None, None
+class _Rows(NamedTuple):
+    """Where the rows of a buffer of sorted pairs belong: row r is pair
+    ``pair[r]`` of token ``tok[r]``; choice j of token t sorted to row
+    ``at[j, t]`` and is held here if ``held[j, t]``. Choice-major, (k, T):
+    a (T, k, d) array would carry k in its tiled second-minor dimension,
+    and every reshape to it is a copy. Rows no held choice points at (the
+    grouped products leave them unwritten: they may hold anything, NaN
+    included) are read by nothing that reads through ``at`` under
+    ``held``."""
+    pair: Any
+    tok: Any
+    at: Any
+    held: Any
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _rows(rows, k, order, inverse, n_live):
+    pair = order[:rows]
+    at = inverse.reshape(-1, k).T
+    # A pair not held sorted behind the live ones: whichever row it reads
+    # is masked, and ``%`` spreads those reads over the buffer (all on one
+    # row they were a tenth slower).
+    return _Rows(pair, pair // k, at % rows, at < n_live)
+
+
+def _sum_to_tokens(buf, weights, where):
+    """Float32 (T, d): for every token the sum over the choices held here
+    of the row its pair sorted to, times the choice's weight where
+    ``weights`` (k, T) are given: k gathers of T rows in ``buf``'s dtype,
+    one choice at a time (all k at once keep a (k, T, d) array alive:
+    0.9 GiB more a step at 2 x 8192 tokens). A scatter-add of the
+    buffer's rows into (T, d) was the slower on the chip (6.0 ms against
+    5.1 for 36,864 rows, 14.1 against 5.1 for 98,304: PERF.md, PR 30)."""
+    out = jnp.zeros((where.at.shape[1], buf.shape[-1]), jnp.float32)
+    for j, (at, held) in enumerate(zip(where.at, where.held)):
+        picked = jnp.where(held[:, None], buf[at], 0).astype(jnp.float32)
+        if weights is not None:
+            picked = picked * weights[j][:, None]
+        out = out + picked
+    return out
 
 
 @jax.custom_vjp
-def _undo_dispatch(y, order, inverse):
-    """Sorted rows (T * k, d) back in (token, choice) order; the gradient
-    is the gather by ``order``, as above."""
-    return y[inverse]
+def _take_rows(x, where):
+    """The buffer: row r is token ``where.tok[r]``'s row of ``x`` (T, d).
+    The transpose of this gather is a scatter-add; the gradient is
+    written as the sum by token instead, which leaves out the rows of no
+    expert held here."""
+    return x[where.tok]
 
 
-def _undo_dispatch_fwd(y, order, inverse):
-    return y[inverse], order
+def _take_rows_fwd(x, where):
+    return x[where.tok], where
 
 
-def _undo_dispatch_bwd(order, g):
-    return g[order], None, None
+def _take_rows_bwd(where, g):
+    return _sum_to_tokens(g, None, where).astype(g.dtype), None
 
 
-_undo_dispatch.defvjp(_undo_dispatch_fwd, _undo_dispatch_bwd)
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weights, where):
+    """(T, d) in ``y``'s dtype: each token's float32 sum of its experts'
+    outputs ``y`` (the buffer's rows) under ``weights`` (T, k)."""
+    return _sum_to_tokens(y, weights.T, where).astype(y.dtype)
+
+
+def _combine_fwd(y, weights, where):
+    return _combine(y, weights, where), (y, weights, where)
+
+
+def _combine_bwd(res, g):
+    y, weights, where = res
+    g = g[where.tok].astype(jnp.float32)
+    g_y = weights.reshape(-1)[where.pair][:, None] * g
+    g_weights = jnp.sum(g * y.astype(jnp.float32), -1)
+    g_weights = jnp.where(where.held, g_weights[where.at], 0).T
+    return g_y.astype(y.dtype), g_weights, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _on_rows(rows, k, xt, weights, w_gate_up, w_down, order, inverse, sizes):
+    """The experts' weighted outputs summed by token, (T, d), from the
+    first ``rows`` of the sorted pairs, which must hold every live one:
+    their rows out of ``xt``, through the grouped products, back into
+    their tokens."""
+    with jax.named_scope("moe.dispatch"):
+        where = _rows(rows, k, order, inverse, jnp.sum(sizes))
+        buf = _take_rows(xt, where)
+    with jax.named_scope("moe.experts"):
+        h = lax.ragged_dot(buf, jnp.asarray(w_gate_up, xt.dtype), sizes)
+        gate, up = jnp.split(h, 2, axis=-1)
+        y = lax.ragged_dot(nn.relu(gate) * up,
+                           jnp.asarray(w_down, xt.dtype), sizes)
+    with jax.named_scope("moe.combine"):
+        return _combine(y, weights, where)
+
+
+def _where_they_fit(fn, rows, order, sizes, *operands):
+    """``fn(rows, *operands)`` where the live pairs fit in ``rows``, else
+    ``fn`` over every pair: chosen on the device."""
+    return lax.cond(jnp.sum(sizes) <= rows, functools.partial(fn, rows),
+                    functools.partial(fn, order.shape[0]), *operands)
+
+
+# Jitted, with every choice static, so that the layers of a model, which
+# call these with the same shapes, trace and lower each once: the two
+# branches, forward and back, are most of what a layer gives the tracer.
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _forward_where_they_fit(rows, k, *args):
+    order, _, sizes = args[4:]
+    return _where_they_fit(lambda n, *args: _on_rows(n, k, *args), rows,
+                           order, sizes, *args)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _backward_where_they_fit(rows, k, g, *args):
+    diff, (order, inverse, sizes) = args[:4], args[4:]
+
+    def pull(n, g, *diff):
+        return jax.vjp(lambda *diff: _on_rows(n, k, *diff, order, inverse,
+                                              sizes), *diff)[1](g)
+    return _where_they_fit(pull, rows, order, sizes, g, *diff)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _on_rows_expected(rows, k, xt, weights, w_gate_up, w_down, order,
+                      inverse, sizes):
+    """:func:`_on_rows` over ``rows`` pairs where the live ones fit, else
+    over all of them: one conditional going forward and one coming back,
+    each branch keeping its temporaries to itself. (Differentiated as it
+    stands, the forward conditional would hand the residuals of both
+    branches, the untaken one's as zeros, to the backward one: 3.4 GiB
+    more at 2 x 8192 tokens.) So the backward branch computes its forward
+    part again from the arguments, which is what the caller's ``remat``
+    would have done."""
+    return _forward_where_they_fit(rows, k, xt, weights, w_gate_up, w_down,
+                                   order, inverse, sizes)
+
+
+def _on_rows_expected_fwd(rows, k, *args):
+    return _on_rows_expected(rows, k, *args), args
+
+
+def _on_rows_expected_bwd(rows, k, args, g):
+    return (*_backward_where_they_fit(rows, k, g, *args), None, None, None)
+
+
+_on_rows_expected.defvjp(_on_rows_expected_fwd, _on_rows_expected_bwd)
 
 
 class DroplessMoE(nn.Module):
@@ -269,10 +398,14 @@ class DroplessMoE(nn.Module):
     the shares of the other holders complete (summed by the caller's
     exchange; on one chip there is none and nothing stands in for it).
 
-    Every (token, choice) pair gets a row of the grouped products' buffer,
-    T * top_k rows, so total imbalance drops nothing; the rows of pairs
-    routed elsewhere are sorted behind the held experts' groups, where the
-    grouped product does not visit them.
+    The (token, choice) pairs are sorted by expert, those routed elsewhere
+    behind the last expert held, and the layer moves a buffer of the first
+    ``buffer_rows`` of them: out of ``x``, through the grouped products,
+    and summed back into their tokens. A share whose live pairs outnumber
+    that buffer in some call (a skewed router) runs the same code on all
+    T * top_k pairs instead, chosen on the device from the count of live
+    pairs, so total imbalance drops nothing; a layer that holds every
+    expert has the one size and no branch.
     """
     num_experts: int
     top_k: int
@@ -295,8 +428,9 @@ class DroplessMoE(nn.Module):
         xt = x.reshape(-1, d)
         rt = xt if router_input is None else router_input.reshape(-1, d)
         T = xt.shape[0]
+        C = buffer_rows(T, k, held, E)
         from horovod_tpu.metrics import instruments as hvd_metrics
-        hvd_metrics.record_moe_layer(E, held, k, T * k, T)
+        hvd_metrics.record_moe_layer(E, held, k, C, T)
 
         with jax.named_scope("moe.route"):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
@@ -311,25 +445,19 @@ class DroplessMoE(nn.Module):
             # Pairs routed elsewhere sort behind the last expert held.
             group = jnp.where(here, local, held).reshape(-1)
             order = jnp.argsort(group, stable=True)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(T * k, dtype=order.dtype))
-            sizes = jnp.zeros((held + 1,), jnp.int32).at[group].add(1)[:held]
-            rows = _dispatch(xt.astype(self.dtype), order, inverse,
-                             jnp.sum(sizes), k)
+            inverse = jnp.argsort(order)
+            sizes = jnp.sum(group[:, None] == jnp.arange(held), 0,
+                            dtype=jnp.int32)
 
         w_gate_up = self.param("w_gate_up", nn.initializers.lecun_normal(),
                                (held, d, 2 * f), jnp.float32)
         w_down = self.param("w_down", nn.initializers.lecun_normal(),
                             (held, f, d), jnp.float32)
-        with jax.named_scope("moe.experts"):
-            h = lax.ragged_dot(rows, jnp.asarray(w_gate_up, self.dtype),
-                               sizes)
-            gate, up = jnp.split(h, 2, axis=-1)
-            y = lax.ragged_dot(nn.relu(gate) * up,
-                               jnp.asarray(w_down, self.dtype), sizes)
 
-        with jax.named_scope("moe.combine"):
-            y = _undo_dispatch(y, order, inverse).reshape(T, k, d)
-            y = jnp.where(here[..., None], y, 0)
-            out = jnp.einsum("tk,tkd->td", weights, y.astype(jnp.float32))
-        return out.astype(self.dtype).reshape(x.shape)
+        args = (xt.astype(self.dtype), weights, w_gate_up, w_down, order,
+                inverse, sizes)
+        if C == T * k:
+            out = _on_rows(C, k, *args)
+        else:
+            out = _on_rows_expected(C, k, *args)
+        return out.reshape(x.shape)

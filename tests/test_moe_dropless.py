@@ -159,3 +159,186 @@ class TestDroplessMoE:
         args.update(kw)
         with pytest.raises(ValueError):
             DroplessMoE(**args).init(jax.random.PRNGKey(0), x, r)
+
+
+# A share whose buffer is smaller than its pairs: 1024 tokens, 2 of 8
+# experts a token, experts 2 and 3 held: 2048 pairs, 512 expected live,
+# a buffer of 1.5 x 512 rounded up to the row tile = 1024 rows.
+T2, FIRST, HELD = 1024, 2, 2
+LEAVES = ["output", "router", "w_down", "w_gate_up", "x", "router_input"]
+
+
+def _crowded(params, r):
+    """Router and router input that send every token to experts 2 and 3:
+    2048 live pairs, over the buffer's 1024."""
+    kernel = np.zeros((D, E), np.float32)
+    kernel[:, 2], kernel[:, 3] = 1.0, 0.9
+    return (dict(params, router={"kernel": jnp.asarray(kernel)}),
+            jnp.abs(r) + 0.1)
+
+
+def _count_runs(monkeypatch):
+    """{rows: times a branch of that many rows RAN} (both are traced), by
+    a callback the test wraps round ``moe._on_rows``."""
+    from horovod_tpu.parallel import moe
+    runs, on_rows = {}, moe._on_rows
+
+    def counted(rows, *args):
+        jax.debug.callback(
+            lambda: runs.__setitem__(rows, runs.get(rows, 0) + 1))
+        return on_rows(rows, *args)
+    monkeypatch.setattr(moe, "_on_rows", counted)
+    return runs
+
+
+@pytest.fixture
+def unwritten_rows(monkeypatch):
+    """The TPU's grouped product leaves the rows behind the last group
+    unwritten, in the product and in the gradient it hands its left
+    operand; the CPU's writes zeros. Here both hold NaN."""
+    ragged_dot = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def spoil(x, n):
+        return jnp.where(jnp.arange(x.shape[0])[:, None] < n, x, jnp.nan)
+    spoil.defvjp(lambda x, n: (spoil(x, n), n),
+                 lambda n, g: (spoil(g, n), None))
+
+    def spoiled(lhs, rhs, sizes):
+        n = jnp.sum(sizes)
+        # forward the operand is read as it is (those rows are not
+        # visited); backward its gradient's dead rows are spoiled
+        lhs = jnp.where(jnp.arange(lhs.shape[0])[:, None] < n,
+                        spoil(lhs, n), lhs)
+        return spoil(ragged_dot(lhs, rhs, sizes), n)
+    monkeypatch.setattr(jax.lax, "ragged_dot", spoiled)
+
+
+@pytest.fixture
+def share(rng):
+    x = jnp.asarray(rng.standard_normal((2, T2 // 2, D)), jnp.float32)
+    r = jnp.asarray(rng.standard_normal((2, T2 // 2, D)), jnp.float32)
+    layer = DroplessMoE(E, K, D, F, experts_held=HELD, first_expert=FIRST)
+    params = layer.init(jax.random.PRNGKey(3), x, r)["params"]
+    return layer, params, x, r
+
+
+def _output_and_grads(layer, params, x, r):
+    """{name: array} of ``LEAVES``, the gradients those of a fixed
+    weighting of the output."""
+    w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+    def loss(p, x, r):
+        out = layer.apply({"params": p}, x, r)
+        return jnp.sum(w * out), out
+    (_, out), (g_p, g_x, g_r) = jax.jit(jax.value_and_grad(
+        loss, (0, 1, 2), has_aux=True))(params, x, r)
+    return {"output": out, "router": g_p["router"]["kernel"],
+            "w_down": g_p["w_down"], "w_gate_up": g_p["w_gate_up"],
+            "x": g_x, "router_input": g_r}
+
+
+class TestBufferOfTheRowsExpected:
+    @pytest.fixture(autouse=True)
+    def fresh_traces(self):
+        """The two conditionals are jitted on their own, so that a model's
+        layers share one trace; a test that patches what they call has to
+        have them traced anew, and leave no patched trace behind."""
+        from horovod_tpu.parallel import moe
+        jitted = (moe._forward_where_they_fit, moe._backward_where_they_fit)
+        for f in jitted:
+            f.clear_cache()
+        yield
+        for f in jitted:
+            f.clear_cache()
+
+    def test_buffer_rows(self):
+        from horovod_tpu.parallel.moe import ROW_TILE, SLACK, buffer_rows
+        assert (SLACK, ROW_TILE) == (1.5, 512)
+        assert buffer_rows(16384, 6, 16, 64) == 36864     # 2.25 a token
+        assert buffer_rows(T2, K, HELD, E) == 1024
+        assert buffer_rows(T2, K, E, E) == T2 * K         # every expert
+        assert buffer_rows(T, K, 2, E) == T * K           # under one tile
+
+    @pytest.mark.parametrize("leaf", LEAVES)
+    @pytest.mark.parametrize("wrap", ["plain", "remat"])
+    def test_the_buffer_equals_all_pairs(self, share, monkeypatch,
+                                         unwritten_rows, leaf, wrap):
+        """Output and every gradient through the 1024-row buffer against
+        the same layer made to move all 2048 pairs (``SLACK`` so large
+        that the buffer is every pair: the code a layer holding all
+        experts runs), to float32 rounding, with NaN in the rows the
+        grouped products do not write; ``remat`` as ``SmallThinkerBlock``
+        calls it, inside ``jit``."""
+        import flax.linen as nn
+        from horovod_tpu.parallel import moe
+        layer, params, x, r = share
+        if wrap == "remat":
+            layer = nn.remat(DroplessMoE)(E, K, D, F, experts_held=HELD,
+                                          first_expert=FIRST)
+        runs = _count_runs(monkeypatch)
+        got = _output_and_grads(layer, params, x, r)[leaf]
+        jax.effects_barrier()
+        assert set(runs) == {1024}, "the live pairs fit: the buffer's branch"
+        monkeypatch.setattr(moe, "SLACK", 1e9)
+        want = _output_and_grads(layer, params, x, r)[leaf]
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_allclose(got, want, atol=2e-6 * float(
+            jnp.abs(want).max()))
+
+    @pytest.mark.parametrize("leaf", LEAVES)
+    def test_overflow_runs_all_pairs_and_drops_nothing(self, share,
+                                                       monkeypatch, leaf):
+        """Every token routed to the two experts held, twice the buffer:
+        the branch over all pairs runs (and the buffer's does not), and
+        output and gradients are the dense sum's."""
+        layer, params, x, r = share
+        params, r = _crowded(params, r)
+        runs = _count_runs(monkeypatch)
+        got = _output_and_grads(layer, params, x, r)
+        jax.effects_barrier()
+        assert set(runs) == {T2 * K}
+        whole = dict(params, **{
+            name: jnp.zeros((E,) + params[name].shape[1:]).at[
+                FIRST:FIRST + HELD].set(params[name])
+            for name in ("w_gate_up", "w_down")})
+
+        class Dense:
+            def apply(self, variables, x, r):
+                return _dense(variables["params"], x, r,
+                              range(FIRST, FIRST + HELD))
+        want = _output_and_grads(Dense(), whole, x, r)
+        for name in ("w_gate_up", "w_down"):
+            want[name] = want[name][FIRST:FIRST + HELD]
+        rows = np.abs(np.asarray(got["output"])).reshape(T2, D).max(-1)
+        assert (rows > 0).all(), "a token got no expert"
+        assert float(jnp.abs(want[leaf]).max()) > 0
+        # 5e-5: the router's gradient here is a difference of two nearly
+        # equal sums over 32 columns
+        np.testing.assert_allclose(got[leaf], want[leaf], atol=5e-5 * float(
+            jnp.abs(want[leaf]).max()))
+
+    def test_every_expert_held_has_no_branch(self, setup):
+        params, x, r = setup
+        text = str(jax.make_jaxpr(lambda p, x, r: DroplessMoE(
+            E, K, D, F).apply({"params": p}, x, r))(params, x, r))
+        assert "cond" not in text and "custom_vjp_call" in text
+
+    def test_a_share_has_one_branch_each_way(self, share):
+        layer, params, x, r = share
+        text = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(
+            {"params": p}, x, r))))(params))
+        assert text.count("cond[") == 2         # forward, backward
+
+    def test_gauges_of_a_share_with_a_buffer(self, share):
+        from horovod_tpu import metrics
+        layer, params, x, r = share
+        metrics.instruments.MOE_OVERFLOW_CALLS.labels(1).set(7)
+        jax.eval_shape(lambda p: layer.apply({"params": p}, x, r), params)
+        snap = metrics.snapshot()
+        rows = {s["labels"]["axis_size"]: s["value"] for s in
+                snap["hvd_moe_buffer_rows_per_token"]["series"]}
+        assert rows == {"1": 1024 / T2}
+        calls = {s["labels"]["axis_size"]: s["value"] for s in
+                 snap["hvd_moe_overflow_calls"]["series"]}
+        assert calls == {"1": 0.0}
