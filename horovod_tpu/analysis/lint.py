@@ -78,11 +78,11 @@ _BOOTSTRAP_VARS = frozenset({
     "HVD_TEST_TIMEOUT",
 })
 
-# Recognized non-Config namespaces: bench-harness sweep parameters are
-# set per-invocation by the external bench driver (bench.py reads them on
-# the single process it runs on — nothing to propagate or document in the
-# runtime knob catalogue). HOROVOD_FUSION_THRESHOLD-style runtime knobs
-# must NOT move here.
+# Recognized non-Config namespaces: harness parameters are set
+# per-invocation by whoever runs the harness (the chaos soak reads
+# HVD_BENCH_PROGRESS_FILE on the single process it runs on — nothing to
+# propagate or document in the runtime knob catalogue).
+# HOROVOD_FUSION_THRESHOLD-style runtime knobs must NOT move here.
 # HVD_LOCK_* is the hvdrace runtime-witness namespace (HVD_LOCK_WITNESS,
 # HVD_LOCK_WITNESS_FILE): diagnostic instrumentation toggled per-process
 # by the person debugging, never launcher-propagated config.

@@ -15,7 +15,7 @@ recovery invariants the elastic stack promises:
 5. re-running the same plan + seed produces an identical injection-ledger
    schedule (the determinism contract of :mod:`horovod_tpu.chaos.plan`).
 
-Progress streams through the same JSONL channel as ``bench.py``
+Progress streams to a JSONL file, one flushed line per phase
 (``HVD_BENCH_PROGRESS_FILE``), so a wedged soak still leaves parseable
 evidence of how far it got. CLI wrapper: ``scripts/chaos_soak.py``;
 runbook: docs/robustness.md.
@@ -32,7 +32,7 @@ _T0 = time.perf_counter()
 
 
 def _progress(phase, **extra):
-    """One bench-channel JSONL record (same shape as bench.py's)."""
+    """One JSONL progress record: ts, elapsed_s, phase and the extras."""
     if not _PROGRESS_PATH:
         return
     try:

@@ -355,10 +355,6 @@ class Config:
     # one step exceed it. 0 = no budget declared.
     dcn_bytes_budget: int = 0
 
-    # --- bench/progress plumbing (bench.py, chaos/soak.py) ---
-    # JSONL progress stream, one record per phase mark ("" = off).
-    bench_progress_file: str = ""
-
     # --- serving (horovod_tpu/serving; docs/inference.md) ---
     # Serving mode: `hvdrun --serving` sets it; `python -m
     # horovod_tpu.serving` is the reference worker it launches.
@@ -669,8 +665,6 @@ class Config:
         c.flash_block = _env_int("HVD_FLASH_BLOCK", c.flash_block)
         c.dcn_bytes_budget = _env_int("HOROVOD_DCN_BYTES_BUDGET",
                                       c.dcn_bytes_budget)
-        c.bench_progress_file = os.environ.get("HVD_BENCH_PROGRESS_FILE",
-                                               c.bench_progress_file)
         c.serving = _env_bool("HOROVOD_SERVING", c.serving)
         c.serving_port = _env_int("HOROVOD_SERVING_PORT", c.serving_port)
         c.serving_slots = _env_int("HOROVOD_SERVING_SLOTS",

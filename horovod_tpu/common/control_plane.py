@@ -29,8 +29,7 @@ This module applies the same shape to the ACTUAL control plane:
   job-global scopes to the root listener.
 - ``simulate_exchange`` drives the REAL exchange implementations over an
   in-memory KV with one thread per virtual rank — the n=128-512 dryrun
-  tier (``docs/scale_validation.md``) and the ``bench.py control_sweep``
-  leg both measure through it.
+  tier (``docs/scale_validation.md``) counts through it.
 
 Strategy is env-gated: ``HOROVOD_CONTROL_PLANE=flat|hier`` ("" = auto,
 meaning hier whenever the slice layout has >1 slice). A 1-slice layout

@@ -11,7 +11,6 @@ Record kinds:
 
 - ``run_start``  run id, config fingerprint, world size, argv.
 - ``goodput``    a goodput ledger summary (periodic heartbeat + final).
-- ``bench``      a BENCH record ride-along from :mod:`bench`.
 - ``cluster``    final cluster view (telemetry job view, when present).
 - ``run_end``    clean-shutdown marker with the final goodput ratio — a
                  journal without one is a killed run, by definition.
@@ -148,8 +147,8 @@ def read_journal(path):
 
 def read_runs(root):
     """-> {run_id: summary} for every journal under ``root``. Each
-    summary: start record, last goodput record, bench records, cluster
-    view, whether the run ended cleanly."""
+    summary: start record, last goodput record, cluster view, whether
+    the run ended cleanly."""
     runs = {}
     try:
         names = sorted(os.listdir(root))
@@ -163,7 +162,7 @@ def read_runs(root):
             continue
         run_id = recs[0].get("run") or name[4:-6]
         summary = {"run": run_id, "path": os.path.join(root, name),
-                   "records": len(recs), "bench": [], "goodput": None,
+                   "records": len(recs), "goodput": None,
                    "cluster": None, "start": None, "ended": False}
         for rec in recs:
             kind = rec.get("kind")
@@ -171,8 +170,6 @@ def read_runs(root):
                 summary["start"] = rec
             elif kind == "goodput":
                 summary["goodput"] = rec
-            elif kind == "bench":
-                summary["bench"].append(rec)
             elif kind == "cluster":
                 summary["cluster"] = rec.get("view")
             elif kind == "run_end":

@@ -106,8 +106,6 @@ def render_run(summary):
     victim = find_victim(summary)
     if victim is not None:
         lines.append(f"  victim: rank {victim[0]} ({victim[1]})")
-    if summary.get("bench"):
-        lines.append(f"  bench records: {len(summary['bench'])}")
     return lines
 
 

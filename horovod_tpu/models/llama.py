@@ -73,7 +73,7 @@ class LlamaConfig:
     @staticmethod
     def bench(**kw):
         """~400M-param config sized so a full training step (fp32 master +
-        adam moments) fits one chip's HBM for bench.py."""
+        adam moments) fits one chip's HBM."""
         base = dict(vocab_size=32000, hidden_size=1024, num_layers=24,
                     num_heads=16, num_kv_heads=8, intermediate_size=2816,
                     max_position_embeddings=4096)

@@ -508,7 +508,7 @@ def digest(last=32):
 
 def step_report_summary():
     """Aggregate over the retained records: mean/p50 wall, per-category
-    attribution means, mean MFU — the bench.py ride-along field."""
+    attribution means, mean MFU."""
     return _ledger.summary()
 
 

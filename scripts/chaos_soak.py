@@ -4,7 +4,7 @@
 Runs the :mod:`horovod_tpu.chaos.soak` harness — a clean elastic run, a
 chaos run under a seeded fault plan (worker kill + KV drop + straggler by
 default, or ``--plan``), and a same-seed re-run — then prints ONE JSON
-line with the verdict and evidence, in the same spirit as ``bench.py``.
+line with the verdict and evidence.
 Partial progress streams to the ``HVD_BENCH_PROGRESS_FILE`` JSONL channel
 (default ``bench_progress.jsonl``), so a wedged soak still leaves evidence.
 

@@ -31,7 +31,7 @@ import os
 import sys
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-DEFAULT_SCOPE = ("horovod_tpu", "examples", "scripts", "bench.py")
+DEFAULT_SCOPE = ("horovod_tpu", "examples", "scripts")
 # hvdrace needs whole-package lock/call-graph resolution, so its scope is
 # the package tree (analyzing unrelated scripts would only add pseudo
 # locks without adding resolvable call edges).

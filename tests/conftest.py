@@ -10,8 +10,9 @@ import os
 import tempfile
 
 # Force CPU even when the environment pins a TPU platform (tests model the
-# multi-chip mesh with virtual CPU devices; chip_smoke.py and bench.py use
-# the real chip). Subprocesses the tests spawn inherit it.
+# multi-chip mesh with virtual CPU devices; chip_smoke.py and
+# benchmark/run.py use the real chip). Subprocesses the tests spawn inherit
+# it.
 os.environ["JAX_PLATFORMS"] = "cpu"
 # One fixed persistent-compile-cache directory OUTSIDE the checkout for the
 # whole suite and every worker it spawns: hvd.init() always arms the cache,
@@ -59,7 +60,7 @@ def _reap_orphaned_workers():
     """Session-start hygiene: kill `horovod_tpu.runner.task` orphans left
     by PRIOR timed-out runs (pytest dies under `timeout -k`, its worker
     clusters re-parent to init and poll their dead KV forever — skewing
-    every timing, perf baseline and bench number on this 2-core box; see
+    every timing and perf baseline on this 2-core box; see
     the ROADMAP re-anchor note @ PR 10). Orphans-only (ppid 1), so a
     concurrently running suite's live workers are never touched.
     HVD_REAP_WORKERS=0 opts out."""

@@ -81,6 +81,41 @@ class TestConservation:
         assert snap["steps"] == 10 and snap["resets"] == 0
         assert snap["conservation_error"] <= 1e-9
 
+    def test_every_fault_of_one_schedule_is_recovered_exactly(self):
+        """One run with each badput source in turn (a compile stall,
+        straggler steps, a commit, a trial window, exposed cross-slice
+        waits, a wedge, a reset): every injected second lands in its own
+        category and in no other."""
+        led = GoodputLedger()
+        led.start(0.0)
+        led.on_step_boundary(None, step=0, now=5.0)        # compile stall
+        t, step = _steps(led, 5.0, 12, comm=0.1)           # comm median
+        t, step = _steps(led, t, 4, comm=0.6, first=step)  # 0.5 s over it
+        led.note_commit(2.0)                # eats the next two windows
+        t, step = _steps(led, t, 2, comm=0.1, first=step)
+        led.set_trial(True)
+        t, step = _steps(led, t, 3, comm=0.1, first=step)
+        led.set_trial(False)
+        for _ in range(2):
+            t += 1.0
+            led.on_step_boundary(_rec(comm=0.1, cross=0.3), step=step, now=t)
+            step += 1
+        led.note_wedge(now=t)
+        t += 2.0
+        led.note_unwedged(now=t)
+        t += 1.5                            # the window a reset loses,
+        led.on_reset(now=t)
+        t += 3.0                            # and the re-rendezvous
+        led.on_step_boundary(None, step=step, now=t)
+        t, step = _steps(led, t, 2, comm=0.1, first=step + 1)
+        cats = led.assert_conservation(t, tol=1e-9)["categories"]
+        assert {c: v for c, v in cats.items() if v} == pytest.approx({
+            "init_compile": 5.0, "straggler_wait": 2.0,
+            "checkpoint_commit": 2.0, "autopilot_trial": 3.0,
+            "cross_wait_comm": 0.6, "wedge_idle": 2.0,
+            "rendezvous_recovery": 4.5,
+            PRODUCTIVE: 12.0 + 4 * 0.5 + 2 * 0.7 + 2.0})
+
     def test_snapshot_attributes_live_tail_virtually(self):
         led = GoodputLedger()
         led.start(0.0)
