@@ -68,7 +68,10 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
 - ``hvd_flash_tiles{kernel,kind}``                  score tiles per
   (batch, head) of the last traced flash-attention call
   (kernel=fwd|bwd_dq|bwd_dkv; kind=total|visited|masked, masked = visited
-  with mask code; gauge, set while the call is traced)
+  with mask code, and blocks_inside|blocks_diagonal|blocks_edge|
+  blocks_skipped: the 1024 x 1024 blocks of each kind where a long causal
+  call runs its static schedule by block kind, 0 elsewhere; gauge, set
+  while the call is traced)
 - ``hvd_moe_experts{kind}``                         experts of the last
   traced ``parallel.moe.DroplessMoE`` call (kind=routed|held|per_token:
   the router's width, the experts this layer holds, the experts a token
@@ -321,8 +324,11 @@ FLASH_TILES = REGISTRY.gauge(
     "hvd_flash_tiles",
     "Score tiles per (batch, head) of the last traced flash-attention "
     "call, by kernel (fwd|bwd_dq|bwd_dkv) and kind: total, visited (not "
-    "wholly masked) and masked (visited with mask code: the diagonal or "
-    "the padding edge crosses the tile). From the function that gives "
+    "wholly masked) and masked (visited with mask code: the diagonal, "
+    "the window's or the padding edge crosses the tile); blocks_inside, "
+    "blocks_diagonal, blocks_edge, blocks_skipped: the 1024 x 1024 blocks "
+    "of each kind where a causal call past 1024 runs the static schedule "
+    "by block kind (0 on every other call). From the functions that give "
     "the kernels their loop bounds. Set while the call is traced.",
     ("kernel", "kind"))
 MOE_EXPERTS = REGISTRY.gauge(
@@ -716,8 +722,9 @@ def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
 
 def record_flash_tiles(kernel, counts):
     """The tile schedule of one flash-attention kernel call, known while
-    it is traced: ``counts`` maps kind (total|visited|masked) to tiles
-    per (batch, head)."""
+    it is traced: ``counts`` maps kind (total|visited|masked, tiles; the
+    four blocks_*, blocks of 1024 a side) to its number per (batch,
+    head)."""
     if not _enabled:
         return
     for kind, n in counts.items():

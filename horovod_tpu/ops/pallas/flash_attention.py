@@ -11,7 +11,9 @@ _query_tile_bounds): a causal call skips the tiles over the diagonal and
 masks only the tiles the diagonal or the padding edge crosses; with a
 sliding ``window`` it also skips the tiles wholly behind the window and
 masks the tiles the window's edge crosses (_key_window_bounds,
-_query_window_bounds).
+_query_window_bounds). A long causal sequence is cut into blocks of
+_OUTER_CHUNK a side, and each block runs the static schedule of its kind
+(_block_kinds: diagonal, inside, the window's edge).
 
 The reference framework has no attention code (SURVEY.md §5.7 — Horovod
 operates below the model level); this kernel is part of the TPU build's
@@ -44,6 +46,13 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
+def _env_cap(cap):
+    """``cap``, or HVD_FLASH_BLOCK (at least 128) where that is lower."""
+    import os
+    env_cap = os.environ.get("HVD_FLASH_BLOCK")
+    return min(cap, max(128, int(env_cap))) if env_cap else cap
+
+
 def _pick_block(length, cap=1024):
     """Tile side for a sequence of ``length``: its largest divisor among
     1024, 512, 256 and 128 up to ``cap``; a sequence under 128 is one tile
@@ -53,10 +62,7 @@ def _pick_block(length, cap=1024):
 
     HVD_FLASH_BLOCK caps the tile lower for on-chip sweeps (128, 256 or
     512 per model without code edits); it never raises a tile."""
-    import os
-    env_cap = os.environ.get("HVD_FLASH_BLOCK")
-    if env_cap:
-        cap = min(cap, max(128, int(env_cap)))
+    cap = _env_cap(cap)
     for b in (1024, 512, 256, 128):
         if b <= cap and length % b == 0:
             return b
@@ -99,6 +105,45 @@ def _pick_tiles(lq, lk, causal, kernel="fwd"):
     bq, bk = (_pick_block(n, min(cap, max(128, n // 2)) if small else cap)
               for n, cap in zip((lq, lk), caps))
     return (bq, bk) if bq and bk else None
+
+
+# (block_q, block_k) inside one block of _OUTER_CHUNK a side of a causal call
+# that is longer (_by_block), by kernel: ``crossed`` for a block the diagonal
+# or the window's edge crosses, ``inside`` for a block wholly under the
+# diagonal and inside the window, which has no mask code and nothing to skip.
+# From two sweeps on a v5e at 2 x 28 heads x 8192 x 128, ms a call, causal /
+# window 4096 (PERF.md, PR 32; the rolled 1024 x 1024 tiles took 10.72 /
+# 10.05, 10.27 / 8.96 and 13.98 / 12.39). An inside block is one tile in all
+# three: the static tile runs in 0.82, 0.95 and 0.91 of the rolled one's
+# time, and 128 x 512 tiles took 1.65x as long in the forward kernel, whose
+# row statistics cost a fixed sum per 128 rows of every tile. For the same
+# reason its crossed block is one masked tile too (7.80 / 6.78; 256 x 512:
+# 8.56 / 7.73). dQ: 8.52 / 6.82 (512 x 512: 8.65 / 7.02). dK/dV: 10.85 /
+# 8.57; 128 x 128 read 10.75 / 8.40 and takes 0.9 s longer to lower.
+_BLOCK_TILE = {
+    "fwd": {"crossed": (1024, 1024), "inside": (1024, 1024)},
+    "bwd_dq": {"crossed": (256, 256), "inside": (1024, 1024)},
+    "bwd_dkv": {"crossed": (256, 256), "inside": (1024, 1024)},
+}
+
+
+def _by_block(lq, lk, q_offset, kv_valid, causal, window):
+    """Whether a call runs the static schedule by block kind: a causal call
+    longer than _OUTER_CHUNK on both axes, with no padding, whose lengths,
+    offset and window are whole blocks, so that the mask's edges cross a
+    block only corner to corner. Every other call keeps one tile shape and
+    bounds that follow the chunk index."""
+    whole = (lq, lk, q_offset) + (() if window is None else (window,))
+    return bool(causal and kv_valid == lk and min(lq, lk) > _OUTER_CHUNK
+                and (window is None or window > 0)
+                and not any(n % _OUTER_CHUNK for n in whole))
+
+
+def _block_tiles(kernel):
+    """((block_q, block_k) of a crossed block, of an inside block)."""
+    cap = _env_cap(_OUTER_CHUNK)
+    return tuple(tuple(min(side, cap) for side in _BLOCK_TILE[kernel][kind])
+                 for kind in ("crossed", "inside"))
 
 
 def _vma(*operands):
@@ -282,29 +327,103 @@ def _in_chunk(bounds, c, tpc, n_tiles):
     return tuple(one(b) for b in bounds)
 
 
+def _visited_tiles(kernel, lq, lk, q_offset, kv_valid, block_q, block_k,
+                   causal, window=None):
+    """(first row, first key, masked) of every score tile one call of
+    ``kernel`` visits, masked = with mask code, from the bounds the
+    kernels sweep by: the forward and dQ kernels rows of tiles, the dK/dV
+    kernel columns."""
+    by_key = kernel == "bwd_dkv"
+    n_qt, n_kt = lq // block_q, lk // block_k
+    for o in range(n_kt if by_key else n_qt):
+        # Either way the four bounds are: masked, plain, masked.
+        a, b, c, d = _query_sweeps(
+            o * block_k, block_q, block_k, n_qt, q_offset, kv_valid, causal,
+            window) if by_key else _key_sweeps(
+            o * block_q, block_q, block_k, n_kt, q_offset, kv_valid, causal,
+            window)
+        for t in range(a, d):
+            row, col = (t, o) if by_key else (o, t)
+            yield row * block_q, col * block_k, not b <= t < c
+
+
 def tile_counts(kernel, lq, lk, q_offset, kv_valid, block_q, block_k,
                 causal, window=None):
     """Score tiles per (batch, head) of one call of ``kernel``: ``total``,
-    ``visited`` and ``masked`` (visited with mask code). The forward and dQ
-    kernels sweep rows of tiles, the dK/dV kernel columns."""
-    n_qt, n_kt = lq // block_q, lk // block_k
-    if kernel == "bwd_dkv":
-        sweeps = [_query_sweeps(j * block_k, block_q, block_k, n_qt,
-                                q_offset, kv_valid, causal, window)
-                  for j in range(n_kt)]
-    else:
-        sweeps = [_key_sweeps(i * block_q, block_q, block_k, n_kt,
-                              q_offset, kv_valid, causal, window)
-                  for i in range(n_qt)]
-    # Either way the four bounds are: masked, plain, masked.
-    visited = sum(d - a for a, _, _, d in sweeps)
-    masked = sum((b - a) + (d - c) for a, b, c, d in sweeps)
-    return {"total": n_qt * n_kt, "visited": visited, "masked": masked}
+    ``visited`` and ``masked`` (visited with mask code)."""
+    masked = [m for _, _, m in _visited_tiles(
+        kernel, lq, lk, q_offset, kv_valid, block_q, block_k, causal, window)]
+    return {"total": (lq // block_q) * (lk // block_k),
+            "visited": len(masked), "masked": sum(masked)}
 
 
-def _record_tiles(kernel, *schedule):
+def _block_kinds(delta, block, window):
+    """What a block of ``block`` a side can be, by ``delta``: its query
+    index (the offset counted in) less its key index. A list of (kind,
+    whether the block is of it, the (q_offset, window) that put the mask's
+    edges where they lie in such a block, counted from the block's own
+    corner). A block of no kind is wholly masked and not visited.
+    ``delta`` may be a Python int or a traced scalar."""
+    diagonal = ("diagonal", delta == 0, (0, None))
+    if window is None:
+        return [diagonal, ("inside", delta > 0, (block, None))]
+    w = window // block
+    # Inside the window every key of the block is seen by every row, as it
+    # is one block under the diagonal of a call with no window.
+    inside = [("inside", (delta > 0) & (delta < w), (block, None))]
+    return [diagonal] + inside * (w > 1) + [
+        ("edge", delta == w, (block, block))]
+
+
+def _tile_of(tiles, kind):
+    """(block_q, block_k) of a block of ``kind`` from _block_tiles' pair."""
+    return tiles[kind == "inside"]
+
+
+def block_tiles(kernel, lq, lk, q_offset, window, tiles, block):
+    """The score tiles one call of ``kernel`` visits under the schedule by
+    block kind, as (kind, first row, first key, block_q, block_k, masked):
+    every block of a kind is the square _visited_tiles cuts for it."""
+    for i in range(lq // block):
+        for j in range(lk // block):
+            for kind, is_kind, (off, win) in _block_kinds(
+                    i + q_offset // block - j, block, window):
+                if is_kind:
+                    tile = _tile_of(tiles, kind)
+                    for row, col, masked in _visited_tiles(
+                            kernel, block, block, off, block, *tile, True,
+                            win):
+                        yield (kind, i * block + row, j * block + col, *tile,
+                               masked)
+
+
+def block_counts(kernel, lq, lk, q_offset, window, tiles, block):
+    """tile_counts under the schedule by block kind: ``visited`` and
+    ``masked`` tiles as block_tiles gives them, ``blocks_<kind>`` the
+    blocks of each kind, ``total`` every block cut in its kind's tiles (a
+    skipped block in the crossed kind's)."""
+    visited = list(block_tiles(kernel, lq, lk, q_offset, window, tiles,
+                               block))
+    blocks = {(r // block, c // block): kind
+              for kind, r, c, *_ in visited}
+    counts = {f"blocks_{kind}": list(blocks.values()).count(kind)
+              for kind in ("inside", "diagonal", "edge")}
+    n_blocks = (lq // block) * (lk // block)
+    counts["blocks_skipped"] = n_blocks - len(blocks)
+    (cq, ck), (iq, ik) = tiles
+    n_inside = counts["blocks_inside"]
+    counts["total"] = (n_inside * (block // iq) * (block // ik)
+                       + (n_blocks - n_inside) * (block // cq) * (block // ck))
+    counts["visited"] = len(visited)
+    counts["masked"] = sum(t[-1] for t in visited)
+    return counts
+
+
+def _record_tiles(kernel, counts):
     from horovod_tpu.metrics import instruments as hvd_metrics
-    hvd_metrics.record_flash_tiles(kernel, tile_counts(kernel, *schedule))
+    blocks = dict.fromkeys(("blocks_inside", "blocks_diagonal",
+                            "blocks_edge", "blocks_skipped"), 0)
+    hvd_metrics.record_flash_tiles(kernel, {**blocks, **counts})
 
 
 # A statically bounded sweep of up to this many tiles is unrolled.
@@ -362,8 +481,88 @@ def _apply_mask(s, *, causal, masked, q0, k0, kv_valid, q_axis=0,
     return jnp.where(ok, s, NEG_INF)
 
 
+def _from(base, i):
+    """``base + i``; a static zero base adds no op (a call outside the
+    schedule by block kind keeps its code to the letter)."""
+    return i if isinstance(base, int) and base == 0 else base + i
+
+
+def _each_block(n, block, fn):
+    """``fn(first row)`` for the n blocks of ``block`` rows of a chunk: a
+    rolled loop, one copy of the code whatever the chunk holds (a block's
+    own sweeps unroll: their bounds do not depend on where it lies)."""
+    if n == 1:
+        return fn(0)
+
+    def body(b, carry):
+        fn(pl.multiple_of(b * block, block))
+        return carry
+
+    jax.lax.fori_loop(0, n, body, 0)
+
+
+def _sweep_chunk(sweep_tile, ic, jc, *, by_key, tiles, block, chunks,
+                 q_offset, n_swept, kv_valid, causal, masked, window):
+    """The schedule of one grid step: chunk ``ic`` of the axis the kernel
+    writes (queries; ``by_key`` keys, the dK/dV kernel) against chunk ``jc``
+    of ``n_swept`` of the axis it sweeps. For every tile of the written
+    chunk, ``sweep_tile(at, swept0, side, bounds, mask)``: the tile at
+    ``at`` of its chunk against the tiles of ``side`` that ``bounds``
+    (_key_sweeps; by_key _query_sweeps) name, counted from position
+    ``swept0`` of the swept chunk; ``mask(s, t)`` masks tile t of them.
+
+    ``block`` None: one tile shape, ``tiles``, and bounds that follow the
+    chunk index. Else (_by_block) the written chunk is one block of
+    ``block`` a side, and each block of the swept chunk runs the sweeps of
+    its kind, at that kind's tiles, with static bounds."""
+    w = int(by_key)                  # of (queries, keys): the axis written
+    sweeps = _query_sweeps if by_key else _key_sweeps
+
+    def run(tile, origin, n, swept0, swept_origin, bounds_of, off, **edges):
+        """The ``n`` written positions from ``origin`` on, tile by tile,
+        against tiles that start at position ``swept_origin``; ``off`` and
+        ``edges``: the q_offset and the rest of what _apply_mask takes."""
+        for i in range(n // tile[w]):
+            o = origin + i * tile[w]               # first position, this tile
+
+            def mask(s, t, o=o):
+                at = swept_origin + t * tile[1 - w]
+                q0, k0 = (at, o) if by_key else (o, at)
+                return _apply_mask(s, q0=off + q0, k0=k0, q_axis=w, **edges)
+
+            sweep_tile(pl.ds(i * tile[w], tile[w]), swept0, tile[1 - w],
+                       bounds_of(o), mask)
+
+    if block is None:
+        tpc = chunks[1 - w] // tiles[1 - w]        # swept tiles per chunk
+        run(tiles, ic * chunks[w], chunks[w], 0, jc * chunks[1 - w],
+            lambda o: _in_chunk(
+                sweeps(o, *tiles, n_swept * tpc, q_offset, kv_valid, causal,
+                       window), jc, tpc, n_swept * tpc),
+            # End-aligned causal convention (tril with k = Lk - Lq),
+            # matching local_attention.
+            q_offset, causal=causal, masked=masked, kv_valid=kv_valid,
+            window=window)
+        return
+
+    def one_block(swept0):
+        at = jc * chunks[1 - w] + swept0           # of the swept axis
+        delta = (at + q_offset) // block - ic if by_key \
+            else ic + (q_offset - at) // block
+        # Rows and keys count from the block's own corner from here on.
+        for kind, is_kind, (off, win) in _block_kinds(delta, block, window):
+            tile = _tile_of(tiles, kind)
+            _when(is_kind, functools.partial(
+                run, tile, 0, block, swept0, 0,
+                lambda o: sweeps(o, *tile, block // tile[1 - w], off, block,
+                                 True, win),
+                off, causal=True, masked=False, kv_valid=block, window=win))
+
+    _each_block(chunks[1 - w] // block, block, one_block)
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-               *, sm_scale, causal, block_q, block_k, q_chunk, k_chunk,
+               *, sm_scale, causal, tiles, block, q_chunk, k_chunk,
                q_offset, n_qc, n_kc, kv_valid, masked, window):
     """One (query-chunk, key-chunk) grid step of the online softmax.
 
@@ -372,13 +571,13 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     registers within a query tile's sweep over the chunk's key tiles:
     with a window first the tiles its edge crosses, then the tiles wholly
     under the diagonal and inside kv_valid (no mask code in the loop
-    body), then those the diagonal or the padding edge crosses.
+    body), then those the diagonal or the padding edge crosses; past
+    _OUTER_CHUNK block by block (_sweep_chunk).
     """
     # A grid axis of one chunk gives a STATIC chunk index, and with both
     # static every loop bound below is a compile-time constant.
     ic = 0 if n_qc == 1 else pl.program_id(1)
     jc = 0 if n_kc == 1 else pl.program_id(2)
-    tpc = k_chunk // block_k                       # key tiles per chunk
 
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -387,32 +586,24 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 
     _when(jc == 0, _init)
 
-    for tq in range(q_chunk // block_q):
-        rows = pl.ds(tq * block_q, block_q)
-        q0 = ic * q_chunk + tq * block_q           # first row, this tile
-        n_skip, n_low, n_plain, n_vis = _in_chunk(
-            _key_sweeps(q0, block_q, block_k, n_kc * tpc, q_offset,
-                        kv_valid, causal, window), jc, tpc, n_kc * tpc)
+    def row_of_tiles(rows, keys0, block_k, bounds, mask):
+        """The query tile at ``rows`` of its chunk against key tiles of
+        ``block_k``, counted from key ``keys0`` of their chunk, that
+        ``bounds`` (_key_sweeps) name; ``mask(s, t)`` masks tile t."""
+        n_skip, n_low, n_plain, n_vis = bounds
 
-        def _compute(rows=rows, q0=q0, n_skip=n_skip, n_low=n_low,
-                     n_plain=n_plain, n_vis=n_vis):
+        def _compute():
             q = q_ref[0, rows, :].astype(jnp.float32) * sm_scale  # (BQ, D)
 
             def body(t, carry, crossed):
                 m, l, acc = carry
-                kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(
-                    jnp.float32)
-                vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(
-                    jnp.float32)
+                keys = pl.ds(_from(keys0, t * block_k), block_k)
+                kb = k_ref[0, keys, :].astype(jnp.float32)
+                vb = v_ref[0, keys, :].astype(jnp.float32)
                 s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32)
                 if crossed:
-                    # End-aligned causal convention (tril with k = Lk -
-                    # Lq), matching local_attention and the backward pass.
-                    s = _apply_mask(s, causal=causal, masked=masked,
-                                    q0=q_offset + q0,
-                                    k0=jc * k_chunk + t * block_k,
-                                    kv_valid=kv_valid, window=window)
+                    s = mask(s, t)
                 m_new = jnp.maximum(m, jnp.max(s, axis=-1))
                 corr = jnp.exp(m - m_new)
                 p = jnp.exp(s - m_new[:, None])
@@ -438,6 +629,11 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             acc_ref[rows, :] = acc
 
         _when(n_vis > n_skip, _compute)
+
+    _sweep_chunk(row_of_tiles, ic, jc, by_key=False, tiles=tiles,
+                 block=block, chunks=(q_chunk, k_chunk), q_offset=q_offset,
+                 n_swept=n_kc, kv_valid=kv_valid, causal=causal,
+                 masked=masked, window=window)
 
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)            # (q_chunk, 1)
@@ -467,24 +663,65 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
     chunks to its ``heads/kv_heads`` query heads via the BlockSpec index
     map — no materialized broadcast, 1/g the K/V HBM traffic."""
     lq, lk = q.shape[1], k.shape[1]
-    if block_q is None:
-        block_q, block_k = _pick_tiles(lq, lk, causal, "fwd")
     if q_offset is None:
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
     if window is not None and not causal:
         raise ValueError("a sliding window needs causal=True")
-    _record_tiles("fwd", lq, lk, q_offset, kv_valid, block_q, block_k,
-                  causal, window)
+    tiles, block = _schedule("fwd", lq, lk, q_offset, kv_valid, causal,
+                             window, block_q, block_k)
     gqa = heads is not None and kv_heads is not None and heads != kv_heads
     return _fwd_call(
-        q, k, v, causal=causal, sm_scale=sm_scale,
-        tiles=(block_q, block_k),
-        chunks=(_pick_chunk(lq, block_q, _OUTER_CHUNK),
-                _pick_chunk(lk, block_k)),
+        q, k, v, causal=causal, sm_scale=sm_scale, tiles=tiles, block=block,
+        chunks=(_pick_chunk(lq, block or tiles[0], _OUTER_CHUNK),
+                _pick_chunk(lk, block or tiles[1])),
         q_offset=q_offset, kv_valid=kv_valid, window=window,
         group=(heads, kv_heads) if gqa else None, interpret=_interpret())
+
+
+def _schedule(kernel, lq, lk, q_offset, kv_valid, causal, window,
+              block_q=None, block_k=None):
+    """(tiles, block) of one call of ``kernel``, read off the call's shapes
+    alone, and the hvd_flash_tiles gauge set to what they visit: by block
+    kind (_by_block; tiles as _block_tiles gives them, block =
+    _OUTER_CHUNK), else one (block_q, block_k), :func:`_pick_tiles`' unless
+    given, and block None."""
+    if block_q is None and _by_block(lq, lk, q_offset, kv_valid, causal,
+                                     window):
+        tiles, block = _block_tiles(kernel), _OUTER_CHUNK
+        _record_tiles(kernel, block_counts(kernel, lq, lk, q_offset, window,
+                                           tiles, block))
+        return tiles, block
+    tiles = (block_q, block_k) if block_q else \
+        _pick_tiles(lq, lk, causal, kernel)
+    _record_tiles(kernel, tile_counts(kernel, lq, lk, q_offset, kv_valid,
+                                      *tiles, causal, window))
+    return tiles, None
+
+
+def _fetched(i, j, *, by_key, block, chunk, n, q_offset, window):
+    """The chunk of the swept axis (of ``n`` positions in chunks of
+    ``chunk``) that grid step (i, j) fetches: its own, j, on every path but
+    the schedule by block kind. There the written chunk i is one block,
+    which sees the blocks from the window's edge to the diagonal (a query
+    block; ``by_key`` a key block, which the rows from the diagonal to the
+    window's edge see), and a step whose chunk holds none of them asks for
+    the nearest chunk that does: the one the pipeline already holds, so
+    that no copy starts for a step that computes nothing (4096 keys and
+    values of 128 are 2 MiB, 2.6 us of HBM time)."""
+    if block is None:
+        return j
+    off, last_block = q_offset // block, n // block - 1
+    if by_key:
+        first = i - off
+        last = last_block if window is None else first + window // block
+    else:
+        last = i + off
+        first = 0 if window is None else last - window // block
+    first, last = (jnp.clip(x, 0, last_block) // (chunk // block)
+                   for x in (first, last))
+    return jnp.clip(j, first, last)
 
 
 # The pallas_calls sit in jitted functions of their own, every choice a
@@ -492,28 +729,33 @@ def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
 # kernel and not 24: a kernel body of a dozen unrolled tiles takes 0.1-0.3 s
 # to trace, and traced per layer the three kernels added 50 s to the set-up
 # of gpt2m_1chip (PERF.md, PR 27).
-_CALL_STATICS = ("causal", "sm_scale", "tiles", "chunks", "q_offset",
-                 "kv_valid", "window", "interpret")
+_CALL_STATICS = ("causal", "sm_scale", "tiles", "block", "chunks",
+                 "q_offset", "kv_valid", "window", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("group",))
-def _fwd_call(q, k, v, *, causal, sm_scale, tiles, chunks, q_offset,
+def _fwd_call(q, k, v, *, causal, sm_scale, tiles, block, chunks, q_offset,
               kv_valid, window, group, interpret):
     bh, lq, d = q.shape
     lk = k.shape[1]
-    (block_q, block_k), (q_chunk, k_chunk) = tiles, chunks
+    q_chunk, k_chunk = chunks
+    swept = functools.partial(_fetched, by_key=False, block=block,
+                              chunk=k_chunk, n=lk, q_offset=q_offset,
+                              window=window)
+
     if group is None:
         def kv_map(b, i, j):
-            return (b, j, 0)
+            return (b, swept(i, j), 0)
     else:
         heads, kv_heads = group
         g = heads // kv_heads
 
         def kv_map(b, i, j):
-            return ((b // heads) * kv_heads + (b % heads) // g, j, 0)
+            return ((b // heads) * kv_heads + (b % heads) // g, swept(i, j),
+                    0)
     n_qc, n_kc = lq // q_chunk, lk // k_chunk
     kernel = functools.partial(_fa_kernel, sm_scale=sm_scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
+                               tiles=tiles, block=block,
                                q_chunk=q_chunk, k_chunk=k_chunk,
                                q_offset=q_offset, n_qc=n_qc, n_kc=n_kc,
                                kv_valid=kv_valid, masked=kv_valid < lk,
@@ -573,49 +815,39 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q=None, block_k=None,
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, acc_ref, *, sm_scale, causal, block_q,
-                      block_k, q_chunk, k_chunk, q_offset, n_qc, n_kc,
-                      kv_valid, masked, window):
+                      dq_ref, acc_ref, *, sm_scale, causal, tiles, block,
+                      q_chunk, k_chunk, q_offset, n_qc, n_kc, kv_valid,
+                      masked, window):
     """dQ pass: (query-chunk, key-chunk) grid with the dq accumulator in
     scratch across key chunks; per query tile the same register sweeps
     over the chunk's key tiles as _fa_kernel (the window's edge, plain,
-    the diagonal)."""
+    the diagonal), and with ``block`` the same schedule by block kind."""
     ic = 0 if n_qc == 1 else pl.program_id(1)
     jc = 0 if n_kc == 1 else pl.program_id(2)
-    tpc = k_chunk // block_k
 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     _when(jc == 0, _init)
 
-    for tq in range(q_chunk // block_q):
-        rows = pl.ds(tq * block_q, block_q)
-        q0 = ic * q_chunk + tq * block_q
-        n_skip, n_low, n_plain, n_vis = _in_chunk(
-            _key_sweeps(q0, block_q, block_k, n_kc * tpc, q_offset,
-                        kv_valid, causal, window), jc, tpc, n_kc * tpc)
+    def row_of_tiles(rows, keys0, block_k, bounds, mask):
+        n_skip, n_low, n_plain, n_vis = bounds
 
-        def _compute(rows=rows, q0=q0, n_skip=n_skip, n_low=n_low,
-                     n_plain=n_plain, n_vis=n_vis):
+        def _compute():
             q = q_ref[0, rows, :].astype(jnp.float32)              # (BQ, D)
             do = do_ref[0, rows, :].astype(jnp.float32)
             lse = lse_ref[0, 0, rows]                              # (BQ,)
             delta = delta_ref[0, 0, rows]
 
             def body(t, dq, crossed):
-                kb = k_ref[0, pl.ds(t * block_k, block_k), :].astype(
-                    jnp.float32)
-                vb = v_ref[0, pl.ds(t * block_k, block_k), :].astype(
-                    jnp.float32)
+                keys = pl.ds(_from(keys0, t * block_k), block_k)
+                kb = k_ref[0, keys, :].astype(jnp.float32)
+                vb = v_ref[0, keys, :].astype(jnp.float32)
                 s = jax.lax.dot_general(
                     q, kb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * sm_scale
                 if crossed:
-                    s = _apply_mask(s, causal=causal, masked=masked,
-                                    q0=q_offset + q0,
-                                    k0=jc * k_chunk + t * block_k,
-                                    kv_valid=kv_valid, window=window)
+                    s = mask(s, t)
                     p = jnp.where(s > NEG_INF * 0.5,
                                   jnp.exp(s - lse[:, None]), 0.0)
                 else:
@@ -636,6 +868,11 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         _when(n_vis > n_skip, _compute)
 
+    _sweep_chunk(row_of_tiles, ic, jc, by_key=False, tiles=tiles,
+                 block=block, chunks=(q_chunk, k_chunk), q_offset=q_offset,
+                 n_swept=n_kc, kv_valid=kv_valid, causal=causal,
+                 masked=masked, window=window)
+
     def _finalize():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
@@ -644,13 +881,15 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                       block_q, block_k, q_chunk, k_chunk, q_offset, n_qc,
+                       tiles, block, q_chunk, k_chunk, q_offset, n_qc,
                        n_kc, kv_valid, masked, window):
     """dK/dV pass: (key-chunk, query-chunk) grid; per-key-chunk
     accumulators in scratch across query chunks; per key tile register
     sweeps over the chunk's query tiles: first those the mask edge
     crosses (the diagonal comes first going down a column), then the
-    plain ones under it, then with a window those its edge crosses.
+    plain ones under it, then with a window those its edge crosses. With
+    ``block`` the key chunk is one block and each block of the query chunk
+    runs the sweeps of its kind.
 
     The tile is computed TRANSPOSED, (BK, BQ) = k q^T, so that both
     accumulating products (p^T dO, ds^T q) contract the tile's lane axis
@@ -660,7 +899,6 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     PR 27). lse and delta arrive as (1, q_chunk) rows for it."""
     ic = 0 if n_kc == 1 else pl.program_id(1)      # key chunk (written)
     jc = 0 if n_qc == 1 else pl.program_id(2)      # query chunk (swept)
-    tpc = q_chunk // block_q                       # query tiles per chunk
 
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
@@ -668,21 +906,19 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _when(jc == 0, _init)
 
-    for tk in range(k_chunk // block_k):
-        cols = pl.ds(tk * block_k, block_k)
-        k0 = ic * k_chunk + tk * block_k
-        t_first, t_plain, t_high, t_end = _in_chunk(
-            _query_sweeps(k0, block_q, block_k, n_qc * tpc, q_offset,
-                          kv_valid, causal, window), jc, tpc, n_qc * tpc)
+    def column_of_tiles(cols, rows0, block_q, bounds, mask):
+        """The key tile at ``cols`` of its chunk against query tiles of
+        ``block_q``, counted from row ``rows0`` of their chunk, that
+        ``bounds`` (_query_sweeps) name; ``mask(s, t)`` masks tile t."""
+        t_first, t_plain, t_high, t_end = bounds
 
-        def _compute(cols=cols, k0=k0, t_first=t_first, t_plain=t_plain,
-                     t_high=t_high, t_end=t_end):
+        def _compute():
             kb = k_ref[0, cols, :].astype(jnp.float32)             # (BK, D)
             vb = v_ref[0, cols, :].astype(jnp.float32)
 
             def body(t, carry, crossed):
                 dk, dv = carry
-                tile = pl.ds(t * block_q, block_q)
+                tile = pl.ds(_from(rows0, t * block_q), block_q)
                 qb = q_ref[0, tile, :].astype(jnp.float32)
                 dob = do_ref[0, tile, :].astype(jnp.float32)
                 lse_b = lse_ref[0, :, tile]                        # (1, BQ)
@@ -691,10 +927,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     kb, qb, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * sm_scale
                 if crossed:
-                    s = _apply_mask(s, causal=causal, masked=masked,
-                                    q0=q_offset + jc * q_chunk + t * block_q,
-                                    k0=k0, kv_valid=kv_valid, q_axis=1,
-                                    window=window)
+                    s = mask(s, t)
                     p = jnp.where(s > NEG_INF * 0.5,
                                   jnp.exp(s - lse_b), 0.0)
                 else:
@@ -722,6 +955,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         _when(t_first < t_end, _compute)
 
+    _sweep_chunk(column_of_tiles, ic, jc, by_key=True, tiles=tiles,
+                 block=block, chunks=(q_chunk, k_chunk), q_offset=q_offset,
+                 n_swept=n_qc, kv_valid=kv_valid, causal=causal,
+                 masked=masked, window=window)
+
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -738,18 +976,16 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
-    tiles = {}
-    for kernel in ("bwd_dq", "bwd_dkv"):
-        tiles[kernel] = (block_q, block_k) if block_q else \
-            _pick_tiles(lq, lk, causal, kernel)
-        _record_tiles(kernel, lq, lk, q_offset, kv_valid, *tiles[kernel],
-                      causal, window)
-    (dq_q, dq_k), (dkv_q, dkv_k) = tiles["bwd_dq"], tiles["bwd_dkv"]
+    (dq_tiles, block), (dkv_tiles, _) = (
+        _schedule(kernel, lq, lk, q_offset, kv_valid, causal, window,
+                  block_q, block_k) for kernel in ("bwd_dq", "bwd_dkv"))
+    (dq_q, dq_k), (dkv_q, dkv_k) = (
+        (block, block) if block else t for t in (dq_tiles, dkv_tiles))
     # Each kernel streams the axis it accumulates over in chunks of up to
     # 4096 and writes the other in chunks of up to _OUTER_CHUNK.
     return _bwd_call(
         q, k, v, o, lse, do, causal=causal, sm_scale=sm_scale,
-        tiles=(tiles["bwd_dq"], tiles["bwd_dkv"]),
+        tiles=(dq_tiles, dkv_tiles), block=block,
         chunks=((_pick_chunk(lq, dq_q, _OUTER_CHUNK),
                  _pick_chunk(lk, dq_k)),
                 (_pick_chunk(lq, dkv_q),
@@ -759,7 +995,7 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
 
 
 @functools.partial(jax.jit, static_argnames=_CALL_STATICS)
-def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
+def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
               q_offset, kv_valid, window, interpret):
     bh, lq, d = q.shape
     lk = k.shape[1]
@@ -770,18 +1006,24 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
     vma = _vma(q, k, v, do)
 
     def kernel(body, tile, chunk):
-        (block_q, block_k), (q_chunk, k_chunk) = tile, chunk
+        q_chunk, k_chunk = chunk
         return functools.partial(
-            body, sm_scale=sm_scale, causal=causal, block_q=block_q,
-            block_k=block_k, q_chunk=q_chunk, k_chunk=k_chunk,
+            body, sm_scale=sm_scale, causal=causal, tiles=tile,
+            block=block, q_chunk=q_chunk, k_chunk=k_chunk,
             n_qc=lq // q_chunk, n_kc=lk // k_chunk, q_offset=q_offset,
             kv_valid=kv_valid, masked=kv_valid < lk, window=window)
+
+    def swept(by_key, chunk, n):
+        return functools.partial(_fetched, by_key=by_key, block=block,
+                                 chunk=chunk, n=n, q_offset=q_offset,
+                                 window=window)
 
     # dQ: grid over query chunks; key chunks stream innermost.
     q_chunk, k_chunk = chunks[0]
     q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0))
     r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i))
-    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, j, 0))
+    keys = swept(False, k_chunk, lk)
+    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, keys(i, j), 0))
     dq = pl.pallas_call(
         kernel(_fa_bwd_dq_kernel, tiles[0], chunks[0]),
         name="hvd_flash_bwd_dq",
@@ -795,8 +1037,9 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, chunks,
     )(q, k, v, do, lse, delta)
     # dK/dV: grid over key chunks; query chunks stream innermost.
     q_chunk, k_chunk = chunks[1]
-    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, j, 0))
-    r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, j))
+    rows = swept(True, q_chunk, lq)
+    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, rows(i, j), 0))
+    r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, rows(i, j)))
     k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, i, 0))
     dk, dv = pl.pallas_call(
         kernel(_fa_bwd_dkv_kernel, tiles[1], chunks[1]),

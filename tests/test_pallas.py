@@ -425,17 +425,237 @@ class TestTileSchedule:
                 q, k, v, causal, 0.125), q, q, q)
             jax.eval_shape(lambda q, k, v, o, lse, do: fa._fa_backward(
                 q, k, v, o, lse, do, causal, 0.125), q, q, q, q, r, q)
+        no_blocks = dict.fromkeys(("blocks_inside", "blocks_diagonal",
+                                   "blocks_edge", "blocks_skipped"), 0)
         trace(True)
         got = gauge()
         for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
             c = got[kernel]
-            assert c == fa.tile_counts(
+            assert c == {**no_blocks, **fa.tile_counts(
                 kernel, 1024, 1024, 0, 1024,
-                *fa._pick_tiles(1024, 1024, True, kernel), True)
+                *fa._pick_tiles(1024, 1024, True, kernel), True)}
             assert c["masked"] < c["visited"] < c["total"]
         trace(False)
         for kernel, c in gauge().items():
-            assert c == {"total": 1, "visited": 1, "masked": 0}, kernel
+            assert c == {"total": 1, "visited": 1, "masked": 0,
+                         **no_blocks}, kernel
+
+
+def _flash_gauge():
+    from horovod_tpu import metrics
+    out = {}
+    for s in metrics.snapshot()["hvd_flash_tiles"]["series"]:
+        out.setdefault(s["labels"]["kernel"], {})[
+            s["labels"]["kind"]] = s["value"]
+    return out
+
+
+class TestBlockSchedule:
+    """Past _OUTER_CHUNK a causal call whose lengths, offset and window are
+    whole blocks runs each block's static schedule by its kind."""
+
+    @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+    @pytest.mark.parametrize("window", [None, 4096, 2048])
+    @pytest.mark.parametrize("length", [2048, 4096, 8192])
+    def test_tiles_cover_the_kept_pairs_once(self, monkeypatch, kernel,
+                                             window, length):
+        """The tiles the schedule visits cover every kept pair exactly
+        once, none of them is wholly masked, and the masked ones are
+        exactly those an edge crosses; block_counts, the per-kind
+        tile_counts and the gauge of a traced call say the same."""
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        block = fa._OUTER_CHUNK
+        assert fa._by_block(length, length, 0, length, True, window)
+        tiles = fa._block_tiles(kernel)
+        visited = list(fa.block_tiles(kernel, length, length, 0, window,
+                                      tiles, block))
+        j = np.arange(length)[None, :]
+        kinds = {"inside": 0, "diagonal": 0, "edge": 0, "skipped": 0}
+        for row in range(length // block):     # one row of blocks at a time
+            i = row * block + np.arange(block)[:, None]
+            ok = j <= i
+            if window is not None:
+                ok &= j > i - window
+            seen = np.zeros(ok.shape, np.int8)
+            for kind, r0, c0, bq, bk, masked in visited:
+                if r0 // block != row:
+                    continue
+                assert (bq, bk) == fa._tile_of(tiles, kind)
+                at = np.s_[r0 - row * block:r0 - row * block + bq,
+                           c0:c0 + bk]
+                assert ok[at].any(), (r0, c0)
+                assert masked == (not ok[at].all()), (r0, c0)
+                seen[at] += 1
+            assert seen.max() == 1
+            assert (seen[ok] == 1).all()
+            for col in range(length // block):
+                blk = ok[:, col * block:(col + 1) * block]
+                kinds["skipped" if not blk.any() else
+                      "inside" if blk.all() else
+                      "diagonal" if col == row else "edge"] += 1
+        counts = fa.block_counts(kernel, length, length, 0, window, tiles,
+                                 block)
+        assert {k: counts["blocks_" + k] for k in kinds} == kinds
+        assert counts["visited"] == len(visited)
+        assert counts["masked"] == sum(t[-1] for t in visited)
+        assert counts["masked"] < counts["visited"] < counts["total"]
+        # a block of a kind is the 1024 square tile_counts knows
+        per_kind = {"diagonal": (0, None), "inside": (block, None),
+                    "edge": (block, block)}
+        for key in ("visited", "masked"):
+            assert counts[key] == sum(
+                kinds[kind] * fa.tile_counts(
+                    kernel, block, block, off, block,
+                    *fa._tile_of(tiles, kind), True, win)[key]
+                for kind, (off, win) in per_kind.items())
+        # the gauge of a traced call
+        q = jax.ShapeDtypeStruct((2, length, 64), jnp.bfloat16)
+        if kernel == "fwd":
+            jax.eval_shape(lambda q, k, v: fa._fa_forward(
+                q, k, v, True, 0.125, window=window), q, q, q)
+        else:
+            r = jax.ShapeDtypeStruct((2, length), jnp.float32)
+            jax.eval_shape(lambda q, k, v, o, lse, do: fa._fa_backward(
+                q, k, v, o, lse, do, True, 0.125, window=window),
+                q, q, q, q, r, q)
+        assert _flash_gauge()[kernel] == counts
+
+    def test_the_cell_reads_the_blocks_the_issue_names(self, monkeypatch):
+        """smallthinker_ep4_8k_1chip, forward, per (batch, head): inside /
+        diagonal / edge / skipped."""
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        for window, want in ((None, (28, 8, 0, 28)), (4096, (18, 8, 4, 34))):
+            c = fa.block_counts("fwd", 8192, 8192, 0, window,
+                                fa._block_tiles("fwd"), 1024)
+            assert tuple(c["blocks_" + k] for k in (
+                "inside", "diagonal", "edge", "skipped")) == want
+
+    @pytest.mark.parametrize("by_key", [False, True])
+    @pytest.mark.parametrize("lq,lk,q_offset,window", [
+        (8192, 8192, 0, None), (8192, 8192, 0, 4096), (8192, 8192, 0, 1024),
+        (2048, 3072, 1024, None), (4096, 2048, -2048, 1024),
+        (8192, 4096, 1024, None), (4096, 8192, -1024, 2048)])
+    def test_a_step_fetches_its_chunk_or_one_it_holds(self, by_key, lq, lk,
+                                                      q_offset, window):
+        """A grid step whose chunk of the swept axis holds a visited block
+        fetches that chunk; any other step fetches a chunk that a step
+        beside it needs (no new copy), never one out of range."""
+        fa = _fa()
+        block, chunk = 1024, 2048
+        n_w, n_s = (lk, lq) if by_key else (lq, lk)
+        per = chunk // block
+        for i in range(n_w // block):
+            needed = set()
+            for b in range(n_s // block):
+                delta = (b + q_offset // block - i) if by_key \
+                    else (i + q_offset // block - b)
+                if any(is_kind for _, is_kind, _ in fa._block_kinds(
+                        delta, block, window)):
+                    needed.add(b // per)
+            for j in range(n_s // chunk):
+                got = int(fa._fetched(i, j, by_key=by_key, block=block,
+                                      chunk=chunk, n=n_s, q_offset=q_offset,
+                                      window=window))
+                if j in needed:
+                    assert got == j
+                else:
+                    assert 0 <= got < n_s // chunk
+                    assert not needed or got in needed
+        assert fa._fetched(3, 1, by_key=by_key, block=None, chunk=chunk,
+                           n=n_s, q_offset=q_offset, window=window) == 1
+
+    # (lq, lk, window, heads, kv heads): 128-token blocks; a window of one
+    # block has no inside kind; 1024 on 2048 keys is a q_offset of 1024,
+    # 896 on 1024 of one block.
+    @pytest.mark.parametrize("lq,lk,window,heads,kv_heads", [
+        (1024, 1024, None, 2, 2), (1024, 1024, 512, 28, 4),
+        (1024, 2048, None, 4, 2), (896, 1024, 256, 2, 1),
+        (1024, 1024, 128, 2, 2), (1024, 2048, 1024, 2, 1)])
+    def test_kernels_match_the_jnp_oracles(self, rng, monkeypatch, lq, lk,
+                                           window, heads, kv_heads):
+        """Forward, dQ and dK/dV on the path by block kind through the
+        interpreter: _OUTER_CHUNK shrunk to 128 so that 1024 tokens are
+        eight blocks, four to a grid step, each cut in several tiles."""
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        monkeypatch.setattr(fa, "_OUTER_CHUNK", 128)
+        monkeypatch.setattr(fa, "_BLOCK_TILE", {
+            "fwd": {"crossed": (32, 64), "inside": (64, 128)},
+            "bwd_dq": {"crossed": (64, 32), "inside": (64, 64)},
+            "bwd_dkv": {"crossed": (32, 32), "inside": (64, 32)}})
+        pick = fa._pick_chunk
+        monkeypatch.setattr(
+            fa, "_pick_chunk",
+            lambda n, block, cap=4096: pick(n, block, min(cap, 512)))
+        assert fa._by_block(lq, lk, lk - lq, lk, True, window)
+        D = 16
+        q, do = (jnp.asarray(rng.standard_normal((heads, lq, D)),
+                             np.float32) for _ in range(2))
+        k, v = (jnp.asarray(rng.standard_normal((kv_heads, lk, D)),
+                            np.float32) for _ in range(2))
+        sm = 1.0 / D ** 0.5
+        o, lse = fa._fa_forward(q, k, v, True, sm, window=window,
+                                heads=heads, kv_heads=kv_heads)
+        got = _flash_gauge()["fwd"]
+        assert got["blocks_diagonal"] == lq // 128
+        assert got["blocks_inside"] + got["blocks_edge"] > 0
+        kw, vw = (fa.gqa_repeat3(t, 1, kv_heads, heads // kv_heads)
+                  for t in (k, v))
+        o_ref, lse_ref = fa._jnp_block_fwd(q, kw, vw, True, sm,
+                                           window=window)
+        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                                   rtol=2e-4, atol=2e-5)
+        got = fa._fa_backward(q, kw, vw, o_ref, lse_ref, do, True, sm,
+                              window=window)
+        want = fa._jnp_block_bwd(q, kw, vw, o_ref, lse_ref, do, True, sm,
+                                 window=window)
+        for a, b, nm in zip(got, want, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5,
+                                       err_msg=f"d{nm}")
+
+    # (lq, lk, causal, window, kv_valid) -> forward tiles, block, chunks
+    @pytest.mark.parametrize("call,want", [
+        ((1024, 1024, True, None, None), ((128, 512), None, (1024, 1024))),
+        ((1024, 1024, True, 300, None), ((128, 512), None, (1024, 1024))),
+        ((256, 1024, True, None, None), ((128, 512), None, (256, 1024))),
+        ((8192, 8192, True, 300, None), ((1024, 1024), None, (1024, 4096))),
+        ((8192, 8192, False, None, None),
+         ((1024, 1024), None, (1024, 4096))),
+        ((2048, 2048, True, None, 2000), ((1024, 1024), None, (1024, 2048))),
+        ((1024, 2048, True, None, None), ((1024, 1024), None, (1024, 2048))),
+        ((1152, 2304, True, None, None), ((128, 256), None, (384, 2304))),
+        ((8192, 8192, True, None, None), ("by block", 1024, (1024, 4096))),
+        ((8192, 8192, True, 4096, None), ("by block", 1024, (1024, 4096))),
+        ((2048, 3072, True, None, None), ("by block", 1024, (1024, 3072)))])
+    def test_the_path_follows_the_call(self, monkeypatch, call, want):
+        """Up to 1024 a side, with a window or an offset that is no whole
+        number of blocks, padded or not causal, a call keeps the tiles,
+        chunks and path it had; a whole number of blocks past 1024 goes by
+        block kind. Read off what reaches the jitted call."""
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        lq, lk, causal, window, kv_valid = call
+        seen = {}
+        monkeypatch.setattr(fa, "_fwd_call",
+                            lambda q, k, v, **kw: seen.update(kw))
+        q, k = (jax.ShapeDtypeStruct((2, n, 64), jnp.bfloat16)
+                for n in (lq, lk))
+        fa._fa_forward(q, k, k, causal, 0.125, window=window,
+                       kv_valid=kv_valid)
+        tiles, block, chunks = want
+        if tiles == "by block":
+            tiles = fa._block_tiles("fwd")
+            assert _flash_gauge()["fwd"]["blocks_diagonal"] == lq // 1024
+        else:
+            assert tiles == fa._pick_tiles(lq, lk, causal, "fwd")
+            assert _flash_gauge()["fwd"]["blocks_diagonal"] == 0
+        assert (seen["tiles"], seen["block"], seen["chunks"]) \
+            == (tiles, block, chunks)
 
 
 # (lq, lk, q_offset, kv_valid, block_q, block_k, chunk cap): tiles forced

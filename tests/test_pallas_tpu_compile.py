@@ -60,6 +60,12 @@ _CALLS = {
                                      "bfloat16", 4096),
     "gqa_28_on_4_8192_causal": (2, 8192, 8192, 28, 4, 128, True, "bfloat16"),
     "window_300_on_1024": (2, 1024, 1024, 8, 8, 64, True, "bfloat16", 300),
+    # past 1024 by block kind: an edge block beside every diagonal block,
+    # and a q_offset of one block (2048 queries end-aligned on 3072 keys)
+    "causal_4096_window_2048": (2, 4096, 4096, 8, 8, 64, True, "bfloat16",
+                                2048),
+    "causal_2048_on_3072_keys": (2, 2048, 3072, 16, 4, 128, True,
+                                 "bfloat16"),
 }
 
 
