@@ -355,6 +355,20 @@ MOE_OVERFLOW_CALLS = REGISTRY.gauge(
     "the path exists, not how often it ran. By the chips the experts are "
     "exchanged over.",
     ("axis_size",))
+SSM_LAYER = REGISTRY.gauge(
+    "hvd_ssm_layer",
+    "Sizes of the last traced Mamba2Mixer call, by kind: heads, head_dim, "
+    "state (the state's size a channel), groups (of B and C), chunk (the "
+    "scan's chunk length) and chunks (chunks a sequence). Set while the "
+    "call is traced.",
+    ("kind",))
+SSM_CHUNK_STATE_BYTES = REGISTRY.gauge(
+    "hvd_ssm_chunk_state_bytes",
+    "Float32 bytes of the chunk states one traced Mamba2Mixer call's scan "
+    "writes: one (head_dim, state) matrix a sequence, chunk and head. By "
+    "the chips the sequence is split over (1: the scan has no exchange). "
+    "Set while the call is traced.",
+    ("axis_size",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -718,6 +732,19 @@ def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
     MOE_BUFFER_ROWS_PER_TOKEN.labels(axis_size).set(buffer_rows / tokens)
     if buffer_rows < per_token * tokens:
         MOE_OVERFLOW_CALLS.labels(axis_size).set(0)
+
+
+def record_ssm_layer(heads, head_dim, state, groups, chunk, chunks,
+                     chunk_state_bytes, axis_size=1):
+    """What one trace of ``parallel.ssm.Mamba2Mixer`` makes: known while
+    the call is traced, so set there once and not per step."""
+    if not _enabled:
+        return
+    for kind, n in (("heads", heads), ("head_dim", head_dim),
+                    ("state", state), ("groups", groups), ("chunk", chunk),
+                    ("chunks", chunks)):
+        SSM_LAYER.labels(kind).set(n)
+    SSM_CHUNK_STATE_BYTES.labels(axis_size).set(chunk_state_bytes)
 
 
 def record_flash_tiles(kernel, counts):

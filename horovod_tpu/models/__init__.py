@@ -11,6 +11,9 @@ from horovod_tpu.models.llama import Llama, LlamaBlock, LlamaConfig  # noqa: F40
 from horovod_tpu.models.smallthinker import (  # noqa: F401
     SmallThinker, SmallThinkerBlock, SmallThinkerConfig,
 )
+from horovod_tpu.models.nemotron_h import (  # noqa: F401
+    NemotronH, NemotronHBlock, NemotronHConfig,
+)
 from horovod_tpu.models.t5 import (  # noqa: F401
     T5, T5Config, t5_beam_decode, t5_generate, t5_greedy_decode,
 )

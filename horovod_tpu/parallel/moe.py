@@ -314,18 +314,30 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _on_rows(rows, k, xt, weights, w_gate_up, w_down, order, inverse, sizes):
+def _gated_relu(h):
+    gate, up = jnp.split(h, 2, axis=-1)
+    return nn.relu(gate) * up
+
+
+# What an expert is, by the name a layer is given as ``expert_form``: the
+# name of its first matrix, that matrix's width in units of the expert's,
+# and what lies between the two products.
+EXPERT_FORMS = {"gated_relu": ("w_gate_up", 2, _gated_relu),
+                "relu2": ("w_up", 1, lambda h: jnp.square(nn.relu(h)))}
+WEIGHTINGS = ("softmax", "sigmoid")
+
+
+def _on_rows(rows, k, form, xt, weights, w_in, w_down, order, inverse, sizes):
     """The experts' weighted outputs summed by token, (T, d), from the
     first ``rows`` of the sorted pairs, which must hold every live one:
-    their rows out of ``xt``, through the grouped products, back into
-    their tokens."""
+    their rows out of ``xt``, through the grouped products of experts of
+    ``form``, back into their tokens."""
     with jax.named_scope("moe.dispatch"):
         where = _rows(rows, k, order, inverse, jnp.sum(sizes))
         buf = _take_rows(xt, where)
     with jax.named_scope("moe.experts"):
-        h = lax.ragged_dot(buf, jnp.asarray(w_gate_up, xt.dtype), sizes)
-        gate, up = jnp.split(h, 2, axis=-1)
-        y = lax.ragged_dot(nn.relu(gate) * up,
+        h = lax.ragged_dot(buf, jnp.asarray(w_in, xt.dtype), sizes)
+        y = lax.ragged_dot(EXPERT_FORMS[form][2](h),
                            jnp.asarray(w_down, xt.dtype), sizes)
     with jax.named_scope("moe.combine"):
         return _combine(y, weights, where)
@@ -341,25 +353,25 @@ def _where_they_fit(fn, rows, order, sizes, *operands):
 # Jitted, with every choice static, so that the layers of a model, which
 # call these with the same shapes, trace and lower each once: the two
 # branches, forward and back, are most of what a layer gives the tracer.
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _forward_where_they_fit(rows, k, *args):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _forward_where_they_fit(rows, k, form, *args):
     order, _, sizes = args[4:]
-    return _where_they_fit(lambda n, *args: _on_rows(n, k, *args), rows,
-                           order, sizes, *args)
+    return _where_they_fit(lambda n, *args: _on_rows(n, k, form, *args),
+                           rows, order, sizes, *args)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _backward_where_they_fit(rows, k, g, *args):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _backward_where_they_fit(rows, k, form, g, *args):
     diff, (order, inverse, sizes) = args[:4], args[4:]
 
     def pull(n, g, *diff):
-        return jax.vjp(lambda *diff: _on_rows(n, k, *diff, order, inverse,
-                                              sizes), *diff)[1](g)
+        return jax.vjp(lambda *diff: _on_rows(n, k, form, *diff, order,
+                                              inverse, sizes), *diff)[1](g)
     return _where_they_fit(pull, rows, order, sizes, g, *diff)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _on_rows_expected(rows, k, xt, weights, w_gate_up, w_down, order,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _on_rows_expected(rows, k, form, xt, weights, w_in, w_down, order,
                       inverse, sizes):
     """:func:`_on_rows` over ``rows`` pairs where the live ones fit, else
     over all of them: one conditional going forward and one coming back,
@@ -369,34 +381,51 @@ def _on_rows_expected(rows, k, xt, weights, w_gate_up, w_down, order,
     more at 2 x 8192 tokens.) So the backward branch computes its forward
     part again from the arguments, which is what the caller's ``remat``
     would have done."""
-    return _forward_where_they_fit(rows, k, xt, weights, w_gate_up, w_down,
+    return _forward_where_they_fit(rows, k, form, xt, weights, w_in, w_down,
                                    order, inverse, sizes)
 
 
-def _on_rows_expected_fwd(rows, k, *args):
-    return _on_rows_expected(rows, k, *args), args
+def _on_rows_expected_fwd(rows, k, form, *args):
+    return _on_rows_expected(rows, k, form, *args), args
 
 
-def _on_rows_expected_bwd(rows, k, args, g):
-    return (*_backward_where_they_fit(rows, k, g, *args), None, None, None)
+def _on_rows_expected_bwd(rows, k, form, args, g):
+    return (*_backward_where_they_fit(rows, k, form, g, *args),
+            None, None, None)
 
 
 _on_rows_expected.defvjp(_on_rows_expected_fwd, _on_rows_expected_bwd)
 
 
 class DroplessMoE(nn.Module):
-    """Sparse feed-forward layer of gated experts, ``top_k`` of
+    """Sparse feed-forward layer of small experts, ``top_k`` of
     ``num_experts`` a token, none dropped, for a layer that holds
     ``experts_held`` contiguous experts from ``first_expert`` on (all of
     them by default).
 
     The router (``num_experts`` wide, float32) reads ``router_input``
-    (``x`` when None); a token's weights are the softmax of its ``top_k``
-    largest logits. The layer returns ``sum over the chosen experts held
-    here of w_e * (relu(x W_gate,e) * (x W_up,e)) W_down,e``: the whole
-    layer when it holds every expert, else this share's partial sum, which
-    the shares of the other holders complete (summed by the caller's
-    exchange; on one chip there is none and nothing stands in for it).
+    (``x`` when None) and gives every expert a score; a token goes to the
+    ``top_k`` experts of the largest score. ``weighting`` says how the
+    chosen are weighed:
+
+    - ``"softmax"``: the score is the logit, the weights the softmax of
+      the chosen logits;
+    - ``"sigmoid"``: the score is the logit's sigmoid, the weights the
+      chosen scores over their sum;
+
+    either way times ``weight_scale``. ``expert_form`` says what an
+    expert is:
+
+    - ``"gated_relu"``: ``(relu(x W_gate) * (x W_up)) W_down``, the first
+      two fused as ``w_gate_up`` (held, d, 2 f);
+    - ``"relu2"``: ``relu(x W_up)^2 W_down``, not gated, ``w_up``
+      (held, d, f).
+
+    The layer returns ``sum over the chosen experts held here of w_e *
+    expert_e(x)``: the whole layer when it holds every expert, else this
+    share's partial sum, which the shares of the other holders complete
+    (summed by the caller's exchange; on one chip there is none and
+    nothing stands in for it).
 
     The (token, choice) pairs are sorted by expert, those routed elsewhere
     behind the last expert held, and the layer moves a buffer of the first
@@ -414,6 +443,9 @@ class DroplessMoE(nn.Module):
     experts_held: Optional[int] = None
     first_expert: int = 0
     dtype: Any = jnp.float32
+    weighting: str = "softmax"
+    weight_scale: float = 1.0
+    expert_form: str = "gated_relu"
 
     @nn.compact
     def __call__(self, x, router_input=None):
@@ -425,6 +457,12 @@ class DroplessMoE(nn.Module):
             raise ValueError(
                 f"top_k {k} of {E} experts, holding {held} from "
                 f"{self.first_expert}: not a share of the experts")
+        if self.weighting not in WEIGHTINGS:
+            raise ValueError(f"unknown weighting {self.weighting!r}; "
+                             f"choose from {WEIGHTINGS}")
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(f"unknown expert_form {self.expert_form!r}; "
+                             f"choose from {tuple(EXPERT_FORMS)}")
         xt = x.reshape(-1, d)
         rt = xt if router_input is None else router_input.reshape(-1, d)
         T = xt.shape[0]
@@ -436,8 +474,14 @@ class DroplessMoE(nn.Module):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               precision=lax.Precision.HIGHEST,
                               name="router")(rt.astype(jnp.float32))
-            top, chosen = lax.top_k(logits, k)                     # (T, k)
-            weights = jax.nn.softmax(top, axis=-1)
+            scores = logits if self.weighting == "softmax" \
+                else jax.nn.sigmoid(logits)
+            top, chosen = lax.top_k(scores, k)                     # (T, k)
+            weights = jax.nn.softmax(top, axis=-1) \
+                if self.weighting == "softmax" \
+                else top / jnp.sum(top, axis=-1, keepdims=True)
+            if self.weight_scale != 1.0:
+                weights = weights * self.weight_scale
 
         with jax.named_scope("moe.dispatch"):
             local = chosen - self.first_expert
@@ -449,13 +493,14 @@ class DroplessMoE(nn.Module):
             sizes = jnp.sum(group[:, None] == jnp.arange(held), 0,
                             dtype=jnp.int32)
 
-        w_gate_up = self.param("w_gate_up", nn.initializers.lecun_normal(),
-                               (held, d, 2 * f), jnp.float32)
+        first, wide, _ = EXPERT_FORMS[self.expert_form]
+        w_in = self.param(first, nn.initializers.lecun_normal(),
+                          (held, d, wide * f), jnp.float32)
         w_down = self.param("w_down", nn.initializers.lecun_normal(),
                             (held, f, d), jnp.float32)
 
-        args = (xt.astype(self.dtype), weights, w_gate_up, w_down, order,
-                inverse, sizes)
+        args = (self.expert_form, xt.astype(self.dtype), weights, w_in,
+                w_down, order, inverse, sizes)
         if C == T * k:
             out = _on_rows(C, k, *args)
         else:

@@ -1,0 +1,238 @@
+"""State-space layers: the Mamba-2 mixer and its scan in chunked form.
+
+The selective state-space recurrence of Mamba-2, per head ``h`` with a
+state ``S`` of (head size ``P``) x (state size ``N``), zero where a
+sequence starts::
+
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T        a_t = exp(dt_t A),  A < 0
+    y_t = S_t C_t + D x_t
+
+``B`` and ``C`` are shared by the heads of a group. Run one token at a time
+this is 8192 dependent steps on a state that no matrix unit sees, so
+:func:`ssm_scan` computes it by chunks of ``chunk`` tokens (the
+state-space-duality form): inside a chunk the outputs are products under
+the mask of decays ``exp(sum of dt A over (j, i])``, every chunk closes
+with the state its own tokens add, the recurrence runs over those closing
+states alone (``L / chunk`` steps), and the state a chunk opens with gives
+the rest of its outputs. The decays and their running sums, ``dt`` and the
+states stay float32; the products take the activations' dtype and
+accumulate in float32.
+
+:class:`Mamba2Mixer` is the layer round it: one input projection to
+``[z | x B C | dt]``, a causal depthwise convolution and SiLU over
+``x B C``, the scan, ``RMSNorm_groups(y * silu(z))`` and the output
+projection. Scopes (``ssm.mixer`` > ``ssm.in_proj``, ``ssm.conv``,
+``ssm.scan``, ``ssm.gate_norm``, ``ssm.out_proj``) are not
+``hvd.``-prefixed: the phase of an op is its innermost ``hvd.`` scope.
+"""
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# A fresh head's ``-A`` is drawn uniformly from here (Mamba-2's range; no
+# published config has a key for it).
+A_RANGE = (1.0, 16.0)
+
+
+def chunk_states_bytes(batch, length, heads, head_dim, state, chunk):
+    """Float32 bytes of the closing states one :func:`ssm_scan` call
+    writes: one (``head_dim``, ``state``) matrix a head and chunk."""
+    return 4 * batch * -(-length // chunk) * heads * head_dim * state
+
+
+def ssm_scan(x, dt, A, B, C, D, chunk):
+    """``y`` (b, L, H, P) of the recurrence above, in chunks of ``chunk``.
+
+    ``x`` (b, L, H, P) and ``B``, ``C`` (b, L, G, N) in the activations'
+    dtype (head ``h`` reads group ``h // (H / G)``); ``dt`` (b, L, H), the
+    positive step, and ``A``, ``D`` (H,) float32. A length that is no
+    whole number of chunks is padded with steps of ``dt`` 0, which neither
+    decay the state nor add to it. Differentiable as it stands.
+    """
+    b, length, H, P = x.shape
+    G, N = B.shape[-2:]
+    if H % G:
+        raise ValueError(f"{H} heads are no whole multiple of {G} groups")
+    R, Q = H // G, min(chunk, length)
+    pad = -length % Q
+    if pad:
+        x, dt, B, C = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, B, C))
+    nc = (length + pad) // Q
+    dtype = x.dtype
+    dt = dt.astype(jnp.float32).reshape(b, nc, Q, H)
+    xc = x.reshape(b, nc, Q, H, P)
+    Bc, Cc = B.reshape(b, nc, Q, G, N), C.reshape(b, nc, Q, G, N)
+
+    # Running sums of the log decays inside each chunk, float32, with the
+    # positions of a chunk last: (b, nc, H, Q).
+    cs = jnp.cumsum(jnp.swapaxes(dt, 2, 3)
+                    * A.astype(jnp.float32)[:, None], axis=-1)
+    total = cs[..., -1]                                     # (b, nc, H)
+    by_position = jnp.swapaxes(cs, 2, 3)                    # (b, nc, Q, H)
+    xdt = xc * dt[..., None].astype(dtype)                  # dt_j x_j
+
+    # Inside a chunk: (C_i . B_j) exp(cs_i - cs_j) for j <= i, times dt_j x_j.
+    cb = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc,
+                    preferred_element_type=jnp.float32)
+    later = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    decay = jnp.exp(jnp.where(later, cs[..., :, None] - cs[..., None, :],
+                              -jnp.inf))                    # (b,nc,H,Q,Q)
+    mask = (cb[:, :, :, None] * decay.reshape(b, nc, G, R, Q, Q)).astype(
+        dtype).reshape(b, nc, H, Q, Q)
+    y = jnp.einsum("bchij,bcjhp->bcihp", mask, xdt,
+                   preferred_element_type=jnp.float32)
+
+    # What each chunk's own tokens leave in the state when it closes.
+    to_end = jnp.exp(total[:, :, None] - by_position)       # (b, nc, Q, H)
+    closing = jnp.einsum(
+        "bcjgn,bcjgrp->cbgrpn", Bc,
+        (xdt * to_end[..., None].astype(dtype)).reshape(b, nc, Q, G, R, P),
+        preferred_element_type=jnp.float32)
+
+    # The recurrence over the closing states: the state each chunk opens
+    # with, float32.
+    def carry_on(state, closed):
+        decay_c, closing_c = closed
+        return decay_c[..., None, None] * state + closing_c, state
+
+    _, opening = lax.scan(
+        carry_on, jnp.zeros((b, G, R, P, N), jnp.float32),
+        (jnp.exp(jnp.moveaxis(total, 1, 0)).reshape(nc, b, G, R), closing))
+
+    # The opening state's part of a chunk's outputs, and the skip.
+    y = y + jnp.einsum("bcign,cbgrpn->bcigrp", Cc, opening.astype(dtype),
+                       preferred_element_type=jnp.float32).reshape(
+                           b, nc, Q, H, P) * jnp.exp(by_position)[..., None]
+    y = y + D.astype(jnp.float32)[:, None] * xc.astype(jnp.float32)
+    return y.reshape(b, nc * Q, H, P)[:, :length].astype(dtype)
+
+
+class CausalConv1d(nn.Module):
+    """Depthwise convolution over the sequence axis of (b, L, C): position
+    ``t`` reads ``t - taps + 1 .. t`` (zeros before a sequence's start),
+    tap ``taps - 1`` its own position; with a bias. A fresh kernel is
+    uniform in +-1 / sqrt(taps)."""
+    taps: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        channels, bound = x.shape[-1], 1.0 / math.sqrt(self.taps)
+        kernel = self.param(
+            "kernel", lambda key, shape: jax.random.uniform(
+                key, shape, jnp.float32, -bound, bound),
+            (self.taps, channels))
+        bias = self.param("bias", nn.initializers.zeros, (channels,))
+        length = x.shape[1]
+        padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
+        out = jnp.asarray(bias, self.dtype)
+        for k in range(self.taps):
+            out = out + padded[:, k:k + length] \
+                * jnp.asarray(kernel[k], self.dtype)
+        return out
+
+
+class GatedGroupRMSNorm(nn.Module):
+    """``RMSNorm(y * silu(z)) * scale`` with the mean square taken over
+    each of ``groups`` equal runs of channels, in float32."""
+    groups: int
+    epsilon: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, z):
+        channels = y.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (channels,))
+        g = (y * nn.silu(z)).astype(jnp.float32)
+        g = g.reshape(g.shape[:-1] + (self.groups, channels // self.groups))
+        g = g * lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                          + self.epsilon)
+        return (g.reshape(y.shape) * scale).astype(self.dtype)
+
+
+def _fresh_dt_bias(step_min, step_max, floor):
+    """Initializer: the inverse softplus of a step drawn log-uniformly in
+    [``step_min``, ``step_max``] and floored at ``floor``."""
+    def init(key, shape):
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, math.log(step_min),
+            math.log(step_max))), floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+    return init
+
+
+def _fresh_a_log(key, shape):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, *A_RANGE))
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer of ``num_heads`` heads of ``head_dim`` with a state
+    of ``state_size`` a channel, ``num_groups`` groups of ``B`` and ``C``,
+    on (b, L, ``hidden_size``). No bias but the convolution's. ``dt``, ``A``
+    and the scan's decays and states are float32 whatever ``dtype``. A
+    fresh head's step ``softplus(dt_bias)`` is drawn log-uniformly in
+    [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``
+    (the published configs' keys, Mamba-2's values by default). Of the scan
+    the backward pass keeps the inputs and the output alone
+    (``jax.checkpoint``), always."""
+    hidden_size: int
+    num_heads: int
+    head_dim: int
+    state_size: int
+    num_groups: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    norm_eps: float = 1e-5
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        H, P, G, N = (self.num_heads, self.head_dim, self.num_groups,
+                      self.state_size)
+        inner, b, length = H * P, u.shape[0], u.shape[1]
+        chunk = min(self.chunk_size, length)
+        from horovod_tpu.metrics import instruments as hvd_metrics
+        hvd_metrics.record_ssm_layer(
+            H, P, N, G, chunk, -(-length // chunk),
+            chunk_states_bytes(b, length, H, P, N, chunk))
+        with jax.named_scope("ssm.mixer"):
+            with jax.named_scope("ssm.in_proj"):
+                zxbcdt = nn.Dense(2 * inner + 2 * G * N + H, use_bias=False,
+                                  dtype=self.dtype, name="in_proj")(u)
+                z, xbc, dt = jnp.split(
+                    zxbcdt, [inner, 2 * inner + 2 * G * N], axis=-1)
+            with jax.named_scope("ssm.conv"):
+                xbc = nn.silu(CausalConv1d(self.conv_kernel, self.dtype,
+                                           name="conv")(xbc))
+                x, B, C = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+            dt_bias = self.param("dt_bias", _fresh_dt_bias(
+                self.time_step_min, self.time_step_max,
+                self.time_step_floor), (H,))
+            a_log = self.param("A_log", _fresh_a_log, (H,))
+            skip = self.param("D", nn.initializers.ones, (H,))
+            # The backward pass computes the scan again from its inputs:
+            # its 128 x 128 masks a head and chunk and its chunk states
+            # are several times the bytes of x, B, C and dt.
+            with jax.named_scope("ssm.scan"):
+                y = jax.checkpoint(ssm_scan, static_argnums=(6,))(
+                    x.reshape(b, length, H, P),
+                    jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                    -jnp.exp(a_log), B.reshape(b, length, G, N),
+                    C.reshape(b, length, G, N), skip, chunk)
+            with jax.named_scope("ssm.gate_norm"):
+                y = GatedGroupRMSNorm(G, self.norm_eps, self.dtype,
+                                      name="gate_norm")(
+                    y.reshape(b, length, inner), z)
+            with jax.named_scope("ssm.out_proj"):
+                return nn.Dense(self.hidden_size, use_bias=False,
+                                dtype=self.dtype, name="out_proj")(y)

@@ -342,3 +342,187 @@ class TestBufferOfTheRowsExpected:
         calls = {s["labels"]["axis_size"]: s["value"] for s in
                  snap["hvd_moe_overflow_calls"]["series"]}
         assert calls == {"1": 0.0}
+
+
+def jaxpr_digest(fn, *args):
+    """sha256 (16 hex digits) of the jaxpr ``fn`` traces on ``args``, with
+    the addresses in the reprs of function objects taken out."""
+    import hashlib
+    import re
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _zeros(tree):
+    return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), tree)
+
+
+class TestTheDefaultLayerIsTheParents:
+    """With its default arguments the layer traces, forward and backward,
+    the jaxpr it traced at commit dbf7cc0 (before ``weighting`` and
+    ``expert_form`` existed), to the letter: the digests were recorded on
+    that commit. A change that means to alter the default layer records new
+    ones and says so."""
+
+    @pytest.mark.parametrize("tokens, kw, recorded", [
+        pytest.param(48, {}, "6d64aef40e13cfca", id="every_expert_held"),
+        pytest.param(1024, {"experts_held": 2, "first_expert": 2},
+                     "a0bfbf7980f800ee", id="a_share_with_its_branch")])
+    def test_jaxpr_digest(self, tokens, kw, recorded):
+        x = jnp.zeros((2, tokens // 2, 32))
+        r = jnp.ones((2, tokens // 2, 32))
+        layer = DroplessMoE(8, 2, 32, 16, **kw)
+        params = _zeros(jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                                       x, r)["params"])
+        assert jaxpr_digest(jax.value_and_grad(
+            lambda p, x, r: layer.apply({"params": p}, x, r).sum(),
+            (0, 1, 2)), params, x, r) == recorded
+
+
+# -- the weightings and the expert forms (PR 33) ------------------------------
+
+SCALE = 2.5
+
+
+def _dense_by(params, x, r, weighting, form, scale=1.0, experts=range(E)):
+    """The layer written densely for any weighting and expert form: every
+    expert on every token under a mask; ``params`` hold all E experts."""
+    xt, rt = x.reshape(-1, D), r.reshape(-1, D)
+    logits = jnp.dot(rt, params["router"]["kernel"], precision="highest")
+    scores = logits if weighting == "softmax" else jax.nn.sigmoid(logits)
+    top, chosen = jax.lax.top_k(scores, K)
+    weights = jax.nn.softmax(top, -1) if weighting == "softmax" \
+        else top / jnp.sum(top, -1, keepdims=True)
+    out = jnp.zeros_like(xt)
+    for e in experts:
+        w_e = scale * jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
+        if form == "gated_relu":
+            gate_up = xt @ params["w_gate_up"][e]
+            hidden = jax.nn.relu(gate_up[:, :F]) * gate_up[:, F:]
+        else:
+            hidden = jnp.square(jax.nn.relu(xt @ params["w_up"][e]))
+        out = out + w_e[:, None] * (hidden @ params["w_down"][e])
+    return out.reshape(x.shape)
+
+
+class TestWeightingsAndExpertForms:
+    @pytest.fixture
+    def inputs(self, rng):
+        x = jnp.asarray(rng.standard_normal((2, T // 2, D)), jnp.float32)
+        r = jnp.asarray(rng.standard_normal((2, T // 2, D)), jnp.float32)
+        return x, r
+
+    @pytest.mark.parametrize("weighting, form, own_router_input", [
+        ("sigmoid", "relu2", True), ("sigmoid", "relu2", False),
+        ("sigmoid", "gated_relu", True), ("softmax", "relu2", True),
+        ("softmax", "gated_relu", False)])
+    def test_against_a_dense_loop_over_the_experts(self, inputs, weighting,
+                                                   form, own_router_input):
+        """Forward and every gradient against the layer written densely,
+        float32: 1e-5 of the largest entry; the router reading an input of
+        its own, or ``x``."""
+        x, r = inputs
+        layer = DroplessMoE(E, K, D, F, weighting=weighting,
+                            weight_scale=SCALE, expert_form=form)
+        params = layer.init(jax.random.PRNGKey(3), x, r)["params"]
+        first = "w_up" if form == "relu2" else "w_gate_up"
+        assert set(params) == {"router", first, "w_down"}
+        assert params[first].shape == (E, D, F if form == "relu2" else 2 * F)
+        w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+
+        def got(p, x, r):
+            return jnp.sum(w * layer.apply(
+                {"params": p}, x, r if own_router_input else None))
+
+        def want(p, x, r):
+            return jnp.sum(w * _dense_by(
+                p, x, r if own_router_input else x, weighting, form, SCALE))
+        dense = _dense_by(params, x, r if own_router_input else x,
+                          weighting, form, SCALE)
+        np.testing.assert_allclose(
+            layer.apply({"params": params}, x,
+                        r if own_router_input else None),
+            dense, atol=1e-5 * float(jnp.abs(dense).max()))
+        wrt = (0, 1, 2) if own_router_input else (0, 1)
+        for a, b in zip(jax.tree.leaves(jax.grad(got, wrt)(params, x, r)),
+                        jax.tree.leaves(jax.grad(want, wrt)(params, x, r))):
+            assert float(jnp.abs(b).max()) > 0
+            np.testing.assert_allclose(a, b, atol=1e-5 * float(
+                jnp.abs(b).max()))
+
+    def test_sigmoid_weights_sum_to_the_scale(self, inputs):
+        """Experts that return their input times one (identity-like) show
+        the weights: with every expert held a token's weights sum to
+        ``weight_scale`` whatever its scores."""
+        x, r = inputs
+        layer = DroplessMoE(E, K, D, D, weighting="sigmoid",
+                            weight_scale=SCALE, expert_form="relu2")
+        params = layer.init(jax.random.PRNGKey(3), x, r)["params"]
+        eye = jnp.broadcast_to(jnp.eye(D), (E, D, D))
+        params = dict(params, w_up=eye, w_down=eye)
+        positive = jnp.abs(x) + 0.1
+        got = layer.apply({"params": params}, positive, r)
+        np.testing.assert_allclose(got, SCALE * jnp.square(positive),
+                                   rtol=1e-5)
+
+    @pytest.mark.parametrize("held", [1, 2, 4])
+    def test_shares_of_sigmoid_relu2_add_up(self, inputs, held):
+        x, r = inputs
+        whole = DroplessMoE(E, K, D, F, weighting="sigmoid",
+                            weight_scale=SCALE, expert_form="relu2")
+        params = whole.init(jax.random.PRNGKey(3), x, r)["params"]
+        want = whole.apply({"params": params}, x, r)
+        total = 0.0
+        for first in range(0, E, held):
+            share = dict(params, w_up=params["w_up"][first:first + held],
+                         w_down=params["w_down"][first:first + held])
+            total = total + DroplessMoE(
+                E, K, D, F, experts_held=held, first_expert=first,
+                weighting="sigmoid", weight_scale=SCALE,
+                expert_form="relu2").apply({"params": share}, x, r)
+        np.testing.assert_allclose(total, want, atol=1e-5 * float(
+            jnp.abs(want).max()))
+
+    def test_a_share_with_its_branch_and_unwritten_rows(self, rng,
+                                                        unwritten_rows):
+        """The buffer of the rows expected, with the rows the grouped
+        product leaves unwritten spoiled as on the chip, for the new form
+        and weighting: output and every gradient against the dense loop."""
+        from horovod_tpu.parallel import moe
+        for f in (moe._forward_where_they_fit, moe._backward_where_they_fit):
+            f.clear_cache()
+        x = jnp.asarray(rng.standard_normal((2, T2 // 2, D)), jnp.float32)
+        r = jnp.asarray(rng.standard_normal((2, T2 // 2, D)), jnp.float32)
+        whole = DroplessMoE(E, K, D, F, weighting="sigmoid",
+                            weight_scale=SCALE, expert_form="relu2")
+        params = whole.init(jax.random.PRNGKey(3), x, r)["params"]
+        layer = DroplessMoE(E, K, D, F, experts_held=HELD,
+                            first_expert=FIRST, weighting="sigmoid",
+                            weight_scale=SCALE, expert_form="relu2")
+        share = dict(params, w_up=params["w_up"][FIRST:FIRST + HELD],
+                     w_down=params["w_down"][FIRST:FIRST + HELD])
+        w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+        got = jax.jit(jax.value_and_grad(lambda p, x, r: jnp.sum(
+            w * layer.apply({"params": p}, x, r)), (0, 1, 2)))(share, x, r)
+        want = jax.value_and_grad(lambda p, x, r: jnp.sum(w * _dense_by(
+            p, x, r, "sigmoid", "relu2", SCALE,
+            range(FIRST, FIRST + HELD))), (0, 1, 2))(params, x, r)
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-4)
+        want_p = dict(want[1][0], w_up=want[1][0]["w_up"][FIRST:FIRST + HELD],
+                      w_down=want[1][0]["w_down"][FIRST:FIRST + HELD])
+        for a, b in zip(jax.tree.leaves((got[1][0], got[1][1:])),
+                        jax.tree.leaves((want_p, want[1][1:]))):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(a, b, atol=5e-5 * float(
+                jnp.abs(b).max()))
+        for f in (moe._forward_where_they_fit, moe._backward_where_they_fit):
+            f.clear_cache()
+
+    @pytest.mark.parametrize("kw, named", [
+        (dict(weighting="tanh"), "unknown weighting 'tanh'.*softmax.*sigmoid"),
+        (dict(expert_form="swiglu"),
+         "unknown expert_form 'swiglu'.*gated_relu.*relu2")])
+    def test_an_unknown_name_raises_by_name(self, inputs, kw, named):
+        x, r = inputs
+        with pytest.raises(ValueError, match=named):
+            DroplessMoE(E, K, D, F, **kw).init(jax.random.PRNGKey(0), x, r)

@@ -199,3 +199,21 @@ class TestModel:
             state, loss = step(state, batch)
             losses.append(float(loss))
         assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.1
+
+
+def test_the_traced_program_is_the_parents():
+    """``SmallThinker.tiny()`` holding a share, at a length at which its
+    expert layers have their branch (1024 tokens), traces forward and
+    backward the jaxpr it traced at commit dbf7cc0, before ``DroplessMoE``
+    took a weighting and an expert form: the digest was recorded on that
+    commit."""
+    from test_moe_dropless import jaxpr_digest
+    model = SmallThinker(SmallThinkerConfig.tiny(experts_held=2,
+                                                 first_expert_held=2))
+    ids = jnp.zeros((2, 512), jnp.int32)
+    params = jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"])
+    assert jaxpr_digest(jax.value_and_grad(
+        lambda p: model.apply({"params": p}, ids).sum()), params) \
+        == "ed1e32c8740b7c33"
