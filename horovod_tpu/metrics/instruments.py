@@ -87,6 +87,14 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   raised from the device: the branch is chosen there, and a host callback
   in it keeps the whole step out of the persistent compile cache (gauge;
   absent where no traced layer has the path)
+- ``hvd_moe_product_path{product}``                 how the last traced
+  DroplessMoE call runs each of its two grouped products (product=in|down):
+  1 = the repo's kernels (``ops/pallas/grouped_matmul.py``), 0 =
+  ``lax.ragged_dot`` as the compiler tiles it, read off the shapes
+  (``parallel.moe.product_tiles``; gauge, set while the call is traced)
+- ``hvd_moe_product_tiles{product,dim}``            the tile of that
+  product in rows, contraction and output columns (dim=m|k|n; gauge, as
+  above)
 - ``autopilot_decisions_total{lever,outcome}``      autopilot control
   decisions (lever=tuner|overlap|cross_wire|remediate; counter)
 - ``autopilot_remediations_total{cause,outcome}``   autopilot-initiated
@@ -355,6 +363,23 @@ MOE_OVERFLOW_CALLS = REGISTRY.gauge(
     "the path exists, not how often it ran. By the chips the experts are "
     "exchanged over.",
     ("axis_size",))
+MOE_PRODUCT_PATH = REGISTRY.gauge(
+    "hvd_moe_product_path",
+    "How the last traced DroplessMoE call runs each of its two grouped "
+    "products (in: the rows times the experts' first matrix; down: times "
+    "their second): 1 = the repo's Pallas kernels in tiles picked from the "
+    "shapes, 0 = lax.ragged_dot in the tile the compiler gives its widths, "
+    "kept only where no slab of the kernels' fits VMEM. Set while the call "
+    "is traced.",
+    ("product",))
+MOE_PRODUCT_TILES = REGISTRY.gauge(
+    "hvd_moe_product_tiles",
+    "The tile of each grouped product of the last traced DroplessMoE "
+    "call, by dim: m (rows a step), k (of the contraction: the block of "
+    "the left operand's gradient on path 1) and n (output columns). A "
+    "value of 128 in k or n on path 0 is the compiler's slow tile. Set "
+    "while the call is traced.",
+    ("product", "dim"))
 SSM_LAYER = REGISTRY.gauge(
     "hvd_ssm_layer",
     "Sizes of the last traced Mamba2Mixer call, by kind: heads, head_dim, "
@@ -719,11 +744,12 @@ def record_fused_allreduce(axis_size, buckets, nbytes):
 
 
 def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
-                     axis_size=1):
+                     axis_size=1, products=None):
     """What one trace of ``parallel.moe.DroplessMoE`` makes: known while
     the call is traced, so set there once and not per step. A buffer of
     fewer rows than the call's pairs has an overflow path, whose series
-    appears here."""
+    appears here. ``products``: ``{name: (path, (tm, tk, tn))}`` of the
+    call's grouped products (``parallel.moe.product_tiles``)."""
     if not _enabled:
         return
     for kind, n in (("routed", routed), ("held", held),
@@ -732,6 +758,10 @@ def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
     MOE_BUFFER_ROWS_PER_TOKEN.labels(axis_size).set(buffer_rows / tokens)
     if buffer_rows < per_token * tokens:
         MOE_OVERFLOW_CALLS.labels(axis_size).set(0)
+    for product, (path, tiles) in (products or {}).items():
+        MOE_PRODUCT_PATH.labels(product).set(path)
+        for dim, tile in zip("mkn", tiles):
+            MOE_PRODUCT_TILES.labels(product, dim).set(tile)
 
 
 def record_ssm_layer(heads, head_dim, state, groups, chunk, chunks,

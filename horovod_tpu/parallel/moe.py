@@ -27,7 +27,7 @@ with no capacity and no dropped token, gated experts, a router that may
 read another tensor than the experts do, and a layer that is TOLD which
 contiguous run of the experts it holds and computes their part of the sum.
 Dispatch is a sort of the (token, choice) pairs by expert and the experts'
-products are grouped over the experts held (``lax.ragged_dot``), so no
+products are grouped over the experts held (:func:`_grouped_dot`), so no
 ``(T, E, C)`` tensor exists.
 """
 
@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.ops.pallas import grouped_matmul as gmm
 from horovod_tpu.parallel.tp import axis_size_or_1, shard_init
 
 EP_AXIS = "ep"
@@ -327,6 +328,34 @@ EXPERT_FORMS = {"gated_relu": ("w_gate_up", 2, _gated_relu),
 WEIGHTINGS = ("softmax", "sigmoid")
 
 
+def product_tiles(m, k, n, itemsize=2):
+    """``(path, (tm, tk, tn))`` of the grouped product ``(m, k) @ (groups,
+    k, n)`` and of its two transposes, read off the shapes. Path 1: the
+    repo's kernels (``ops/pallas/grouped_matmul.py``) in the tiles they
+    pick from the shapes. Path 0, ``lax.ragged_dot`` as the TPU compiler
+    tiles it (512 rows by, in k and in n, the largest of 512, 256 and 128
+    that DIVIDES the width: a seventh of the kernels' speed where that is
+    128; PERF.md, PR 34), only where no slab of the kernels' fits VMEM: a
+    contraction of some 25,000 in bfloat16."""
+    tiles = gmm.pick_tiles(m, k, n, itemsize)
+    if tiles is not None:
+        return 1, tiles
+    return 0, (ROW_TILE, *(next((t for t in (512, 256) if w % t == 0), 128)
+                           for w in (k, n)))
+
+
+def _grouped_dot(lhs, rhs, sizes):
+    """(m, n): row r of ``lhs`` (m, k) times ``rhs[g]`` (groups, k, n) for
+    the group g that ``sizes`` puts r in, in ``lhs``'s dtype with float32
+    sums; rows behind the last group may hold anything, here and in
+    ``lhs``'s gradient. One rule picks how: :func:`product_tiles`."""
+    path, tiles = product_tiles(lhs.shape[0], *rhs.shape[1:],
+                                lhs.dtype.itemsize)
+    if path == 0:
+        return lax.ragged_dot(lhs, rhs, sizes)
+    return gmm.grouped_matmul(lhs, rhs, sizes, tiles)
+
+
 def _on_rows(rows, k, form, xt, weights, w_in, w_down, order, inverse, sizes):
     """The experts' weighted outputs summed by token, (T, d), from the
     first ``rows`` of the sorted pairs, which must hold every live one:
@@ -336,9 +365,9 @@ def _on_rows(rows, k, form, xt, weights, w_in, w_down, order, inverse, sizes):
         where = _rows(rows, k, order, inverse, jnp.sum(sizes))
         buf = _take_rows(xt, where)
     with jax.named_scope("moe.experts"):
-        h = lax.ragged_dot(buf, jnp.asarray(w_in, xt.dtype), sizes)
-        y = lax.ragged_dot(EXPERT_FORMS[form][2](h),
-                           jnp.asarray(w_down, xt.dtype), sizes)
+        h = _grouped_dot(buf, jnp.asarray(w_in, xt.dtype), sizes)
+        y = _grouped_dot(EXPERT_FORMS[form][2](h),
+                         jnp.asarray(w_down, xt.dtype), sizes)
     with jax.named_scope("moe.combine"):
         return _combine(y, weights, where)
 
@@ -468,7 +497,12 @@ class DroplessMoE(nn.Module):
         T = xt.shape[0]
         C = buffer_rows(T, k, held, E)
         from horovod_tpu.metrics import instruments as hvd_metrics
-        hvd_metrics.record_moe_layer(E, held, k, C, T)
+        itemsize = jnp.dtype(self.dtype).itemsize
+        wide = EXPERT_FORMS[self.expert_form][1] * f
+        hvd_metrics.record_moe_layer(
+            E, held, k, C, T,
+            products={"in": product_tiles(C, d, wide, itemsize),
+                      "down": product_tiles(C, f, d, itemsize)})
 
         with jax.named_scope("moe.route"):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
@@ -493,9 +527,9 @@ class DroplessMoE(nn.Module):
             sizes = jnp.sum(group[:, None] == jnp.arange(held), 0,
                             dtype=jnp.int32)
 
-        first, wide, _ = EXPERT_FORMS[self.expert_form]
-        w_in = self.param(first, nn.initializers.lecun_normal(),
-                          (held, d, wide * f), jnp.float32)
+        w_in = self.param(EXPERT_FORMS[self.expert_form][0],
+                          nn.initializers.lecun_normal(),
+                          (held, d, wide), jnp.float32)
         w_down = self.param("w_down", nn.initializers.lecun_normal(),
                             (held, f, d), jnp.float32)
 

@@ -195,8 +195,10 @@ def _count_runs(monkeypatch):
 def unwritten_rows(monkeypatch):
     """The TPU's grouped product leaves the rows behind the last group
     unwritten, in the product and in the gradient it hands its left
-    operand; the CPU's writes zeros. Here both hold NaN."""
-    ragged_dot = jax.lax.ragged_dot
+    operand. Here both hold NaN, whatever the product's path leaves there
+    on the CPU (the Pallas interpreter NaN, ``lax.ragged_dot`` zeros)."""
+    from horovod_tpu.parallel import moe
+    grouped_dot = moe._grouped_dot
 
     @jax.custom_vjp
     def spoil(x, n):
@@ -210,8 +212,8 @@ def unwritten_rows(monkeypatch):
         # visited); backward its gradient's dead rows are spoiled
         lhs = jnp.where(jnp.arange(lhs.shape[0])[:, None] < n,
                         spoil(lhs, n), lhs)
-        return spoil(ragged_dot(lhs, rhs, sizes), n)
-    monkeypatch.setattr(jax.lax, "ragged_dot", spoiled)
+        return spoil(grouped_dot(lhs, rhs, sizes), n)
+    monkeypatch.setattr(moe, "_grouped_dot", spoiled)
 
 
 @pytest.fixture
@@ -320,15 +322,15 @@ class TestBufferOfTheRowsExpected:
 
     def test_every_expert_held_has_no_branch(self, setup):
         params, x, r = setup
-        text = str(jax.make_jaxpr(lambda p, x, r: DroplessMoE(
-            E, K, D, F).apply({"params": p}, x, r))(params, x, r))
-        assert "cond" not in text and "custom_vjp_call" in text
+        counts = _primitives(jax.make_jaxpr(lambda p, x, r: DroplessMoE(
+            E, K, D, F).apply({"params": p}, x, r))(params, x, r).jaxpr)
+        assert "cond" not in counts and counts["custom_vjp_call"] > 0
 
     def test_a_share_has_one_branch_each_way(self, share):
         layer, params, x, r = share
-        text = str(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(layer.apply(
-            {"params": p}, x, r))))(params))
-        assert text.count("cond[") == 2         # forward, backward
+        counts = _primitives(jax.make_jaxpr(jax.grad(lambda p: jnp.sum(
+            layer.apply({"params": p}, x, r))))(params).jaxpr)
+        assert counts["cond"] == 2              # forward, backward
 
     def test_gauges_of_a_share_with_a_buffer(self, share):
         from horovod_tpu import metrics
@@ -342,6 +344,20 @@ class TestBufferOfTheRowsExpected:
         calls = {s["labels"]["axis_size"]: s["value"] for s in
                  snap["hvd_moe_overflow_calls"]["series"]}
         assert calls == {"1": 0.0}
+
+
+def _primitives(jaxpr, counts=None):
+    """{primitive: equations of it} of ``jaxpr`` and every jaxpr its
+    equations carry, a kernel's own body apart (a ``pl.when`` is a
+    ``cond`` of the kernel's, not of the layer's)."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        counts[name] = counts.get(name, 0) + 1
+        if name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                _primitives(sub, counts)
+    return counts
 
 
 def jaxpr_digest(fn, *args):
@@ -359,24 +375,66 @@ def _zeros(tree):
 
 class TestTheDefaultLayerIsTheParents:
     """With its default arguments the layer traces, forward and backward,
-    the jaxpr it traced at commit dbf7cc0 (before ``weighting`` and
-    ``expert_form`` existed), to the letter: the digests were recorded on
-    that commit. A change that means to alter the default layer records new
-    ones and says so."""
+    the jaxpr recorded here, to the letter. Until PR 34 the digests were
+    those of commit dbf7cc0 (before ``weighting`` and ``expert_form``
+    existed: 6d64aef40e13cfca, a0bfbf7980f800ee). **PR 34 meant to alter
+    the default layer and recorded new ones**: the two grouped products are
+    ``ops/pallas/grouped_matmul.py``'s kernels and no longer
+    ``lax.ragged_dot``, at every width (the kernels were ahead on the chip
+    at the SmallThinker cell's widths too, so there is one path: PERF.md,
+    section 6, PR 34), and nothing else in the layer moved: with
+    ``_grouped_dot`` made ``lax.ragged_dot`` again the three cases trace
+    the parent's digests (``test_all_but_the_product_is_the_parents``). A
+    change that means to alter the default layer, or the kernels' bodies,
+    records new ones and says so."""
 
-    @pytest.mark.parametrize("tokens, kw, recorded", [
-        pytest.param(48, {}, "6d64aef40e13cfca", id="every_expert_held"),
-        pytest.param(1024, {"experts_held": 2, "first_expert": 2},
-                     "a0bfbf7980f800ee", id="a_share_with_its_branch")])
-    def test_jaxpr_digest(self, tokens, kw, recorded):
-        x = jnp.zeros((2, tokens // 2, 32))
-        r = jnp.ones((2, tokens // 2, 32))
-        layer = DroplessMoE(8, 2, 32, 16, **kw)
-        params = _zeros(jax.eval_shape(layer.init, jax.random.PRNGKey(0),
-                                       x, r)["params"])
-        assert jaxpr_digest(jax.value_and_grad(
-            lambda p, x, r: layer.apply({"params": p}, x, r).sum(),
-            (0, 1, 2)), params, x, r) == recorded
+    # tokens, layer arguments -> digest with the kernels, digest at the
+    # parent commit 71d82cb (which PR 34's tree traces with lax.ragged_dot)
+    CASES = {
+        "every_expert_held": (48, 32, 16, {}, "float32",
+                              "4acade00a616ae47", "6d64aef40e13cfca"),
+        "a_share_with_its_branch": (
+            1024, 32, 16, {"num_experts": 8, "top_k": 2, "experts_held": 2,
+                           "first_expert": 2}, "float32",
+            "e6b6a08a8e82c7de", "a0bfbf7980f800ee"),
+        # the SmallThinker cell's layer at its real shapes, traced from
+        # shapes alone: 2 x 8192 tokens, 6 of 64 experts a token, 16 held
+        "smallthinker_ep4_8k_real_shapes": (
+            16384, 2560, 768, {"num_experts": 64, "top_k": 6,
+                               "experts_held": 16, "first_expert": 16},
+            "bfloat16", "40fc68b72e3a91c4", "1521117c1275fea7"),
+    }
+
+    @staticmethod
+    def _digest(tokens, d, f, kw, dtype):
+        kw = dict({"num_experts": 8, "top_k": 2}, **kw)
+        x = jax.ShapeDtypeStruct((2, tokens // 2, d), jnp.dtype(dtype))
+        layer = DroplessMoE(hidden_size=d, intermediate_size=f,
+                            dtype=jnp.dtype(dtype), **kw)
+        params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x,
+                                x)["params"]
+        return jaxpr_digest(jax.value_and_grad(
+            lambda p, x, r: layer.apply({"params": p}, x, r).astype(
+                jnp.float32).sum(), (0, 1, 2)), params, x, x)
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_jaxpr_digest(self, case):
+        *args, recorded, _ = self.CASES[case]
+        assert self._digest(*args) == recorded
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_all_but_the_product_is_the_parents(self, case, monkeypatch):
+        from horovod_tpu.parallel import moe
+        monkeypatch.setattr(moe, "_grouped_dot", jax.lax.ragged_dot)
+        for f in (moe._forward_where_they_fit, moe._backward_where_they_fit):
+            f.clear_cache()
+        *args, _, parents = self.CASES[case]
+        try:
+            assert self._digest(*args) == parents
+        finally:
+            for f in (moe._forward_where_they_fit,
+                      moe._backward_where_they_fit):
+                f.clear_cache()
 
 
 # -- the weightings and the expert forms (PR 33) ------------------------------

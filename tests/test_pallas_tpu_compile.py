@@ -86,3 +86,55 @@ def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
         shape(lq, h), shape(lk, kv), shape(lk, kv)).compile().as_text()
     for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
         assert kernel in hlo, f"{kernel} is not in the compiled program"
+
+
+# -- DroplessMoE's grouped products (PR 34) -----------------------------------
+
+# (tokens, experts, a token, held, hidden, expert width, form): the expert
+# layer of each sparse cell of BENCHMARK.json, at its real shapes
+_EXPERT_LAYERS = {
+    "smallthinker_ep4_8k": (16384, 64, 6, 16, 2560, 768, "gated_relu"),
+    "nemotron_tt_ep16_8k": (16384, 128, 6, 8, 2688, 1856, "relu2"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_EXPERT_LAYERS))
+def test_no_grouped_product_of_a_cell_runs_a_128_wide_tile(one_chip,
+                                                           monkeypatch, cell):
+    """``jax.grad`` of ``parallel.moe._on_rows`` compiles for a v5e at the
+    cell's shapes, and each of its grouped products (two forward, four
+    transposes) runs in tiles of at least 256 in the contraction and in the
+    output's width. The repo's kernels say their tile in their names
+    (``hvd_gmm_<tm>x<contraction>x<tn>``, ``hvd_tgmm_<tm>x<tk>x<tn>``), the
+    compiler's own product in ``ragged_dot_tiling="tm,tk,tn"``: it falls to
+    128 where 256 does not divide a width (2688, 1856), at a sixth of the
+    speed (PERF.md, PR 34). The guard that the next model's widths cannot
+    bring that tile back in silence."""
+    import re
+    from horovod_tpu.ops.pallas import grouped_matmul as gmm
+    from horovod_tpu.parallel import moe
+    monkeypatch.setattr(gmm, "_interpret", lambda: False)
+    tokens, routed, k, held, d, f, form = _EXPERT_LAYERS[cell]
+    rows = moe.buffer_rows(tokens, k, held, routed)
+    wide = moe.EXPERT_FORMS[form][1] * f
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(xt, weights, w_in, w_down, order, inverse, sizes):
+        return moe._on_rows(rows, k, form, xt, weights, w_in, w_down, order,
+                            inverse, sizes).astype(jnp.float32).sum()
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape((tokens, d), jnp.bfloat16), shape((tokens, k), jnp.float32),
+        shape((held, d, wide), jnp.float32), shape((held, f, d), jnp.float32),
+        shape((tokens * k,), jnp.int32), shape((tokens * k,), jnp.int32),
+        shape((held,), jnp.int32)).compile().as_text()
+    theirs = {tuple(map(int, t.split(","))) for t in
+              re.findall(r'ragged_dot_tiling="([\d,]+)"', hlo)}
+    ours = {(name, *map(int, dims)) for name, *dims in re.findall(
+        r"(hvd_t?gmm(?:_t)?)_(\d+)x(\d+)x(\d+)", hlo)}
+    # two products, each forward, for its left and for its right operand
+    assert not theirs and len(ours) == 6, (theirs, ours)
+    assert {name for name, *_ in ours} == {"hvd_gmm", "hvd_gmm_t", "hvd_tgmm"}
+    for *_, tk, tn in ours:
+        assert min(tk, tn) >= 256, ours

@@ -201,19 +201,34 @@ class TestModel:
         assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.1
 
 
-def test_the_traced_program_is_the_parents():
+@pytest.mark.parametrize("product, recorded", [
+    ("the_kernels", "3c3334890888e037"), ("ragged_dot", "ed1e32c8740b7c33")])
+def test_the_traced_program_is_the_parents(product, recorded, monkeypatch):
     """``SmallThinker.tiny()`` holding a share, at a length at which its
     expert layers have their branch (1024 tokens), traces forward and
-    backward the jaxpr it traced at commit dbf7cc0, before ``DroplessMoE``
-    took a weighting and an expert form: the digest was recorded on that
-    commit."""
+    backward the jaxpr recorded here. With ``lax.ragged_dot`` for the
+    experts' grouped products it is the jaxpr of commit dbf7cc0 (before
+    ``DroplessMoE`` took a weighting and an expert form) still; PR 34 gave
+    the products to ``ops/pallas/grouped_matmul.py``'s kernels, meant to,
+    and recorded the digest with them."""
+    from horovod_tpu.parallel import moe
     from test_moe_dropless import jaxpr_digest
+    jitted = (moe._forward_where_they_fit, moe._backward_where_they_fit)
+    if product == "ragged_dot":
+        monkeypatch.setattr(moe, "_grouped_dot", jax.lax.ragged_dot)
+        for f in jitted:
+            f.clear_cache()
     model = SmallThinker(SmallThinkerConfig.tiny(experts_held=2,
                                                  first_expert_held=2))
     ids = jnp.zeros((2, 512), jnp.int32)
     params = jax.tree.map(
         lambda s: jnp.zeros(s.shape, s.dtype),
         jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)["params"])
-    assert jaxpr_digest(jax.value_and_grad(
-        lambda p: model.apply({"params": p}, ids).sum()), params) \
-        == "ed1e32c8740b7c33"
+    try:
+        assert jaxpr_digest(jax.value_and_grad(
+            lambda p: model.apply({"params": p}, ids).sum()), params) \
+            == recorded
+    finally:
+        if product == "ragged_dot":
+            for f in jitted:
+                f.clear_cache()
