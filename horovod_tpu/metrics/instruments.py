@@ -380,6 +380,13 @@ MOE_PRODUCT_TILES = REGISTRY.gauge(
     "value of 128 in k or n on path 0 is the compiler's slow tile. Set "
     "while the call is traced.",
     ("product", "dim"))
+ATTN_LAYER = REGISTRY.gauge(
+    "hvd_attn_layer",
+    "Sizes of the last traced TPSelfAttention call that norms its query "
+    "and key heads or gates its output, by kind: heads, kv_heads, head_dim, "
+    "window (0: every earlier key), normed and gated (1 or 0). Set while "
+    "the call is traced; a layer with neither records nothing.",
+    ("kind",))
 SSM_LAYER = REGISTRY.gauge(
     "hvd_ssm_layer",
     "Sizes of the last traced Mamba2Mixer call, by kind: heads, head_dim, "
@@ -762,6 +769,18 @@ def record_moe_layer(routed, held, per_token, buffer_rows, tokens,
         MOE_PRODUCT_PATH.labels(product).set(path)
         for dim, tile in zip("mkn", tiles):
             MOE_PRODUCT_TILES.labels(product, dim).set(tile)
+
+
+def record_attn_layer(heads, kv_heads, head_dim, window, normed, gated):
+    """What one trace of a ``parallel.tp.TPSelfAttention`` with a norm on
+    its heads or a gate makes: known while the call is traced, so set there
+    once and not per step."""
+    if not _enabled:
+        return
+    for kind, n in (("heads", heads), ("kv_heads", kv_heads),
+                    ("head_dim", head_dim), ("window", window),
+                    ("normed", int(normed)), ("gated", int(gated))):
+        ATTN_LAYER.labels(kind).set(n)
 
 
 def record_ssm_layer(heads, head_dim, state, groups, chunk, chunks,

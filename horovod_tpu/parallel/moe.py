@@ -315,16 +315,19 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _gated_relu(h):
-    gate, up = jnp.split(h, 2, axis=-1)
-    return nn.relu(gate) * up
+def _gated(act):
+    def form(h):
+        gate, up = jnp.split(h, 2, axis=-1)
+        return act(gate) * up
+    return form
 
 
 # What an expert is, by the name a layer is given as ``expert_form``: the
 # name of its first matrix, that matrix's width in units of the expert's,
 # and what lies between the two products.
-EXPERT_FORMS = {"gated_relu": ("w_gate_up", 2, _gated_relu),
-                "relu2": ("w_up", 1, lambda h: jnp.square(nn.relu(h)))}
+EXPERT_FORMS = {"gated_relu": ("w_gate_up", 2, _gated(nn.relu)),
+                "relu2": ("w_up", 1, lambda h: jnp.square(nn.relu(h))),
+                "gated_silu": ("w_gate_up", 2, _gated(nn.silu))}
 WEIGHTINGS = ("softmax", "sigmoid")
 
 
@@ -448,7 +451,16 @@ class DroplessMoE(nn.Module):
     - ``"gated_relu"``: ``(relu(x W_gate) * (x W_up)) W_down``, the first
       two fused as ``w_gate_up`` (held, d, 2 f);
     - ``"relu2"``: ``relu(x W_up)^2 W_down``, not gated, ``w_up``
-      (held, d, f).
+      (held, d, f);
+    - ``"gated_silu"``: ``(silu(x W_gate) * (x W_up)) W_down``, fused as
+      ``w_gate_up`` like the first.
+
+    ``selection_bias`` (a call argument, (num_experts,) float32, None for
+    none) is added to the scores for the CHOICE of the ``top_k`` alone,
+    under ``stop_gradient``: the weights come from the chosen experts'
+    unbiased scores, and the bias gets no gradient. It is an input, not a
+    parameter: who moves it against the experts' loads (a training
+    recipe's rule) owns it.
 
     The layer returns ``sum over the chosen experts held here of w_e *
     expert_e(x)``: the whole layer when it holds every expert, else this
@@ -477,7 +489,7 @@ class DroplessMoE(nn.Module):
     expert_form: str = "gated_relu"
 
     @nn.compact
-    def __call__(self, x, router_input=None):
+    def __call__(self, x, router_input=None, selection_bias=None):
         E, k = self.num_experts, self.top_k
         d, f = self.hidden_size, self.intermediate_size
         held = E if self.experts_held is None else self.experts_held
@@ -510,7 +522,12 @@ class DroplessMoE(nn.Module):
                               name="router")(rt.astype(jnp.float32))
             scores = logits if self.weighting == "softmax" \
                 else jax.nn.sigmoid(logits)
-            top, chosen = lax.top_k(scores, k)                     # (T, k)
+            if selection_bias is None:
+                top, chosen = lax.top_k(scores, k)                 # (T, k)
+            else:
+                _, chosen = lax.top_k(scores + lax.stop_gradient(
+                    jnp.asarray(selection_bias, jnp.float32)), k)
+                top = jnp.take_along_axis(scores, chosen, axis=-1)
             weights = jax.nn.softmax(top, axis=-1) \
                 if self.weighting == "softmax" \
                 else top / jnp.sum(top, axis=-1, keepdims=True)

@@ -208,7 +208,16 @@ class TPSelfAttention(nn.Module):
     it is not ``hidden_size / num_heads`` (28 heads of 128 at hidden 2560).
     ``window`` (causal, full-sequence, no sp): each query attends its last
     ``window`` keys, itself included; the flash kernels skip the tiles
-    behind it.
+    behind it. ``qk_norm_eps`` (None: no norm) puts an RMS norm over the
+    ``head_dim`` of every query head and every key head, before the
+    rotation, with one learned scale for all query heads and one for all
+    key heads (``q_norm/scale``, ``k_norm/scale``; float32 statistics). ``gated``
+    adds a fourth column-parallel projection of the same input, ``gate``,
+    as wide as the query heads and sharded with them; the heads' output is
+    multiplied by its sigmoid before the output projection. Both act on the
+    full-sequence path, plain and flash alike, under the scopes
+    ``attn.qk_norm`` and ``attn.gate``; with ``decode=True`` or an
+    ``sp_axis`` they raise.
     """
     num_heads: int
     hidden_size: int
@@ -226,6 +235,8 @@ class TPSelfAttention(nn.Module):
     use_bias: bool = True
     head_dim: Optional[int] = None       # None -> hidden_size // num_heads
     window: Optional[int] = None         # None -> every earlier key
+    qk_norm_eps: Optional[float] = None  # None -> q and k heads not normed
+    gated: bool = False                  # sigmoid(gate(x)) * heads' output
 
     def _decode_attend(self, q, k, v, bias=None, pos=None):
         """Cached decode against the KV cache: ``s`` query tokens per call
@@ -416,6 +427,16 @@ class TPSelfAttention(nn.Module):
         local_heads = self.num_heads // n
         local_kv = kv_heads // n
         head_dim = self.head_dim or self.hidden_size // self.num_heads
+        normed = self.qk_norm_eps is not None
+        if normed or self.gated:
+            if self.decode or self.sp_axis is not None:
+                raise ValueError(
+                    "qk_norm_eps and gated act on the full-sequence path "
+                    "only (neither decode=True nor an sp_axis)")
+            from horovod_tpu.metrics import instruments as hvd_metrics
+            hvd_metrics.record_attn_layer(
+                self.num_heads, kv_heads, head_dim, self.window or 0,
+                normed, self.gated)
 
         # Column-parallel fused QKV: shard s's local output is
         # [q_s | k_s | v_s] for its heads [s*local_heads, (s+1)*local_heads)
@@ -433,6 +454,12 @@ class TPSelfAttention(nn.Module):
             return t.reshape(t.shape[:-1] + (-1, head_dim))
 
         q, k, v = heads(q), heads(k), heads(v)
+        if normed:
+            with jax.named_scope("attn.qk_norm"):
+                q = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
+                               name="q_norm")(q)
+                k = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
+                               name="k_norm")(k)
         if self.decode:
             if self.sp_axis is not None or mask is not None \
                     or self.window is not None:
@@ -469,6 +496,13 @@ class TPSelfAttention(nn.Module):
             # grouped q heads against the narrow cache.)
             out = self._attend(q, k, v, mask, bias=bias)
         out = out.reshape(out.shape[:-2] + (local_heads * head_dim,))
+        if self.gated:
+            with jax.named_scope("attn.gate"):
+                gate = ColumnParallelDense(
+                    self.num_heads * head_dim, dtype=self.dtype,
+                    use_bias=self.use_bias, axis_name=self.axis_name,
+                    name="gate")(x)
+                out = out * nn.sigmoid(gate)
         return RowParallelDense(self.hidden_size, dtype=self.dtype,
                                 use_bias=self.use_bias,
                                 axis_name=self.axis_name, name="out")(out)

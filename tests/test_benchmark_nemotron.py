@@ -92,7 +92,9 @@ class TestManifestEntries:
         by_name = {e["name"]: e for e in m["per_layer"]}
         for name, (layer, unit) in NEW_METRICS.items():
             entry = by_name[name]
-            assert entry["workloads"] == [CELL], name
+            # the cell that brought the metric is its first; later cells
+            # append themselves (``moe.shared_ms``)
+            assert entry["workloads"][0] == CELL, name
             assert (entry["layer"], entry["unit"], entry["moves"]) \
                 == (layer, unit, "tokens_per_s_per_chip"), name
             spec = _json("benchmark", "metrics", f"{name}.json")
@@ -102,7 +104,7 @@ class TestManifestEntries:
                      "moe.experts_roofline", "moe.buffer_rows_per_token",
                      "moe.overflow_calls", "attn.full_ms", "init.compile_s",
                      "device.idle_pct", "step.forward_ms"):
-            assert by_name[name]["workloads"][-1] == CELL, name
+            assert CELL in by_name[name]["workloads"], name
         for name in ("attn.window_ms", "allreduce.exposed_ms",
                      "allreduce.reduce_ms"):
             assert CELL not in by_name[name]["workloads"], name

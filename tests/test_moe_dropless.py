@@ -442,21 +442,25 @@ class TestTheDefaultLayerIsTheParents:
 SCALE = 2.5
 
 
-def _dense_by(params, x, r, weighting, form, scale=1.0, experts=range(E)):
+def _dense_by(params, x, r, weighting, form, scale=1.0, experts=range(E),
+              bias=None):
     """The layer written densely for any weighting and expert form: every
-    expert on every token under a mask; ``params`` hold all E experts."""
+    expert on every token under a mask; ``params`` hold all E experts.
+    ``bias`` (E,) moves the choice and nothing else."""
     xt, rt = x.reshape(-1, D), r.reshape(-1, D)
     logits = jnp.dot(rt, params["router"]["kernel"], precision="highest")
     scores = logits if weighting == "softmax" else jax.nn.sigmoid(logits)
-    top, chosen = jax.lax.top_k(scores, K)
+    _, chosen = jax.lax.top_k(scores if bias is None else scores + bias, K)
+    top = jnp.take_along_axis(scores, chosen, -1)
     weights = jax.nn.softmax(top, -1) if weighting == "softmax" \
         else top / jnp.sum(top, -1, keepdims=True)
     out = jnp.zeros_like(xt)
     for e in experts:
         w_e = scale * jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
-        if form == "gated_relu":
+        if form in ("gated_relu", "gated_silu"):
+            act = jax.nn.relu if form == "gated_relu" else jax.nn.silu
             gate_up = xt @ params["w_gate_up"][e]
-            hidden = jax.nn.relu(gate_up[:, :F]) * gate_up[:, F:]
+            hidden = act(gate_up[:, :F]) * gate_up[:, F:]
         else:
             hidden = jnp.square(jax.nn.relu(xt @ params["w_up"][e]))
         out = out + w_e[:, None] * (hidden @ params["w_down"][e])
@@ -473,7 +477,8 @@ class TestWeightingsAndExpertForms:
     @pytest.mark.parametrize("weighting, form, own_router_input", [
         ("sigmoid", "relu2", True), ("sigmoid", "relu2", False),
         ("sigmoid", "gated_relu", True), ("softmax", "relu2", True),
-        ("softmax", "gated_relu", False)])
+        ("softmax", "gated_relu", False), ("sigmoid", "gated_silu", False),
+        ("softmax", "gated_silu", True)])
     def test_against_a_dense_loop_over_the_experts(self, inputs, weighting,
                                                    form, own_router_input):
         """Forward and every gradient against the layer written densely,
@@ -579,8 +584,85 @@ class TestWeightingsAndExpertForms:
     @pytest.mark.parametrize("kw, named", [
         (dict(weighting="tanh"), "unknown weighting 'tanh'.*softmax.*sigmoid"),
         (dict(expert_form="swiglu"),
-         "unknown expert_form 'swiglu'.*gated_relu.*relu2")])
+         "unknown expert_form 'swiglu'.*gated_relu.*relu2.*gated_silu")])
     def test_an_unknown_name_raises_by_name(self, inputs, kw, named):
         x, r = inputs
         with pytest.raises(ValueError, match=named):
             DroplessMoE(E, K, D, F, **kw).init(jax.random.PRNGKey(0), x, r)
+
+
+# -- the selection bias (PR 35) ------------------------------------------------
+
+class TestSelectionBias:
+    """``selection_bias``: added to the scores for the choice of the
+    ``top_k`` alone. It moves the choice and not the weights, and gets no
+    gradient."""
+
+    BIAS = jnp.asarray([0.0, 0.9, 0.0, -0.9, 0.0, 0.0, 0.4, 0.0])
+
+    @pytest.fixture
+    def case(self, rng):
+        x = jnp.asarray(rng.standard_normal((2, T // 2, D)), jnp.float32)
+        layer = DroplessMoE(E, K, D, F, weighting="sigmoid",
+                            weight_scale=SCALE, expert_form="gated_silu")
+        params = layer.init(jax.random.PRNGKey(3), x)["params"]
+        return layer, params, x
+
+    @pytest.mark.parametrize("held, first", [(E, 0), (2, 1)])
+    def test_against_a_dense_loop_with_the_bias(self, case, held, first):
+        """Output and every gradient, the whole layer and a share, float32:
+        1e-5 of the largest entry."""
+        whole, params, x = case
+        layer = DroplessMoE(E, K, D, F, experts_held=held, first_expert=first,
+                            weighting="sigmoid", weight_scale=SCALE,
+                            expert_form="gated_silu")
+        share = _share(params, first, held)
+        w = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
+        got = jax.value_and_grad(lambda p, x: jnp.sum(w * layer.apply(
+            {"params": p}, x, None, self.BIAS)), (0, 1))(share, x)
+        want = jax.value_and_grad(lambda p, x: jnp.sum(w * _dense_by(
+            p, x, x, "sigmoid", "gated_silu", SCALE,
+            range(first, first + held), self.BIAS)), (0, 1))(params, x)
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+        want_p = _share(want[1][0], first, held)
+        for a, b in zip(jax.tree.leaves((got[1][0], got[1][1])),
+                        jax.tree.leaves((want_p, want[1][1]))):
+            np.testing.assert_allclose(a, b, atol=1e-5 * float(
+                jnp.abs(b).max()))
+
+    def test_it_moves_the_choice_and_not_the_weights(self, case):
+        """With identity-like experts of one output each the layer shows
+        whom it chose and how it weighed them: with the bias some token's
+        choice changes, and every chosen expert's weight is its unbiased
+        score's share of the chosen unbiased scores."""
+        layer, params, x = case
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.reshape(-1, D), params["router"]["kernel"],
+            precision="highest"))
+        plain = jax.lax.top_k(scores, K)[1]
+        biased = jax.lax.top_k(scores + self.BIAS, K)[1]
+        moved = jnp.any(jnp.sort(plain, -1) != jnp.sort(biased, -1), -1)
+        assert 0 < int(moved.sum()) < moved.size
+        # expert e writes SCALE-free weight into column e: gate 1 * up 1
+        mark = DroplessMoE(E, K, D, 1, weighting="sigmoid",
+                           expert_form="relu2")
+        ones = jnp.ones((E, D, 1)) / D
+        down = jnp.zeros((E, 1, D)).at[jnp.arange(E), 0,
+                                       jnp.arange(E)].set(1.0)
+        marks = dict(router=params["router"], w_up=ones, w_down=down)
+        pos = jnp.ones_like(x)            # relu(mean of ones)^2 = 1
+        got = mark.apply({"params": marks}, pos, x, self.BIAS).reshape(-1, D)
+        top = jnp.take_along_axis(scores, biased, -1)
+        want = jnp.zeros_like(got).at[
+            jnp.arange(got.shape[0])[:, None], biased].set(
+                top / top.sum(-1, keepdims=True))
+        np.testing.assert_allclose(got, want, atol=1e-6)
+
+    def test_it_gets_no_gradient_and_zero_changes_nothing(self, case):
+        layer, params, x = case
+        g = jax.grad(lambda b: jnp.sum(jnp.square(layer.apply(
+            {"params": params}, x, None, b))))(self.BIAS)
+        assert bool(jnp.all(g == 0))
+        np.testing.assert_array_equal(
+            layer.apply({"params": params}, x, None, jnp.zeros(E)),
+            layer.apply({"params": params}, x))

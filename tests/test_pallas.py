@@ -526,7 +526,9 @@ class TestBlockSchedule:
         diagonal / edge / skipped."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
-        for window, want in ((None, (28, 8, 0, 28)), (4096, (18, 8, 4, 34))):
+        # trinity_mini_ep16_8k_1chip's window layers: 2048, two blocks wide
+        for window, want in ((None, (28, 8, 0, 28)), (4096, (18, 8, 4, 34)),
+                             (2048, (7, 8, 6, 43))):
             c = fa.block_counts("fwd", 8192, 8192, 0, window,
                                 fa._block_tiles("fwd"), 1024)
             assert tuple(c["blocks_" + k] for k in (
@@ -749,6 +751,92 @@ class TestTiledKernels:
 # (length, window): shorter than, equal to and longer than the sequence, and
 # a window shorter than one tile.
 _WINDOWS = [(256, 96), (256, 256), (256, 400), (256, 20)]
+
+
+class TestGatedNormedAttention:
+    """``TPSelfAttention(qk_norm_eps=..., gated=True)`` against attention
+    written out in ``jnp``: an RMS norm over every query and key head
+    before the rotation, the heads' output times the sigmoid of a fourth
+    projection of the input; 8:1 grouped K/V, heads twice as wide together
+    as the model."""
+
+    H, KV, D, HID, EPS = 8, 1, 16, 64, 1e-5
+
+    def _layer(self, window, use_flash, **kw):
+        from horovod_tpu.parallel.tp import TPSelfAttention
+        return TPSelfAttention(
+            self.H, self.HID, axis_name=None, causal=True,
+            use_flash=use_flash, num_kv_heads=self.KV, head_dim=self.D,
+            rope_theta=1e4 if window else None, window=window,
+            use_bias=False, qk_norm_eps=self.EPS, gated=True, **kw)
+
+    def _plain(self, p, x, window):
+        from horovod_tpu.parallel.tp import apply_rope
+        H, KV, D = self.H, self.KV, self.D
+        L = x.shape[1]
+
+        def rms(t, scale):
+            return t * jax.lax.rsqrt(jnp.mean(t * t, -1, keepdims=True)
+                                     + self.EPS) * scale
+
+        q, k, v = jnp.split(x @ p["qkv"]["shard"]["kernel"],
+                            [H * D, (H + KV) * D], -1)
+        q = rms(q.reshape(*x.shape[:2], H, D), p["q_norm"]["scale"])
+        k = rms(k.reshape(*x.shape[:2], KV, D), p["k_norm"]["scale"])
+        v = v.reshape(*x.shape[:2], KV, D)
+        t, j = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
+        keep = j <= t
+        if window:
+            q, k = (apply_rope(a, jnp.arange(L), 1e4) for a in (q, k))
+            keep &= j > t - window
+        k, v = (jnp.repeat(a, H // KV, 2) for a in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / D ** 0.5
+        o = jnp.einsum("bhqk,bkhd->bqhd",
+                       jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1), v)
+        o = o.reshape(*x.shape[:2], H * D) \
+            * jax.nn.sigmoid(x @ p["gate"]["shard"]["kernel"])
+        return o @ p["out"]["shard"]["kernel"]
+
+    @pytest.mark.parametrize("use_flash", [False, True])
+    @pytest.mark.parametrize("window", [None, 16])
+    def test_forward_and_gradients_match_jnp(self, rng, window, use_flash):
+        layer = self._layer(window, use_flash)
+        x = jnp.asarray(rng.standard_normal((2, 64, self.HID)), np.float32)
+        params = layer.init(jax.random.PRNGKey(0), x)["params"]
+        assert set(params) == {"qkv", "gate", "out", "q_norm", "k_norm"}
+        assert params["gate"]["shard"]["kernel"].shape \
+            == (self.HID, self.H * self.D)
+        assert params["q_norm"]["scale"].shape \
+            == params["k_norm"]["scale"].shape == (self.D,)
+        # scales other than one, so that a scale left out shows
+        for name, lo in (("q_norm", 0.5), ("k_norm", 1.5)):
+            params[name]["scale"] = lo + jnp.arange(self.D) / self.D
+        w = jnp.asarray(rng.standard_normal(x.shape), np.float32)
+        got = jax.value_and_grad(lambda p, x: jnp.sum(
+            w * layer.apply({"params": p}, x)), (0, 1))(params, x)
+        want = jax.value_and_grad(lambda p, x: jnp.sum(
+            w * self._plain(p, x, window)), (0, 1))(params, x)
+        np.testing.assert_allclose(got[0], want[0], rtol=2e-4)
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+            np.testing.assert_allclose(
+                a, b, atol=2e-4 * float(jnp.abs(b).max()))
+
+    @pytest.mark.parametrize("kw", [{"decode": True, "cache_len": 8},
+                                    {"sp_axis": "sp"}])
+    def test_raises_off_the_full_sequence_path(self, kw):
+        x = jnp.zeros((1, 8, self.HID))
+        with pytest.raises(ValueError, match="full-sequence path only"):
+            self._layer(None, False, **kw).init(jax.random.PRNGKey(0), x)
+
+    def test_the_gauge_says_what_the_layer_is(self):
+        from horovod_tpu import metrics
+        layer = self._layer(16, False)
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 32, self.HID)))
+        got = {s["labels"]["kind"]: s["value"] for s in
+               metrics.snapshot()["hvd_attn_layer"]["series"]}
+        assert got == {"heads": 8, "kv_heads": 1, "head_dim": 16,
+                       "window": 16, "normed": 1, "gated": 1}
 
 
 class TestSlidingWindow:

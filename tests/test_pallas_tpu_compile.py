@@ -66,6 +66,10 @@ _CALLS = {
                                 2048),
     "causal_2048_on_3072_keys": (2, 2048, 3072, 16, 4, 128, True,
                                  "bfloat16"),
+    # trinity_mini_ep16_8k_1chip's window layers: 8:1 grouped K/V, a window
+    # two 1024-blocks wide
+    "gqa_32_on_4_8192_window_2048": (2, 8192, 8192, 32, 4, 128, True,
+                                     "bfloat16", 2048),
 }
 
 
@@ -90,11 +94,15 @@ def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
 
 # -- DroplessMoE's grouped products (PR 34) -----------------------------------
 
-# (tokens, experts, a token, held, hidden, expert width, form): the expert
-# layer of each sparse cell of BENCHMARK.json, at its real shapes
+# (tokens, experts, a token, held, hidden, expert width, form, kernels): the
+# expert layer of each sparse cell of BENCHMARK.json, at its real shapes,
+# and how many kernels its six products make (two whose shapes and tiles
+# are the same are one kernel)
 _EXPERT_LAYERS = {
-    "smallthinker_ep4_8k": (16384, 64, 6, 16, 2560, 768, "gated_relu"),
-    "nemotron_tt_ep16_8k": (16384, 128, 6, 8, 2688, 1856, "relu2"),
+    "smallthinker_ep4_8k": (16384, 64, 6, 16, 2560, 768, "gated_relu", 6),
+    "nemotron_tt_ep16_8k": (16384, 128, 6, 8, 2688, 1856, "relu2", 6),
+    # both transposed products contract 2048 into blocks of 1024 columns
+    "trinity_mini_ep16_8k": (16384, 128, 8, 8, 2048, 1024, "gated_silu", 5),
 }
 
 
@@ -114,7 +122,7 @@ def test_no_grouped_product_of_a_cell_runs_a_128_wide_tile(one_chip,
     from horovod_tpu.ops.pallas import grouped_matmul as gmm
     from horovod_tpu.parallel import moe
     monkeypatch.setattr(gmm, "_interpret", lambda: False)
-    tokens, routed, k, held, d, f, form = _EXPERT_LAYERS[cell]
+    tokens, routed, k, held, d, f, form, kernels = _EXPERT_LAYERS[cell]
     rows = moe.buffer_rows(tokens, k, held, routed)
     wide = moe.EXPERT_FORMS[form][1] * f
 
@@ -134,7 +142,7 @@ def test_no_grouped_product_of_a_cell_runs_a_128_wide_tile(one_chip,
     ours = {(name, *map(int, dims)) for name, *dims in re.findall(
         r"(hvd_t?gmm(?:_t)?)_(\d+)x(\d+)x(\d+)", hlo)}
     # two products, each forward, for its left and for its right operand
-    assert not theirs and len(ours) == 6, (theirs, ours)
+    assert not theirs and len(ours) == kernels, (theirs, ours)
     assert {name for name, *_ in ours} == {"hvd_gmm", "hvd_gmm_t", "hvd_tgmm"}
     for *_, tk, tn in ours:
         assert min(tk, tn) >= 256, ours
