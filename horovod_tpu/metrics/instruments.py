@@ -396,11 +396,24 @@ SSM_LAYER = REGISTRY.gauge(
     ("kind",))
 SSM_CHUNK_STATE_BYTES = REGISTRY.gauge(
     "hvd_ssm_chunk_state_bytes",
-    "Float32 bytes of the chunk states one traced Mamba2Mixer call's scan "
-    "writes: one (head_dim, state) matrix a sequence, chunk and head. By "
-    "the chips the sequence is split over (1: the scan has no exchange). "
-    "Set while the call is traced.",
+    "Bytes of one set of chunk states that one traced Mamba2Mixer call's "
+    "scan writes to HBM: one (head_dim, state) matrix a sequence, chunk "
+    "and head. The jax.numpy form (hvd_ssm_scan_path 0) writes float32 "
+    "closing states forward; the kernels (path 1) write none forward and, "
+    "in the backward pass's first sweep, the state each chunk opens with "
+    "in the activations' dtype: that sweep's bytes. By the chips the "
+    "sequence is split over (1: the scan has no exchange). Set while the "
+    "call is traced.",
     ("axis_size",))
+SSM_SCAN_PATH = REGISTRY.gauge(
+    "hvd_ssm_scan_path",
+    "How the last traced parallel.ssm.ssm_scan call runs its chunks, by "
+    "kind: kernels (1: ops/pallas/ssm_scan.py, decay masks and the carried "
+    "state in VMEM; 0: the jax.numpy chunked form) and the kernels' block "
+    "of a grid step: positions, channels (a group's heads x head_dim), "
+    "state (zeros on path 0). Picked from the call's shapes "
+    "(parallel.ssm.scan_path). Set while the call is traced.",
+    ("kind",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -794,6 +807,16 @@ def record_ssm_layer(heads, head_dim, state, groups, chunk, chunks,
                     ("chunks", chunks)):
         SSM_LAYER.labels(kind).set(n)
     SSM_CHUNK_STATE_BYTES.labels(axis_size).set(chunk_state_bytes)
+
+
+def record_ssm_scan_path(path, blocks):
+    """Which way one trace of ``parallel.ssm.ssm_scan`` runs its chunks
+    (``parallel.ssm.scan_path``): known while the call is traced."""
+    if not _enabled:
+        return
+    for kind, n in zip(("kernels", "positions", "channels", "state"),
+                       (path, *blocks)):
+        SSM_SCAN_PATH.labels(kind).set(n)
 
 
 def record_flash_tiles(kernel, counts):

@@ -16,7 +16,11 @@ with the state its own tokens add, the recurrence runs over those closing
 states alone (``L / chunk`` steps), and the state a chunk opens with gives
 the rest of its outputs. The decays and their running sums, ``dt`` and the
 states stay float32; the products take the activations' dtype and
-accumulate in float32.
+accumulate in float32. Two ways run the same chunks, picked from the
+call's shapes alone (:func:`scan_path`): the Pallas kernels of
+``ops/pallas/ssm_scan.py``, which keep the masks and the carried state in
+VMEM, and :func:`chunked_scan`, ``jax.numpy`` products with masks and chunk
+states in HBM, for calls off the kernels' grid and as the tests' oracle.
 
 :class:`Mamba2Mixer` is the layer round it: one input projection to
 ``[z | x B C | dt]``, a causal depthwise convolution and SiLU over
@@ -34,15 +38,33 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.ops.pallas import ssm_scan as kernels
+
 # A fresh head's ``-A`` is drawn uniformly from here (Mamba-2's range; no
 # published config has a key for it).
 A_RANGE = (1.0, 16.0)
 
 
-def chunk_states_bytes(batch, length, heads, head_dim, state, chunk):
-    """Float32 bytes of the closing states one :func:`ssm_scan` call
-    writes: one (``head_dim``, ``state``) matrix a head and chunk."""
-    return 4 * batch * -(-length // chunk) * heads * head_dim * state
+def chunk_states_bytes(batch, length, heads, head_dim, state, chunk,
+                       itemsize=4):
+    """Bytes of one set of chunk states, one (``head_dim``, ``state``)
+    matrix a head and chunk, at ``itemsize`` bytes an element: float32 as
+    :func:`chunked_scan` writes its closing states."""
+    return itemsize * batch * -(-length // chunk) * heads * head_dim * state
+
+
+def scan_path(x_shape, groups, state, chunk, itemsize):
+    """``(path, blocks)`` of an :func:`ssm_scan` call, read off its shapes.
+    Path 1: the kernels of ``ops/pallas/ssm_scan.py`` in ``blocks``
+    (positions, channels and state columns a grid step), wherever their
+    rule admits the call (``pick_blocks``: a whole number of chunks of a
+    multiple of 128, a group's channels and the state's size multiples of
+    128, all of it inside the VMEM budget). Path 0: :func:`chunked_scan`,
+    ``blocks`` zeros, for every other call."""
+    _, length, heads, head_dim = x_shape
+    blocks = kernels.pick_blocks(length, heads, head_dim, groups, state,
+                                 min(chunk, length), itemsize)
+    return (0, (0, 0, 0)) if blocks is None else (1, blocks)
 
 
 def ssm_scan(x, dt, A, B, C, D, chunk):
@@ -52,12 +74,28 @@ def ssm_scan(x, dt, A, B, C, D, chunk):
     dtype (head ``h`` reads group ``h // (H / G)``); ``dt`` (b, L, H), the
     positive step, and ``A``, ``D`` (H,) float32. A length that is no
     whole number of chunks is padded with steps of ``dt`` 0, which neither
-    decay the state nor add to it. Differentiable as it stands.
+    decay the state nor add to it. One of two ways runs the chunks
+    (:func:`scan_path`), both differentiable in all six: the kernels under
+    a ``custom_vjp`` whose backward pass keeps the six alone, or
+    :func:`chunked_scan` as it stands.
     """
-    b, length, H, P = x.shape
-    G, N = B.shape[-2:]
+    H, (G, N) = x.shape[2], B.shape[-2:]
     if H % G:
         raise ValueError(f"{H} heads are no whole multiple of {G} groups")
+    path, blocks = scan_path(x.shape, G, N, chunk, x.dtype.itemsize)
+    from horovod_tpu.metrics import instruments as hvd_metrics
+    hvd_metrics.record_ssm_scan_path(path, blocks)
+    if path == 1:
+        return kernels.scan(x, dt, A, B, C, D, min(chunk, x.shape[1]))
+    return chunked_scan(x, dt, A, B, C, D, chunk)
+
+
+def chunked_scan(x, dt, A, B, C, D, chunk):
+    """:func:`ssm_scan` as ``jax.numpy`` products: the decay masks and two
+    sets of float32 chunk states go through HBM. Differentiable as it
+    stands, keeping all of them."""
+    b, length, H, P = x.shape
+    G, N = B.shape[-2:]
     R, Q = H // G, min(chunk, length)
     pad = -length % Q
     if pad:
@@ -180,8 +218,8 @@ class Mamba2Mixer(nn.Module):
     fresh head's step ``softplus(dt_bias)`` is drawn log-uniformly in
     [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``
     (the published configs' keys, Mamba-2's values by default). Of the scan
-    the backward pass keeps the inputs and the output alone
-    (``jax.checkpoint``), always."""
+    the backward pass keeps the convolution's output, the step and the
+    scan's output alone (``jax.checkpoint``), always."""
     hidden_size: int
     num_heads: int
     head_dim: int
@@ -202,9 +240,15 @@ class Mamba2Mixer(nn.Module):
         inner, b, length = H * P, u.shape[0], u.shape[1]
         chunk = min(self.chunk_size, length)
         from horovod_tpu.metrics import instruments as hvd_metrics
+        # One set of chunk states through HBM: float32 closing states on
+        # path 0; on path 1 the states the backward pass's first sweep
+        # writes, in the dtype the products read them in.
+        itemsize = jnp.dtype(self.dtype).itemsize
+        path, _ = scan_path((b, length, H, P), G, N, chunk, itemsize)
         hvd_metrics.record_ssm_layer(
             H, P, N, G, chunk, -(-length // chunk),
-            chunk_states_bytes(b, length, H, P, N, chunk))
+            chunk_states_bytes(b, length, H, P, N, chunk,
+                               itemsize if path else 4))
         with jax.named_scope("ssm.mixer"):
             with jax.named_scope("ssm.in_proj"):
                 zxbcdt = nn.Dense(2 * inner + 2 * G * N + H, use_bias=False,
@@ -212,23 +256,32 @@ class Mamba2Mixer(nn.Module):
                 z, xbc, dt = jnp.split(
                     zxbcdt, [inner, 2 * inner + 2 * G * N], axis=-1)
             with jax.named_scope("ssm.conv"):
-                xbc = nn.silu(CausalConv1d(self.conv_kernel, self.dtype,
-                                           name="conv")(xbc))
-                x, B, C = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+                xbc = CausalConv1d(self.conv_kernel, self.dtype,
+                                   name="conv")(xbc)
             dt_bias = self.param("dt_bias", _fresh_dt_bias(
                 self.time_step_min, self.time_step_max,
                 self.time_step_floor), (H,))
             a_log = self.param("A_log", _fresh_a_log, (H,))
             skip = self.param("D", nn.initializers.ones, (H,))
-            # The backward pass computes the scan again from its inputs:
-            # its 128 x 128 masks a head and chunk and its chunk states
-            # are several times the bytes of x, B, C and dt.
+
+            def activate_and_scan(xbc, step, A, skip):
+                with jax.named_scope("ssm.conv"):
+                    x, B, C = jnp.split(nn.silu(xbc), [inner, inner + G * N],
+                                        axis=-1)
+                with jax.named_scope("ssm.scan"):
+                    return ssm_scan(x.reshape(b, length, H, P), step, A,
+                                    B.reshape(b, length, G, N),
+                                    C.reshape(b, length, G, N), skip, chunk)
             with jax.named_scope("ssm.scan"):
-                y = jax.checkpoint(ssm_scan, static_argnums=(6,))(
-                    x.reshape(b, length, H, P),
-                    jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
-                    -jnp.exp(a_log), B.reshape(b, length, G, N),
-                    C.reshape(b, length, G, N), skip, chunk)
+                step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+                A = -jnp.exp(a_log)
+            # The backward pass starts from the convolution's output and
+            # the step alone: x, B and C (as much again) are activated and
+            # split a second time, and on path 0 the masks and chunk states
+            # computed again. On path 1 that second pass runs no kernel:
+            # the custom_vjp keeps its inputs, so its forward sweep is dead
+            # code there.
+            y = jax.checkpoint(activate_and_scan)(xbc, step, A, skip)
             with jax.named_scope("ssm.gate_norm"):
                 y = GatedGroupRMSNorm(G, self.norm_eps, self.dtype,
                                       name="gate_norm")(

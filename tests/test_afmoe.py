@@ -313,8 +313,11 @@ def _models():
     long, short = jnp.zeros((2, 512), jnp.int32), jnp.zeros((2, 64),
                                                             jnp.int32)
     return {
+        # PR 36 meant to alter this one: the mixer's checkpoint now holds
+        # the activation and the split beside the scan (1a0c0ff61873e94a
+        # before)
         "nemotron_h": (NemotronH(NemotronHConfig.tiny(
-            experts_held=2, first_expert_held=2)), long, "1a0c0ff61873e94a"),
+            experts_held=2, first_expert_held=2)), long, "7c3025bd832587d8"),
         "gpt": (GPT(GPTConfig.tiny()), short, "d068edd8e7aab78d"),
         "gpt_flash": (GPT(GPTConfig.tiny(use_flash=True)), short,
                       "cb4c5ffd803c118e"),
@@ -336,8 +339,8 @@ ATTENTION = {
 class TestTheOtherProgramsAreTheParents:
     """``TPSelfAttention`` with its default ``qk_norm_eps`` and ``gated``,
     ``NemotronH.tiny()`` holding a share and a tiny ``GPT`` trace, forward
-    and backward, the jaxprs they traced at commit a475bcc (PR 34), to the
-    digest (``SmallThinker.tiny()``'s is held by ``tests/test_smallthinker.py``,
+    and backward, the jaxprs they traced at commit a475bcc (PR 34;
+    ``NemotronH``'s since PR 36), to the digest (``SmallThinker.tiny()``'s is held by ``tests/test_smallthinker.py``,
     ``DroplessMoE``'s by ``tests/test_moe_dropless.py``). A change that
     means to alter one records a new digest and says so."""
 

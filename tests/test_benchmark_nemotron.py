@@ -275,13 +275,21 @@ class TestGoldens:
         assert cell["scan_work"]["bwd"]["flops"] == 2 * scan
 
     def test_chunk_states_of_a_mixer_call(self):
-        """``ssm.chunk_state_mb`` at the cell's sizes: 2 sequences x 64
-        chunks x 64 heads x 64 x 128 float32 = 268.4 MB a mixer call."""
-        from horovod_tpu.parallel.ssm import chunk_states_bytes
+        """``ssm.chunk_state_mb`` at the cell's sizes: one set of chunk
+        states is 2 sequences x 64 chunks x 64 heads x 64 x 128 elements a
+        mixer call. The cell's call lies on the kernels' grid: the forward
+        pass writes none, the backward pass's first sweep one set in
+        bfloat16, 134.2 MB; the ``jax.numpy`` form's float32 closing states
+        were 268.4 MB."""
+        from horovod_tpu.parallel.ssm import chunk_states_bytes, scan_path
         cfg = _json("benchmark", "configs", f"{CONFIG}.json")
-        assert chunk_states_bytes(
-            2, 8192, cfg["mamba_num_heads"], cfg["mamba_head_dim"],
-            cfg["ssm_state_size"], cfg["chunk_size"]) == 268_435_456
+        sizes = (cfg["mamba_num_heads"], cfg["mamba_head_dim"],
+                 cfg["ssm_state_size"], cfg["chunk_size"])
+        assert chunk_states_bytes(2, 8192, *sizes) == 268_435_456
+        path, blocks = scan_path((2, 8192, *sizes[:2]), cfg["n_groups"],
+                                 sizes[2], sizes[3], 2)
+        assert (path, blocks) == (1, (128, 512, 128))
+        assert chunk_states_bytes(2, 8192, *sizes, itemsize=2) == 134_217_728
         spec = _json("benchmark", "metrics", "ssm.chunk_state_mb.json")
         assert spec["args"] == {"gauge": "hvd_ssm_chunk_state_bytes",
                                 "scale": 1e-06}

@@ -1,4 +1,5 @@
-"""The flash kernels compiled for a described TPU v5e, without the chip.
+"""The repo's kernels (flash attention, the grouped products, the state-space
+scan) compiled for a described TPU v5e, without the chip.
 
 The Pallas interpreter (tests/test_pallas.py) checks the kernels'
 arithmetic; it cannot see what mosaic refuses: a slice not aligned to the
@@ -146,3 +147,37 @@ def test_no_grouped_product_of_a_cell_runs_a_128_wide_tile(one_chip,
     assert {name for name, *_ in ours} == {"hvd_gmm", "hvd_gmm_t", "hvd_tgmm"}
     for *_, tk, tn in ours:
         assert min(tk, tn) >= 256, ours
+
+
+# -- the Mamba-2 scan's kernels (PR 36) ---------------------------------------
+
+def test_the_scans_three_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """``parallel.ssm.ssm_scan`` and its backward pass at the call of
+    ``nemotron_tt_ep16_8k_1chip`` (2 x 8192, 64 heads of 64, state 128, 8
+    groups, chunks of 128, bfloat16) lower and compile for a v5e: the
+    forward sweep, the backward pass's states sweep and the reverse sweep,
+    one kernel each, in blocks of 128 positions x 512 channels x 128 state
+    columns. A slice off the (8, 128) tiling or too much scoped VMEM fails
+    here and not on the chip."""
+    from horovod_tpu.ops.pallas import ssm_scan as kernels
+    from horovod_tpu.parallel import ssm
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    b, length, heads, head, groups, state, chunk = 2, 8192, 64, 64, 8, 128, 128
+    assert ssm.scan_path((b, length, heads, head), groups, state, chunk, 2) \
+        == (1, (128, 512, 128))
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def forward_and_backward(dy, *operands):
+        y, pull = jax.vjp(lambda *a: ssm.ssm_scan(*a, chunk), *operands)
+        return y, pull(dy)
+    x = shape((b, length, heads, head))
+    bc = shape((b, length, groups, state))
+    per_head = shape((heads,), jnp.float32)
+    hlo = jax.jit(forward_and_backward).lower(
+        x, x, shape((b, length, heads), jnp.float32), per_head, bc, bc,
+        per_head).compile().as_text()
+    for kernel in ("hvd_ssm_fwd", "hvd_ssm_states", "hvd_ssm_bwd"):
+        assert f"{kernel}_128x512x128" in hlo, \
+            f"{kernel} is not in the compiled program"
