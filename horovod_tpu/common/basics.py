@@ -149,6 +149,11 @@ def _init(process_sets, devices):
         # then skip XLA recompiles entirely (see docs/performance.md).
         with span("init.compile_cache"):
             _setup_compile_cache(config.compile_cache_dir)
+            # The one listener on JAX's monitoring bus: the cache's
+            # counts, and while tracing is armed the compile's stages as
+            # spans of the run trace, whatever became of the cache.
+            from horovod_tpu import metrics as hvd_metrics
+            hvd_metrics.install_compile_cache_listener()
 
         with span("init.topology"):
             topology = build_topology(devices)
@@ -334,8 +339,6 @@ def _setup_compile_cache(path):
         # it off — reset so the directory takes effect.
         from jax._src import compilation_cache as _cc
         _cc.reset_cache()
-        from horovod_tpu import metrics as hvd_metrics
-        hvd_metrics.install_compile_cache_listener()
         hvd_logging.info("persistent XLA compile cache at %s", path)
     except Exception as e:  # noqa: BLE001 — cache is an optimization only
         hvd_logging.warning("compile cache setup failed (%s): %s", path, e)

@@ -576,27 +576,93 @@ def record_compile_cache(event):
     COMPILE_CACHE_EVENTS.labels(event).inc()
 
 
-_compile_listener_installed = False
+# JAX's monitoring bus: the events mirrored into the registry, and the
+# time spans that become spans of the ``run`` trace (``fun`` in ``args``;
+# docs/observability.md).
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# A traced step traces hundreds of small inner functions, and a process
+# may compile for as long as it lives: spans under a millisecond are
+# dropped, so the ``run`` trace keeps the ones worth reading and stays
+# under the store's cap.
+COMPILE_SPAN_MIN_S = 1e-3
+
+_listeners_installed = set()      # "counts", "spans"
+_cache_load = threading.local()   # .seconds: a cache read not yet placed
+
+
+def _on_compile_event(event, **kwargs):
+    if event == "/jax/compilation_cache/cache_hits":
+        record_compile_cache("hit")
+    elif event == "/jax/compilation_cache/compile_requests_use_cache":
+        record_compile_cache("request")
+
+
+def _on_compile_duration(event, duration_secs, **kwargs):
+    # JAX reports the cache read from inside the backend's span, before
+    # that span closes and with no function name: held for the span.
+    if event == _CACHE_LOAD_EVENT:
+        _cache_load.seconds = duration_secs
+
+
+def _compiled_fun(name):
+    """The traced function's own name: JAX says ``hvd_dp_step`` when it
+    traces and ``jit(hvd_dp_step)`` when it lowers and compiles."""
+    name = str(name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name
+
+
+def _on_compile_span(event, start_time, end_time, fun_name="", **kwargs):
+    """One stage of a compile as a span of the ``run`` trace: JAX's clock
+    is the store's (``time.time``). ``compile.cache_load`` is the child of
+    the ``compile.backend`` it was read in and ends where that ends."""
+    name = _COMPILE_SPANS.get(event)
+    if name is None:
+        return
+    load = None
+    if name == "compile.backend":
+        load = getattr(_cache_load, "seconds", None)
+        _cache_load.seconds = None
+    if end_time - start_time < COMPILE_SPAN_MIN_S:
+        return
+    from horovod_tpu import trace as _trace
+    tid = _trace.run_tid()
+    args = {"fun": _compiled_fun(fun_name)}
+    kept = _trace.add_span(tid, name, start_time, end_time - start_time,
+                           args=args)
+    if load is not None and kept is not None:
+        _trace.add_span(tid, "compile.cache_load", end_time - load, load,
+                        parent=name, args=args)
 
 
 def install_compile_cache_listener():
-    """Mirror JAX's persistent-compilation-cache monitoring events into
-    the registry, so cache effectiveness (and the zero-fresh-compiles
-    restart guarantee) is assertable from metrics. Idempotent; installed
-    when ``basics.init`` arms the cache."""
-    global _compile_listener_installed
-    if _compile_listener_installed:
-        return
+    """Listen to JAX's monitoring bus, the one site that does. Idempotent;
+    installed by ``basics.init``.
+
+    Always: the persistent compilation cache's events mirrored into the
+    registry, so cache effectiveness (and the zero-fresh-compiles restart
+    guarantee) is assertable from metrics. While tracing is armed
+    (``HOROVOD_TRACE``): the three stages of every compile as spans of the
+    ``run`` trace, ``compile.trace`` / ``compile.lower`` /
+    ``compile.backend`` with the function's name, and the cache read as
+    ``compile.cache_load`` under the last. A function that compiles again
+    in the middle of a run shows as a second ``compile.backend``."""
     from jax._src import monitoring as _jax_monitoring
-
-    def _on_event(event, **kwargs):
-        if event == "/jax/compilation_cache/cache_hits":
-            record_compile_cache("hit")
-        elif event == "/jax/compilation_cache/compile_requests_use_cache":
-            record_compile_cache("request")
-
-    _jax_monitoring.register_event_listener(_on_event)
-    _compile_listener_installed = True
+    from horovod_tpu import trace as _trace
+    if "counts" not in _listeners_installed:
+        _jax_monitoring.register_event_listener(_on_compile_event)
+        _listeners_installed.add("counts")
+    if _trace.armed and "spans" not in _listeners_installed:
+        _jax_monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        _jax_monitoring.register_event_time_span_listener(_on_compile_span)
+        _listeners_installed.add("spans")
 
 
 def record_negotiation(gets, payload_bytes, sets=1, tier_gets=None):

@@ -38,11 +38,11 @@ import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.tp import TPSelfAttention, TPSwiGLUMlp
+from horovod_tpu.trace.scopes import scope
 
 FFN_KINDS = ("dense", "sparse")
 ATTENTION_KINDS = {"sliding_attention": "window", "full_attention": "full"}
@@ -133,7 +133,9 @@ class AfmoeBlock(nn.Module):
             return nn.RMSNorm(epsilon=c.rms_eps, dtype=c.dtype, name=name)
 
         windowed = attention == "window"
-        with jax.named_scope("attn.window" if windowed else "attn.full"):
+        with scope("attn.window" if windowed else "attn.full"):
+            with scope("block.norm"):
+                h = norm("input_norm")(x)
             a = TPSelfAttention(
                 c.num_heads, c.hidden_size, dtype=c.dtype, axis_name=None,
                 causal=True, use_flash=c.use_flash,
@@ -141,12 +143,13 @@ class AfmoeBlock(nn.Module):
                 rope_theta=c.rope_theta if windowed else None,
                 window=c.sliding_window if windowed else None,
                 use_bias=False, qk_norm_eps=c.rms_eps, gated=True,
-                name="attention")(norm("input_norm")(x))
-        with jax.named_scope("block.post_norm"):
+                name="attention")(h)
+        with scope("block.post_norm"):
             x = x + norm("post_attn_norm")(a)
-        m = norm("pre_ffn_norm")(x)
+        with scope("block.norm"):
+            m = norm("pre_ffn_norm")(x)
         if ffn == "dense":
-            with jax.named_scope("mlp.dense"):
+            with scope("mlp.dense"):
                 f = TPSwiGLUMlp(c.dense_size, c.hidden_size, dtype=c.dtype,
                                 axis_name=None, name="mlp")(m)
         else:
@@ -165,11 +168,11 @@ class AfmoeBlock(nn.Module):
                 first_expert=c.first_expert_held, dtype=c.dtype,
                 weighting="sigmoid", weight_scale=c.routed_scale,
                 expert_form="gated_silu", name="moe")(m, None, bias)
-            with jax.named_scope("moe.shared"):
+            with scope("moe.shared"):
                 f = f + TPSwiGLUMlp(
                     c.expert_size * c.shared_experts, c.hidden_size,
                     dtype=c.dtype, axis_name=None, name="shared")(m)
-        with jax.named_scope("block.post_norm"):
+        with scope("block.post_norm"):
             return x + norm("post_ffn_norm")(f)
 
 
@@ -206,7 +209,10 @@ class Afmoe(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         c = self.config
-        x = AfmoeEmbed(c, name="embed")(input_ids)
-        for i, kind in enumerate(c.kinds):
-            x = AfmoeBlock(c, kind, name=f"layer_{i}")(x)
-        return AfmoeHead(c, name="head")(x)
+        with scope("lm.model"):
+            with scope("lm.embed"):
+                x = AfmoeEmbed(c, name="embed")(input_ids)
+            for i, kind in enumerate(c.kinds):
+                x = AfmoeBlock(c, kind, name=f"layer_{i}")(x)
+            with scope("lm.head"):
+                return AfmoeHead(c, name="head")(x)

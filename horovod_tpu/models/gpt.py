@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from horovod_tpu.parallel.moe import MoEMlp
 from horovod_tpu.parallel.tp import TPTransformerBlock
+from horovod_tpu.trace.scopes import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,8 +180,6 @@ class GPT(nn.Module):
             if pos is None:
                 raise ValueError("decode mode requires pos (the token's "
                                  "global position)")
-        x = GPTEmbed(c, name="embed")(input_ids,
-                                      pos if self.decode else None)
         # remat (training only — decode has no backward): recompute each
         # block in the vjp instead of stashing its activations.
         dense_cls = TPTransformerBlock
@@ -188,19 +187,24 @@ class GPT(nn.Module):
         if c.remat and not self.decode:
             dense_cls = nn.remat(TPTransformerBlock)
             moe_cls = nn.remat(GPTMoEBlock)
-        for i in range(c.num_layers):
-            if c.num_experts and i % self.moe_every == self.moe_every - 1:
-                x = moe_cls(c, name=f"layer_{i}")(x)
-            else:
-                x = dense_cls(
-                    c.num_heads, c.hidden_size, c.intermediate_size,
-                    dtype=c.dtype, axis_name=c.tp_axis, causal=True,
-                    use_flash=c.use_flash, sp_axis=c.sp_axis,
-                    sp_impl=c.sp_impl, decode=self.decode,
-                    cache_len=c.max_position_embeddings,
-                    kv_cache_int8=c.kv_cache_int8,
-                    name=f"layer_{i}")(
-                        x, pos=pos if self.decode else None)
-        if features_only:
-            return x
-        return GPTHead(c, name="head")(x)
+        with scope("lm.model"):
+            with scope("lm.embed"):
+                x = GPTEmbed(c, name="embed")(input_ids,
+                                              pos if self.decode else None)
+            for i in range(c.num_layers):
+                if c.num_experts and i % self.moe_every == self.moe_every - 1:
+                    x = moe_cls(c, name=f"layer_{i}")(x)
+                else:
+                    x = dense_cls(
+                        c.num_heads, c.hidden_size, c.intermediate_size,
+                        dtype=c.dtype, axis_name=c.tp_axis, causal=True,
+                        use_flash=c.use_flash, sp_axis=c.sp_axis,
+                        sp_impl=c.sp_impl, decode=self.decode,
+                        cache_len=c.max_position_embeddings,
+                        kv_cache_int8=c.kv_cache_int8,
+                        name=f"layer_{i}")(
+                            x, pos=pos if self.decode else None)
+            if features_only:
+                return x
+            with scope("lm.head"):
+                return GPTHead(c, name="head")(x)

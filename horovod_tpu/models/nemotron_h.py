@@ -27,12 +27,12 @@ import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.ssm import Mamba2Mixer
 from horovod_tpu.parallel.tp import TPSelfAttention
+from horovod_tpu.trace.scopes import scope
 
 KINDS = ("M", "E", "*")     # Mamba-2 mixer | experts | attention
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -130,7 +130,9 @@ class NemotronHBlock(nn.Module):
         if self.kind not in KINDS:
             raise ValueError(f"unknown kind of layer {self.kind!r}; "
                              f"choose from {KINDS}")
-        h = nn.RMSNorm(epsilon=c.norm_eps, dtype=c.dtype, name="norm")(x)
+        with scope("block.norm"):
+            h = nn.RMSNorm(epsilon=c.norm_eps, dtype=c.dtype,
+                           name="norm")(x)
         if self.kind == "M":
             return x + Mamba2Mixer(
                 c.hidden_size, c.mamba_heads, c.mamba_head_dim, c.state_size,
@@ -141,7 +143,7 @@ class NemotronHBlock(nn.Module):
                 time_step_floor=c.time_step_floor,
                 dtype=c.dtype, name="mixer")(h)
         if self.kind == "*":
-            with jax.named_scope("attn.full"):
+            with scope("attn.full"):
                 return x + TPSelfAttention(
                     c.num_heads, c.hidden_size, dtype=c.dtype,
                     axis_name=None, causal=True, use_flash=c.use_flash,
@@ -156,7 +158,7 @@ class NemotronHBlock(nn.Module):
             experts_held=c.experts_held, first_expert=c.first_expert_held,
             dtype=c.dtype, weighting="sigmoid", weight_scale=c.routed_scale,
             expert_form="relu2", name="moe")(h)
-        with jax.named_scope("moe.shared"):
+        with scope("moe.shared"):
             shared = SharedExpert(c, name="shared")(h)
         return x + routed + shared
 
@@ -191,7 +193,10 @@ class NemotronH(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         c = self.config
-        x = NemotronHEmbed(c, name="embed")(input_ids)
-        for i, kind in enumerate(c.kinds):
-            x = NemotronHBlock(c, kind, name=f"layer_{i}")(x)
-        return NemotronHHead(c, name="head")(x)
+        with scope("lm.model"):
+            with scope("lm.embed"):
+                x = NemotronHEmbed(c, name="embed")(input_ids)
+            for i, kind in enumerate(c.kinds):
+                x = NemotronHBlock(c, kind, name=f"layer_{i}")(x)
+            with scope("lm.head"):
+                return NemotronHHead(c, name="head")(x)

@@ -23,11 +23,11 @@ import dataclasses
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
 from horovod_tpu.parallel.moe import DroplessMoE
 from horovod_tpu.parallel.tp import TPSelfAttention
+from horovod_tpu.trace.scopes import scope
 
 KINDS = ("full", "window")
 
@@ -103,8 +103,10 @@ class SmallThinkerBlock(nn.Module):
             raise ValueError(f"unknown kind of layer {self.kind!r}; "
                              f"choose from {KINDS}")
         windowed = self.kind == "window"
-        h = nn.RMSNorm(epsilon=c.rms_eps, dtype=c.dtype, name="ln_attn")(x)
-        with jax.named_scope("attn.window" if windowed else "attn.full"):
+        with scope("block.norm"):
+            h = nn.RMSNorm(epsilon=c.rms_eps, dtype=c.dtype,
+                           name="ln_attn")(x)
+        with scope("attn.window" if windowed else "attn.full"):
             a = TPSelfAttention(
                 c.num_heads, c.hidden_size, dtype=c.dtype, axis_name=None,
                 causal=True, use_flash=c.use_flash,
@@ -113,14 +115,15 @@ class SmallThinkerBlock(nn.Module):
                 window=c.sliding_window if windowed else None,
                 use_bias=False, name="attention")(h)
         a = x + a
+        with scope("block.norm"):
+            h = nn.RMSNorm(epsilon=c.rms_eps, dtype=c.dtype,
+                           name="ln_mlp")(a)
         # The backward pass computes the expert layer again: its T x top_k
         # rows are most of a block's saved bytes and little of its time.
         y = nn.remat(DroplessMoE)(
             c.num_experts, c.experts_per_token, c.hidden_size, c.expert_size,
             experts_held=c.experts_held, first_expert=c.first_expert_held,
-            dtype=c.dtype, name="moe")(
-                nn.RMSNorm(epsilon=c.rms_eps, dtype=c.dtype,
-                           name="ln_mlp")(a), x)
+            dtype=c.dtype, name="moe")(h, x)
         return a + y
 
 
@@ -155,7 +158,10 @@ class SmallThinker(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         c = self.config
-        x = SmallThinkerEmbed(c, name="embed")(input_ids)
-        for i, kind in enumerate(c.kinds):
-            x = SmallThinkerBlock(c, kind, name=f"layer_{i}")(x)
-        return SmallThinkerHead(c, name="head")(x)
+        with scope("lm.model"):
+            with scope("lm.embed"):
+                x = SmallThinkerEmbed(c, name="embed")(input_ids)
+            for i, kind in enumerate(c.kinds):
+                x = SmallThinkerBlock(c, kind, name=f"layer_{i}")(x)
+            with scope("lm.head"):
+                return SmallThinkerHead(c, name="head")(x)

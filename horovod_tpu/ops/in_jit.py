@@ -24,6 +24,7 @@ from jax import lax
 from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.ops.collective_ops import (Adasum, Average, Max, Min, Product,
                                             ReduceOp, Sum)
+from horovod_tpu.trace.scopes import scope
 
 
 def _ranks(process_set):
@@ -49,7 +50,7 @@ def _wire():
     """Device scope ``hvd.wire``: round the collective primitive alone, so
     a profile finds the exchange by where the program put it, whatever the
     compiler names the op (docs/observability.md)."""
-    return jax.named_scope("hvd.wire")
+    return scope("hvd.wire")
 
 
 def _gather_select(x, ranks, axis_name):

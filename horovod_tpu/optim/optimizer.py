@@ -33,6 +33,7 @@ from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.ops import in_jit
 from horovod_tpu.ops.collective_ops import Adasum, Average, ReduceOp, Sum
 from horovod_tpu.ops.compression import Compression
+from horovod_tpu.trace.scopes import scope
 
 
 def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
@@ -59,7 +60,7 @@ def fused_allreduce_tree(tree, op=Average, axis_name=HVD_AXIS,
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
-    with jax.named_scope("hvd.grad_exchange"):
+    with scope("hvd.grad_exchange"):
         out = _fused_allreduce_leaves(
             leaves, ReduceOp(op), axis_name, process_set, compression,
             prescale_factor, postscale_factor)
@@ -85,7 +86,7 @@ def _fused_allreduce_leaves(leaves, op, axis_name, process_set, compression,
         # request from inside a jit trace.
         compressed = [(jnp.asarray(l), None) for l in leaves]
     else:
-        with jax.named_scope("pack"):
+        with scope("pack"):
             compressed = [compression.compress(jnp.asarray(l))
                           for l in leaves]
     groups = {}
@@ -131,7 +132,7 @@ def _fused_allreduce_leaves(leaves, op, axis_name, process_set, compression,
         for bucket in buckets:
             parts = [compressed[i][0] for i in bucket]
             with jax.named_scope(f"bucket{n_buckets}"):
-                with jax.named_scope("pack"):
+                with scope("pack"):
                     flats = [x.reshape(-1) for x in parts]
                     buf = jnp.concatenate(flats) if len(flats) > 1 \
                         else flats[0]
@@ -161,7 +162,7 @@ def _fused_allreduce_leaves(leaves, op, axis_name, process_set, compression,
                         process_set=process_set,
                         prescale_factor=prescale_factor,
                         postscale_factor=postscale_factor)
-                with jax.named_scope("unpack"):
+                with scope("unpack"):
                     off = 0
                     for i, x in zip(bucket, parts):
                         out[i] = jax.lax.slice_in_dim(
@@ -170,7 +171,7 @@ def _fused_allreduce_leaves(leaves, op, axis_name, process_set, compression,
     from horovod_tpu.metrics import instruments as hvd_metrics
     hvd_metrics.record_fused_allreduce(lax.axis_size(axis_name), n_buckets,
                                        wire_bytes)
-    with jax.named_scope("unpack"):
+    with scope("unpack"):
         return [compression.decompress(o, ctx)
                 for o, (_, ctx) in zip(out, compressed)]
 

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu import trace
 from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.ops import in_jit
+from horovod_tpu.trace.scopes import scope
 
 
 class TrainState(struct.PyTreeNode):
@@ -56,7 +57,7 @@ def _loss_and_grad(loss_fn, has_aux, params, batch, extra):
     """``(loss, aux, grads)`` of the local batch under the device scope
     ``hvd.loss_and_grad``; within it JAX marks the backward pass's ops
     ``transpose(jvp(...))``."""
-    with jax.named_scope("hvd.loss_and_grad"):
+    with scope("hvd.loss_and_grad"):
         if has_aux:
             (loss, aux), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(params, batch, extra)
@@ -97,7 +98,7 @@ def make_train_step(loss_fn: Callable, optimizer, mesh, axis_name=HVD_AXIS,
         # The DistributedOptimizer's exchange runs inside ``update`` and
         # names itself ``hvd.grad_exchange``: an op belongs to the
         # innermost ``hvd.*`` scope on its path.
-        with jax.named_scope("hvd.optimizer"):
+        with scope("hvd.optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         # The means of loss and aux through in_jit.allreduce (Average), so
@@ -199,7 +200,7 @@ def make_zero_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
         idx = lax.axis_index(axis_name)
         p_shard = lax.dynamic_slice(jnp.pad(flat_p, (0, pad)),
                                     (idx * shard_len,), (shard_len,))
-        with jax.named_scope("hvd.optimizer"):
+        with scope("hvd.optimizer"):
             updates, opt_state = tx.update(g_shard, opt_state, p_shard)
             p_shard = optax.apply_updates(p_shard, updates)
         flat_new = lax.all_gather(p_shard, axis_name, tiled=True)

@@ -35,6 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu import trace
 from horovod_tpu.common.topology import HVD_AXIS
+from horovod_tpu.trace.scopes import scope
 
 
 def fsdp_spec(shape, n, min_size=16384, axis_name=HVD_AXIS):
@@ -116,9 +117,9 @@ def make_fsdp_train_step(loss_fn: Callable, tx, mesh, axis_name=HVD_AXIS,
     # profile of either reads alike (docs/observability.md).
     @functools.partial(jax.jit, donate_argnums=(0, 1) if donate else ())
     def hvd_fsdp_step(params, opt_state, batch):
-        with jax.named_scope("hvd.loss_and_grad"):
+        with scope("hvd.loss_and_grad"):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        with jax.named_scope("hvd.optimizer"):
+        with scope("hvd.optimizer"):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         return params, opt_state, loss

@@ -42,6 +42,7 @@ from jax import lax
 
 from horovod_tpu.ops.pallas import grouped_matmul as gmm
 from horovod_tpu.parallel.tp import axis_size_or_1, shard_init
+from horovod_tpu.trace.scopes import scope
 
 EP_AXIS = "ep"
 
@@ -364,14 +365,14 @@ def _on_rows(rows, k, form, xt, weights, w_in, w_down, order, inverse, sizes):
     first ``rows`` of the sorted pairs, which must hold every live one:
     their rows out of ``xt``, through the grouped products of experts of
     ``form``, back into their tokens."""
-    with jax.named_scope("moe.dispatch"):
+    with scope("moe.dispatch"):
         where = _rows(rows, k, order, inverse, jnp.sum(sizes))
         buf = _take_rows(xt, where)
-    with jax.named_scope("moe.experts"):
+    with scope("moe.experts"):
         h = _grouped_dot(buf, jnp.asarray(w_in, xt.dtype), sizes)
         y = _grouped_dot(EXPERT_FORMS[form][2](h),
                          jnp.asarray(w_down, xt.dtype), sizes)
-    with jax.named_scope("moe.combine"):
+    with scope("moe.combine"):
         return _combine(y, weights, where)
 
 
@@ -516,7 +517,7 @@ class DroplessMoE(nn.Module):
             products={"in": product_tiles(C, d, wide, itemsize),
                       "down": product_tiles(C, f, d, itemsize)})
 
-        with jax.named_scope("moe.route"):
+        with scope("moe.route"):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               precision=lax.Precision.HIGHEST,
                               name="router")(rt.astype(jnp.float32))
@@ -534,7 +535,7 @@ class DroplessMoE(nn.Module):
             if self.weight_scale != 1.0:
                 weights = weights * self.weight_scale
 
-        with jax.named_scope("moe.dispatch"):
+        with scope("moe.dispatch"):
             local = chosen - self.first_expert
             here = (local >= 0) & (local < held)                   # (T, k)
             # Pairs routed elsewhere sort behind the last expert held.

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.ops.pallas import ssm_scan as kernels
+from horovod_tpu.trace.scopes import scope
 
 # A fresh head's ``-A`` is drawn uniformly from here (Mamba-2's range; no
 # published config has a key for it).
@@ -249,13 +250,13 @@ class Mamba2Mixer(nn.Module):
             H, P, N, G, chunk, -(-length // chunk),
             chunk_states_bytes(b, length, H, P, N, chunk,
                                itemsize if path else 4))
-        with jax.named_scope("ssm.mixer"):
-            with jax.named_scope("ssm.in_proj"):
+        with scope("ssm.mixer"):
+            with scope("ssm.in_proj"):
                 zxbcdt = nn.Dense(2 * inner + 2 * G * N + H, use_bias=False,
                                   dtype=self.dtype, name="in_proj")(u)
                 z, xbc, dt = jnp.split(
                     zxbcdt, [inner, 2 * inner + 2 * G * N], axis=-1)
-            with jax.named_scope("ssm.conv"):
+            with scope("ssm.conv"):
                 xbc = CausalConv1d(self.conv_kernel, self.dtype,
                                    name="conv")(xbc)
             dt_bias = self.param("dt_bias", _fresh_dt_bias(
@@ -265,14 +266,14 @@ class Mamba2Mixer(nn.Module):
             skip = self.param("D", nn.initializers.ones, (H,))
 
             def activate_and_scan(xbc, step, A, skip):
-                with jax.named_scope("ssm.conv"):
+                with scope("ssm.conv"):
                     x, B, C = jnp.split(nn.silu(xbc), [inner, inner + G * N],
                                         axis=-1)
-                with jax.named_scope("ssm.scan"):
+                with scope("ssm.scan"):
                     return ssm_scan(x.reshape(b, length, H, P), step, A,
                                     B.reshape(b, length, G, N),
                                     C.reshape(b, length, G, N), skip, chunk)
-            with jax.named_scope("ssm.scan"):
+            with scope("ssm.scan"):
                 step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
                 A = -jnp.exp(a_log)
             # The backward pass starts from the convolution's output and
@@ -282,10 +283,10 @@ class Mamba2Mixer(nn.Module):
             # the custom_vjp keeps its inputs, so its forward sweep is dead
             # code there.
             y = jax.checkpoint(activate_and_scan)(xbc, step, A, skip)
-            with jax.named_scope("ssm.gate_norm"):
+            with scope("ssm.gate_norm"):
                 y = GatedGroupRMSNorm(G, self.norm_eps, self.dtype,
                                       name="gate_norm")(
                     y.reshape(b, length, inner), z)
-            with jax.named_scope("ssm.out_proj"):
+            with scope("ssm.out_proj"):
                 return nn.Dense(self.hidden_size, use_bias=False,
                                 dtype=self.dtype, name="out_proj")(y)

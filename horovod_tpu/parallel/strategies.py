@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.common.topology import CROSS_AXIS, LOCAL_AXIS
+from horovod_tpu.trace.scopes import scope
 
 
 def allreduce_torus(x, cross_axis=CROSS_AXIS, local_axis=LOCAL_AXIS,
@@ -519,7 +520,7 @@ def scaled_allreduce_int8(x, axis_name="hvd", average=False,
     _record_jit_wire(x, axis_name, "int8")
     # The quantized exchange as one unit of the wire (its blocks' scales
     # and both int8 legs), as in_jit.allreduce scopes its psum.
-    with jax.named_scope("hvd.wire"):
+    with scope("hvd.wire"):
         out, _ = _wire.block_scaled_allreduce(
             x, axis_name=axis_name, wire="int8", average=average,
             prescale_factor=prescale_factor,

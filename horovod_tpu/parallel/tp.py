@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from horovod_tpu.trace.scopes import scope
+
 TP_AXIS = "tp"
 
 
@@ -217,7 +219,9 @@ class TPSelfAttention(nn.Module):
     multiplied by its sigmoid before the output projection. Both act on the
     full-sequence path, plain and flash alike, under the scopes
     ``attn.qk_norm`` and ``attn.gate``; with ``decode=True`` or an
-    ``sp_axis`` they raise.
+    ``sp_axis`` they raise. The rest of a call lies under the leaf scopes
+    ``attn.qkv``, ``attn.rope``, ``attn.core`` (``_attend`` and the
+    heads' merge) and ``attn.out`` (``trace/scopes.py``).
     """
     num_heads: int
     hidden_size: int
@@ -443,19 +447,20 @@ class TPSelfAttention(nn.Module):
         # (and the matching kv-head slice), i.e. the global logical weight is
         # the head-blocked interleaving of the shards — one large MXU matmul
         # per shard.
-        qkv = ColumnParallelDense(
-            (self.num_heads + 2 * kv_heads) * head_dim, dtype=self.dtype,
-            use_bias=self.use_bias, axis_name=self.axis_name, name="qkv")(x)
-        q, k, v = jnp.split(
-            qkv, [local_heads * head_dim, (local_heads + local_kv) * head_dim],
-            axis=-1)
-
         def heads(t):
             return t.reshape(t.shape[:-1] + (-1, head_dim))
 
-        q, k, v = heads(q), heads(k), heads(v)
+        with scope("attn.qkv"):
+            qkv = ColumnParallelDense(
+                (self.num_heads + 2 * kv_heads) * head_dim, dtype=self.dtype,
+                use_bias=self.use_bias, axis_name=self.axis_name,
+                name="qkv")(x)
+            q, k, v = jnp.split(
+                qkv, [local_heads * head_dim,
+                      (local_heads + local_kv) * head_dim], axis=-1)
+            q, k, v = heads(q), heads(k), heads(v)
         if normed:
-            with jax.named_scope("attn.qk_norm"):
+            with scope("attn.qk_norm"):
                 q = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
                                name="q_norm")(q)
                 k = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
@@ -487,25 +492,30 @@ class TPSelfAttention(nn.Module):
                 if (self.sp_axis is not None
                         and axis_size_or_1(self.sp_axis) > 1):
                     off = lax.axis_index(self.sp_axis) * L
-                positions = off + jnp.arange(L, dtype=jnp.int32)
-                q = apply_rope(q, positions, self.rope_theta)
-                k = apply_rope(k, positions, self.rope_theta)
+                with scope("attn.rope"):
+                    positions = off + jnp.arange(L, dtype=jnp.int32)
+                    q = apply_rope(q, positions, self.rope_theta)
+                    k = apply_rope(k, positions, self.rope_theta)
             # Grouped kv heads stay NARROW here: _attend broadcasts them
             # for the paths that need MHA shapes and streams them natively
             # through the flash kernels. (Decode above instead contracts
             # grouped q heads against the narrow cache.)
-            out = self._attend(q, k, v, mask, bias=bias)
-        out = out.reshape(out.shape[:-2] + (local_heads * head_dim,))
+            with scope("attn.core"):
+                out = self._attend(q, k, v, mask, bias=bias)
+        with scope("attn.core"):      # the heads' merge belongs to it too
+            out = out.reshape(out.shape[:-2] + (local_heads * head_dim,))
         if self.gated:
-            with jax.named_scope("attn.gate"):
+            with scope("attn.gate"):
                 gate = ColumnParallelDense(
                     self.num_heads * head_dim, dtype=self.dtype,
                     use_bias=self.use_bias, axis_name=self.axis_name,
                     name="gate")(x)
                 out = out * nn.sigmoid(gate)
-        return RowParallelDense(self.hidden_size, dtype=self.dtype,
-                                use_bias=self.use_bias,
-                                axis_name=self.axis_name, name="out")(out)
+        with scope("attn.out"):
+            return RowParallelDense(self.hidden_size, dtype=self.dtype,
+                                    use_bias=self.use_bias,
+                                    axis_name=self.axis_name,
+                                    name="out")(out)
 
 
 class TPMlp(nn.Module):
@@ -630,18 +640,21 @@ class TPTransformerBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None, pos=None):
-        a = TPSelfAttention(self.num_heads, self.hidden_size,
-                            dtype=self.dtype, axis_name=self.axis_name,
-                            causal=self.causal, use_flash=self.use_flash,
-                            sp_axis=self.sp_axis, sp_impl=self.sp_impl,
-                            decode=self.decode, cache_len=self.cache_len,
-                            kv_cache_int8=self.kv_cache_int8,
-                            name="attention")(
-                                nn.LayerNorm(dtype=self.dtype,
-                                             name="ln_attn")(x), mask,
-                                pos=pos)
+        with scope("block.norm"):
+            h = nn.LayerNorm(dtype=self.dtype, name="ln_attn")(x)
+        with scope("attn.full"):
+            a = TPSelfAttention(self.num_heads, self.hidden_size,
+                                dtype=self.dtype, axis_name=self.axis_name,
+                                causal=self.causal, use_flash=self.use_flash,
+                                sp_axis=self.sp_axis, sp_impl=self.sp_impl,
+                                decode=self.decode, cache_len=self.cache_len,
+                                kv_cache_int8=self.kv_cache_int8,
+                                name="attention")(h, mask, pos=pos)
         x = x + a
-        h = TPMlp(self.intermediate_size, self.hidden_size, dtype=self.dtype,
-                  axis_name=self.axis_name, name="mlp")(
-                      nn.LayerNorm(dtype=self.dtype, name="ln_mlp")(x))
+        with scope("block.norm"):
+            h = nn.LayerNorm(dtype=self.dtype, name="ln_mlp")(x)
+        with scope("mlp.dense"):
+            h = TPMlp(self.intermediate_size, self.hidden_size,
+                      dtype=self.dtype, axis_name=self.axis_name,
+                      name="mlp")(h)
         return x + h

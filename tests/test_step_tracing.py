@@ -545,3 +545,285 @@ class TestBenchmarkReading:
                 window.busy_s(), rel=1e-4)
         assert len(trace.host_spans("shard_batch")) \
             == len(expected["host_spans"])
+
+
+# --- the scope list over the four models (trace/scopes.py) ---------------
+
+MODELS = ("gpt", "smallthinker", "nemotron_h", "afmoe")
+SCOPE_LIKE = re.compile(r"^(lm|attn|ssm|moe|mlp|block|hvd)\.[a-z_]+$")
+WRAPPED = re.compile(r"^(?:[A-Za-z_]+\()*([^()]*)\)*$")
+LOC = re.compile(r'loc\("([^"]*)"')
+# the scopes a model's step must carry, forward and backward, beside the
+# three phases: the new ones of this list and the containers round them
+MODEL_SCOPES = {
+    "gpt": ("lm.model", "lm.embed", "lm.head", "block.norm", "attn.full",
+            "attn.qkv", "attn.core", "attn.out", "mlp.dense"),
+    "smallthinker": ("lm.model", "lm.embed", "lm.head", "block.norm",
+                     "attn.full", "attn.window", "attn.qkv", "attn.rope",
+                     "attn.core", "attn.out", "moe.route", "moe.experts"),
+    "nemotron_h": ("lm.model", "lm.embed", "lm.head", "block.norm",
+                   "attn.full", "attn.qkv", "attn.core", "attn.out",
+                   "ssm.mixer", "ssm.in_proj", "ssm.conv", "ssm.scan",
+                   "ssm.gate_norm", "ssm.out_proj", "moe.shared"),
+    "afmoe": ("lm.model", "lm.embed", "lm.head", "block.norm",
+              "block.post_norm", "mlp.dense", "attn.full", "attn.window",
+              "attn.qkv", "attn.qk_norm", "attn.rope", "attn.core",
+              "attn.gate", "attn.out", "moe.shared"),
+}
+
+
+def _tiny(which):
+    """A tiny instance of one of the four models, flash kernels on (the
+    Pallas interpreter on the CPU), as the cells build them."""
+    if which == "gpt":
+        from horovod_tpu.models.gpt import GPT, GPTConfig
+        return GPT(GPTConfig.tiny(tp_axis=None, ep_axis=None,
+                                  use_flash=True))
+    if which == "smallthinker":
+        from horovod_tpu.models.smallthinker import (SmallThinker,
+                                                     SmallThinkerConfig)
+        return SmallThinker(SmallThinkerConfig.tiny(use_flash=True))
+    if which == "nemotron_h":
+        from horovod_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+        return NemotronH(NemotronHConfig.tiny(use_flash=True))
+    from horovod_tpu.models.afmoe import Afmoe, AfmoeConfig
+    return Afmoe(AfmoeConfig.tiny(use_flash=True))
+
+
+_lowerings = {}
+
+
+def _lowered_step(which, scoped=True):
+    """(StableHLO text without locations, the ``loc("...")`` paths) of the
+    model's ``make_train_step`` lowered over one CPU device; with
+    ``scoped=False`` ``jax.named_scope`` is a no-op meanwhile."""
+    import contextlib
+    from unittest import mock
+    from horovod_tpu.optim import DistributedOptimizer
+    from horovod_tpu.parallel import TrainState, make_train_step, moe
+    if (which, scoped) in _lowerings:
+        return _lowerings[which, scoped]
+    model = _tiny(which)
+    ids = jnp.zeros((2, 64), jnp.int32)
+
+    def loss_fn(params, batch):
+        logits = model.apply({"params": params}, batch["ids"])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits[:, :-1].astype(jnp.float32), batch["ids"][:, 1:]).mean()
+
+    opt = DistributedOptimizer(optax.adamw(1e-3))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("hvd",))
+    patch = contextlib.nullcontext() if scoped else mock.patch.object(
+        jax, "named_scope", lambda name: contextlib.nullcontext())
+    # the two jitted conditionals of the expert layer keep their traces
+    for fn in (moe._forward_where_they_fit, moe._backward_where_they_fit):
+        fn.clear_cache()
+    with patch:
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                ids[:1])["params"]
+        state = jax.eval_shape(lambda p: TrainState.create(p, opt), params)
+        low = make_train_step(loss_fn, opt, mesh, donate=False).lower(
+            state, {"ids": ids})
+        # op_name paths alone: a location may also name a source file
+        out = low.as_text(), [
+            p for p in LOC.findall(low.as_text(debug_info=True))
+            if p.startswith("jit(hvd_dp_step)")]
+    for fn in (moe._forward_where_they_fit, moe._backward_where_they_fit):
+        fn.clear_cache()
+    _lowerings[which, scoped] = out
+    return out
+
+
+def _parts(path):
+    return [WRAPPED.sub(r"\1", p) for p in path.split("/")]
+
+
+class TestScopeList:
+    def test_the_list_is_sound(self):
+        from horovod_tpu.trace import scopes
+        names = [s.name for s in scopes.SCOPES]
+        assert len(names) == len(set(names))
+        for s in scopes.SCOPES:
+            assert s.kind in ("leaf", "container", "rule"), s
+            assert s.layer and s.holds
+            assert (s.kind == "rule") == (s.under is not None), s
+            if s.under is not None:
+                assert scopes.KINDS[s.under] == "container"
+        # a name outside the list, and a rule, cannot be entered
+        for bad in ("attn.typo", "lm.loss"):
+            with pytest.raises(ValueError, match="not a scope"):
+                scopes.scope(bad)
+        # none of the new names shadows a phase of harness/scopes.py
+        from benchmark.harness import scopes as bench_scopes
+        assert bench_scopes.phase_of(
+            STEP + "hvd.loss_and_grad/jvp(GPT)/lm.model/layer_0/attn.full/"
+            "attention/attn.qkv/qkv/dot_general") == "forward"
+
+    @pytest.mark.parametrize("which", MODELS)
+    def test_every_scope_is_on_an_op_forward_and_backward(self, which):
+        _, paths = _lowered_step(which)
+        for name in MODEL_SCOPES[which]:
+            mine = [p for p in paths if name in _parts(p)]
+            assert any("transpose(" not in p for p in mine), name
+            assert any("transpose(" in p for p in mine), name
+        # a scope round a custom_vjp site is on its backward rule's ops,
+        # and one round an nn.remat site on the recomputed ones
+        # (the flash kernels' backward rule, whose ops JAX marks
+        # ``transpose(hvd.loss_and_grad)/jvp(<model>)/...``)
+        bwd = [p for p in paths if "attention._attend" in _parts(p)
+               and "transpose(hvd.loss_and_grad)" in p]
+        assert bwd and all("attn.core" in _parts(p) for p in bwd)
+        if which != "gpt":
+            again = [p for p in paths if "checkpoint" in _parts(p)]
+            assert again and all("lm.model" in _parts(p) for p in again)
+
+    @pytest.mark.parametrize("which", MODELS)
+    def test_every_scope_like_name_in_the_hlo_is_listed(self, which):
+        from horovod_tpu.trace import scopes
+        _, paths = _lowered_step(which)
+        met = {p for path in paths for p in _parts(path)
+               if SCOPE_LIKE.match(p)}
+        assert met and met <= set(scopes.KINDS), met - set(scopes.KINDS)
+        assert not any(scopes.KINDS[m] == "rule" for m in met)
+        # what the rule calls the loss is there: under the phase, outside
+        # the model
+        assert any("hvd.loss_and_grad" in _parts(p)
+                   and "lm.model" not in _parts(p) for p in paths)
+
+    @pytest.mark.parametrize("which", MODELS)
+    def test_scopes_leave_the_lowered_step_as_it_was(self, which):
+        scoped, _ = _lowered_step(which)
+        bare, paths = _lowered_step(which, scoped=False)
+        assert not any(SCOPE_LIKE.match(p) for path in paths
+                       for p in _parts(path))
+        assert scoped == bare
+
+    def test_no_literal_scope_outside_the_list_and_no_metric_beside_it(self):
+        import json
+        from horovod_tpu.trace import scopes
+        literal = re.compile(r'named_scope\(\s*"')
+        for dirpath, _, files in os.walk(os.path.join(ROOT, "horovod_tpu")):
+            for f in files:
+                if f.endswith(".py"):
+                    with open(os.path.join(dirpath, f)) as fh:
+                        assert not literal.search(fh.read()), f
+        read = set()
+        metrics_dir = os.path.join(ROOT, "benchmark", "metrics")
+        for f in sorted(os.listdir(metrics_dir)):
+            if not f.endswith(".json"):
+                continue
+            with open(os.path.join(metrics_dir, f)) as fh:
+                args = json.load(fh).get("args", {})
+            named = list(args.get("scopes", ()))
+            if "scope_account" in f or args.get("part") in ("loss",):
+                named.append("lm.loss")
+            for name in named:
+                assert name in scopes.KINDS, (f, name)
+            read.update(named)
+        # the leaves this PR's metrics are for
+        assert {"lm.head", "lm.loss", "attn.qkv", "attn.out", "attn.rope",
+                "attn.core", "ssm.in_proj", "ssm.gate_norm",
+                "ssm.out_proj"} <= read
+
+
+class TestCompileSpans:
+    @pytest.fixture(autouse=True)
+    def fresh_store(self):
+        from horovod_tpu import trace
+        trace.reset()
+        yield
+        trace.reset()
+
+    @staticmethod
+    def _compile_spans():
+        from horovod_tpu import trace
+        return [(s["name"], s["args"]["fun"])
+                for s in trace.get(trace.run_tid())["spans"]
+                if s["name"] in ("compile.trace", "compile.lower",
+                                 "compile.backend")
+                and s["args"]["fun"] == "hvd_dp_step"]
+
+    def test_a_compiled_step_leaves_its_three_stages(self, hvd, mesh4):
+        """``hvd.init`` installed the listener (tracing is armed in the
+        tests): the step's first compile leaves one span a stage, a second
+        call of the same shapes none, a new shape a second set."""
+        from horovod_tpu.optim import DistributedOptimizer
+        from horovod_tpu.parallel import TrainState, make_train_step
+        opt = DistributedOptimizer(optax.adamw(1e-3))
+        state = jax.device_put(TrainState.create(_params(), opt),
+                               NamedSharding(mesh4, P()))
+        step = make_train_step(_loss_fn, opt, mesh4, donate=False)
+        batch = {"x": jnp.ones((8, 8), jnp.float32)}
+        step(state, batch)
+        stages = [("compile.trace", "hvd_dp_step"),
+                  ("compile.lower", "hvd_dp_step"),
+                  ("compile.backend", "hvd_dp_step")]
+        assert self._compile_spans() == stages
+        step(state, batch)
+        assert self._compile_spans() == stages
+        step(state, {"x": jnp.ones((16, 8), jnp.float32)})
+        assert self._compile_spans() == stages * 2
+        from horovod_tpu import trace
+        spans = [s for s in trace.get(trace.run_tid())["spans"]
+                 if s["name"].startswith("compile.")]
+        assert all(s["dur"] >= 1e-3 for s in spans
+                   if s["name"] != "compile.cache_load")
+        assert len(spans) < 64           # the small ones were dropped
+
+    def test_cache_load_is_the_child_of_its_backend_span(self):
+        from horovod_tpu import trace
+        from horovod_tpu.metrics import instruments
+        instruments._on_compile_duration(instruments._CACHE_LOAD_EVENT, 0.25)
+        instruments._on_compile_span(
+            "/jax/core/compile/backend_compile_duration", 100.0, 100.75,
+            fun_name="jit(hvd_dp_step)")
+        instruments._on_compile_span(       # a fresh compile: no child
+            "/jax/core/compile/backend_compile_duration", 101.0, 103.0,
+            fun_name="other")
+        instruments._on_compile_span(       # under a millisecond: dropped
+            "/jax/core/compile/jaxpr_trace_duration", 104.0, 104.0005,
+            fun_name="tiny")
+        instruments._on_compile_span("/jax/core/some/other", 0.0, 9.0)
+        tree = trace.tree(trace.run_tid())
+        first, second = tree["children"]
+        assert (first["name"], first["args"]) \
+            == ("compile.backend", {"fun": "hvd_dp_step"})
+        child, = first["children"]
+        assert (child["name"], child["args"]) \
+            == ("compile.cache_load", {"fun": "hvd_dp_step"})
+        assert child["t0"] + child["dur"] == pytest.approx(100.75)
+        assert child["dur"] == pytest.approx(0.25)
+        assert second["args"] == {"fun": "other"} \
+            and "children" not in second
+
+    def test_disarmed_installs_nothing_and_leaves_no_span(self):
+        code = ("import os\n"
+                "import jax, jax.numpy as jnp\n"
+                "from jax._src import monitoring\n"
+                "import horovod_tpu as hvd\n"
+                "from horovod_tpu import trace\n"
+                "hvd.init()\n"
+                "assert not trace.armed and trace.run_tid() is None\n"
+                "assert monitoring.get_event_time_span_listeners() == []\n"
+                "assert monitoring.get_event_duration_listeners() == []\n"
+                "assert len(monitoring.get_event_listeners()) == 1\n"
+                "jax.jit(lambda x: x * 2)(jnp.ones((4,)))\n"
+                "assert trace.snapshot()['traces'] == []\n"
+                "print('ok')\n")
+        env = dict(os.environ, PYTHONPATH=ROOT, HOROVOD_TRACE="0",
+                   JAX_PLATFORMS="cpu")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=240)
+        assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+    def test_one_listener_site_in_the_package(self):
+        hits = []
+        for dirpath, _, files in os.walk(os.path.join(ROOT, "horovod_tpu")):
+            for f in files:
+                if f.endswith(".py"):
+                    path = os.path.join(dirpath, f)
+                    with open(path) as fh:
+                        if re.search(r"register_event\w*_listener\(",
+                                     fh.read()):
+                            hits.append(os.path.relpath(path, ROOT))
+        assert hits == ["horovod_tpu/metrics/instruments.py"]
