@@ -387,6 +387,16 @@ ATTN_LAYER = REGISTRY.gauge(
     "window (0: every earlier key), normed and gated (1 or 0). Set while "
     "the call is traced; a layer with neither records nothing.",
     ("kind",))
+ATTN_PROLOGUE_LAYERS = REGISTRY.gauge(
+    "hvd_attn_prologue_layers",
+    "TPSelfAttention layers, by the path their last traced call took from "
+    "the fused qkv rows to attention (parallel.tp.prologue_path): 1 = one "
+    "Pallas pass of ops/pallas/attn_prologue.py (the heads' RMS norm, the "
+    "rotation and the move into the flash kernels' layout, forward and "
+    "backward), 0 = the split, nn.RMSNorm, apply_rope and flash's own "
+    "layout change, or no flash at all. Layers are told apart by module "
+    "path, so a second trace of a model counts nothing twice.",
+    ("path",))
 SSM_LAYER = REGISTRY.gauge(
     "hvd_ssm_layer",
     "Sizes of the last traced Mamba2Mixer call, by kind: heads, head_dim, "
@@ -860,6 +870,23 @@ def record_attn_layer(heads, kv_heads, head_dim, window, normed, gated):
                     ("head_dim", head_dim), ("window", window),
                     ("normed", int(normed)), ("gated", int(gated))):
         ATTN_LAYER.labels(kind).set(n)
+
+
+# module path of a TPSelfAttention layer -> the path its last traced call
+# took (hvd_attn_prologue_layers)
+_attn_prologue_layers = {}
+
+
+def record_attn_prologue(path, layer):
+    """The path (1 or 0) one traced call of the ``TPSelfAttention`` at
+    module path ``layer`` took (``parallel.tp.prologue_path``): known while
+    the call is traced."""
+    if not _enabled:
+        return
+    _attn_prologue_layers[layer] = path
+    for p in (0, 1):
+        ATTN_PROLOGUE_LAYERS.labels(p).set(
+            sum(v == p for v in _attn_prologue_layers.values()))
 
 
 def record_ssm_layer(heads, head_dim, state, groups, chunk, chunks,

@@ -1215,12 +1215,18 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
             t3 = jnp.pad(t3, ((0, 0), (0, pad), (0, 0)))
         return t3
 
-    def from3(t):
-        return jnp.moveaxis(t[:, :lq].reshape(b, h, lq, d), 1, 2)
-
     # kv != h: grouped-query — the kernels stream the NARROW k/v (1/g the
     # HBM traffic); no broadcast is materialized on the forward path.
     out = _flash(to3(q, pad_q), to3(k, pad_k), to3(v, pad_k), causal,
                  sm_scale, q_offset=lk - lq, kv_valid=lk, heads=h,
                  kv_heads=kv, window=window)
-    return from3(out)
+    return heads_last(out, b, lq)
+
+
+def heads_last(o3, batch, length):
+    """The kernels' output (B*H, L', D), each sequence's heads contiguous,
+    as (B, length, H, D): its first ``length`` positions, heads after
+    them (``ops/pallas/attn_prologue.py`` shares it)."""
+    bh, _, d = o3.shape
+    return jnp.moveaxis(o3[:, :length].reshape(batch, bh // batch, length,
+                                               d), 1, 2)

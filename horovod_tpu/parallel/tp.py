@@ -167,6 +167,32 @@ def apply_rope(x, positions, theta):
     return out.astype(x.dtype)
 
 
+def prologue_path(qkv_shape, heads, kv_heads, head_dim, itemsize, *,
+                  flash, normed, rotated, varying=False):
+    """``(path, tile)``: how a full-sequence call goes from its fused qkv
+    rows ``qkv_shape`` (B, L, (heads + 2 kv_heads) head_dim) to attention,
+    read off the call alone. Path 1, the one pass of
+    ``ops/pallas/attn_prologue.py`` (norm, rotation and the move into the
+    flash kernels' layout; ``tile`` its rows a grid step), where the call
+    takes the flash kernels with nothing between (``flash``: flash, no
+    mask, no sp axis, not decoding), the layer norms its heads or rotates
+    them, the length needs no padding and the kernels admit the shapes
+    (``row_tile``: a head whole lane tiles). Path 0 (tile 0), the split,
+    ``nn.RMSNorm``, :func:`apply_rope` and the kernels' own layout change,
+    for every other call; also for operands that vary over a mesh axis
+    under the Pallas interpreter, as in :func:`flash_attention`."""
+    from horovod_tpu.ops.pallas import attn_prologue
+    from horovod_tpu.ops.pallas.flash_attention import (_interpret,
+                                                        _pick_block)
+    length = qkv_shape[1]
+    tile = attn_prologue.row_tile(length, heads, kv_heads, head_dim,
+                                  itemsize)
+    if not (flash and (normed or rotated) and tile and _pick_block(length)) \
+            or (varying and _interpret()):
+        return 0, 0
+    return 1, tile
+
+
 def plain_attention(q, k, v, out_dtype, mask=None, bias=None, causal=False,
                     window=None):
     """The ONE plain-XLA attend kernel (scaled scores, optional additive
@@ -190,6 +216,18 @@ def plain_attention(q, k, v, out_dtype, mask=None, bias=None, causal=False,
                            jnp.asarray(-1e9, scores.dtype))
     probs = nn.softmax(scores.astype(jnp.float32)).astype(out_dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+class HeadScale(nn.Module):
+    """The learned scale of an ``nn.RMSNorm`` over a head, alone and under
+    the same name (``<name>/scale``: ``features`` float32 ones), for the
+    prologue pass that applies it."""
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.features,),
+                          jnp.float32)
 
 
 class TPSelfAttention(nn.Module):
@@ -221,7 +259,11 @@ class TPSelfAttention(nn.Module):
     ``attn.qk_norm`` and ``attn.gate``; with ``decode=True`` or an
     ``sp_axis`` they raise. The rest of a call lies under the leaf scopes
     ``attn.qkv``, ``attn.rope``, ``attn.core`` (``_attend`` and the
-    heads' merge) and ``attn.out`` (``trace/scopes.py``).
+    heads' merge) and ``attn.out`` (``trace/scopes.py``). Where
+    :func:`prologue_path` admits the call, the split, the norm, the
+    rotation and the flash kernels' layout change are one Pallas pass each
+    way (``ops/pallas/attn_prologue.py``) under the scope of the first of
+    them it does, ``attn.qk_norm`` or ``attn.rope``.
     """
     num_heads: int
     hidden_size: int
@@ -359,6 +401,12 @@ class TPSelfAttention(nn.Module):
         out = jnp.einsum("bngqk,bknd->bqngd", probs, vals)
         return out.reshape(B, s, h, d)
 
+    def _check_window(self):
+        if self.window is not None and (self.sp_axis is not None
+                                        or not self.causal):
+            raise ValueError("a sliding window needs causal=True and no "
+                             "sp_axis")
+
     def _attend(self, q, k, v, mask, bias=None):
         """Route full-sequence attention: sp ring/Ulysses, Pallas flash,
         or plain XLA. ``k``/``v`` may carry FEWER (grouped) heads than
@@ -374,6 +422,7 @@ class TPSelfAttention(nn.Module):
             raise ValueError(
                 "additive attention bias is supported on the plain XLA "
                 "path only (not flash/sp)")
+        self._check_window()
         g = q.shape[2] // k.shape[2]
         if g > 1 and self.sp_axis is None and not (self.use_flash
                                                    and mask is None):
@@ -383,10 +432,6 @@ class TPSelfAttention(nn.Module):
             # broadcasting — if at all — on the far side of the exchange.
             k = jnp.repeat(k, g, axis=2)
             v = jnp.repeat(v, g, axis=2)
-        if self.window is not None and (self.sp_axis is not None
-                                        or not self.causal):
-            raise ValueError("a sliding window needs causal=True and no "
-                             "sp_axis")
         if self.sp_axis is not None:
             # Sequence parallelism: x carries this chip's token shard; the
             # QKV/out projections are token-local, the attention itself
@@ -455,62 +500,100 @@ class TPSelfAttention(nn.Module):
                 (self.num_heads + 2 * kv_heads) * head_dim, dtype=self.dtype,
                 use_bias=self.use_bias, axis_name=self.axis_name,
                 name="qkv")(x)
-            q, k, v = jnp.split(
-                qkv, [local_heads * head_dim,
-                      (local_heads + local_kv) * head_dim], axis=-1)
-            q, k, v = heads(q), heads(k), heads(v)
-        if normed:
-            with scope("attn.qk_norm"):
-                q = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
-                               name="q_norm")(q)
-                k = nn.RMSNorm(epsilon=self.qk_norm_eps, dtype=self.dtype,
-                               name="k_norm")(k)
-        if self.decode:
-            if self.sp_axis is not None or mask is not None \
-                    or self.window is not None:
-                raise ValueError(
-                    "decode mode supports neither sp_axis, masks nor a "
-                    "sliding window")
-            if bias is not None and x.shape[1] != 1:
-                raise ValueError(
-                    f"decode with an attention bias (T5 relative "
-                    f"positions) feeds ONE token per call, got "
-                    f"{x.shape[1]}")
-            if self.cache_len < 1:
-                raise ValueError("decode=True requires cache_len >= 1")
-            # RoPE + grouped KV handled inside; bias is this step's
-            # relative-position row over the cache; a (B,) pos vector
-            # switches to explicit per-row (continuous-batching) cursors
-            out = self._decode_attend(q, k, v, bias=bias, pos=pos)
+        from horovod_tpu.ops.pallas.flash_attention import _vma
+        path, tile = prologue_path(
+            qkv.shape, local_heads, local_kv, head_dim, qkv.dtype.itemsize,
+            flash=(self.use_flash and mask is None and bias is None
+                   and self.sp_axis is None and not self.decode),
+            normed=normed, rotated=self.rope_theta is not None,
+            varying=bool(_vma(qkv)))
+        from horovod_tpu.metrics import instruments as hvd_metrics
+        hvd_metrics.record_attn_prologue(path, "/".join(self.path))
+        if path:
+            from horovod_tpu.ops.pallas.attn_prologue import attention
+            from horovod_tpu.ops.pallas.flash_attention import heads_last
+            self._check_window()
+            scales = (HeadScale(head_dim, name="q_norm")(),
+                      HeadScale(head_dim, name="k_norm")()) if normed \
+                else (None, None)
+            # One pass from the rows to the kernels' operands, under the
+            # scope of the first thing it does, then the kernels under
+            # attn.core: their (B H, L, D) output (ops/pallas/attn_prologue)
+            out = attention(qkv, *scales, local_heads, local_kv,
+                            self.qk_norm_eps, self.rope_theta, tile,
+                            self.causal, self.window)
         else:
-            if self.rope_theta is not None:
-                # Global token positions: under sequence parallelism x holds
-                # this chip's contiguous token shard (same offset math as
-                # GPTEmbed's sp path); otherwise positions are 0..L-1.
-                L = x.shape[-2]
-                off = 0
-                if (self.sp_axis is not None
-                        and axis_size_or_1(self.sp_axis) > 1):
-                    off = lax.axis_index(self.sp_axis) * L
-                with scope("attn.rope"):
-                    positions = off + jnp.arange(L, dtype=jnp.int32)
-                    q = apply_rope(q, positions, self.rope_theta)
-                    k = apply_rope(k, positions, self.rope_theta)
-            # Grouped kv heads stay NARROW here: _attend broadcasts them
-            # for the paths that need MHA shapes and streams them natively
-            # through the flash kernels. (Decode above instead contracts
-            # grouped q heads against the narrow cache.)
-            with scope("attn.core"):
-                out = self._attend(q, k, v, mask, bias=bias)
-        with scope("attn.core"):      # the heads' merge belongs to it too
-            out = out.reshape(out.shape[:-2] + (local_heads * head_dim,))
+            with scope("attn.qkv"):
+                q, k, v = jnp.split(
+                    qkv, [local_heads * head_dim,
+                          (local_heads + local_kv) * head_dim], axis=-1)
+                q, k, v = heads(q), heads(k), heads(v)
+            if normed:
+                with scope("attn.qk_norm"):
+                    q = nn.RMSNorm(epsilon=self.qk_norm_eps,
+                                   dtype=self.dtype, name="q_norm")(q)
+                    k = nn.RMSNorm(epsilon=self.qk_norm_eps,
+                                   dtype=self.dtype, name="k_norm")(k)
+            if self.decode:
+                if self.sp_axis is not None or mask is not None \
+                        or self.window is not None:
+                    raise ValueError(
+                        "decode mode supports neither sp_axis, masks nor a "
+                        "sliding window")
+                if bias is not None and x.shape[1] != 1:
+                    raise ValueError(
+                        f"decode with an attention bias (T5 relative "
+                        f"positions) feeds ONE token per call, got "
+                        f"{x.shape[1]}")
+                if self.cache_len < 1:
+                    raise ValueError("decode=True requires cache_len >= 1")
+                # RoPE + grouped KV handled inside; bias is this step's
+                # relative-position row over the cache; a (B,) pos vector
+                # switches to explicit per-row (continuous-batching) cursors
+                out = self._decode_attend(q, k, v, bias=bias, pos=pos)
+            else:
+                if self.rope_theta is not None:
+                    # Global token positions: under sequence parallelism x
+                    # holds this chip's contiguous token shard (same offset
+                    # math as GPTEmbed's sp path); otherwise 0..L-1.
+                    L = x.shape[-2]
+                    off = 0
+                    if (self.sp_axis is not None
+                            and axis_size_or_1(self.sp_axis) > 1):
+                        off = lax.axis_index(self.sp_axis) * L
+                    with scope("attn.rope"):
+                        positions = off + jnp.arange(L, dtype=jnp.int32)
+                        q = apply_rope(q, positions, self.rope_theta)
+                        k = apply_rope(k, positions, self.rope_theta)
+                # Grouped kv heads stay NARROW here: _attend broadcasts
+                # them for the paths that need MHA shapes and streams them
+                # natively through the flash kernels. (Decode above instead
+                # contracts grouped q heads against the narrow cache.)
+                with scope("attn.core"):
+                    out = self._attend(q, k, v, mask, bias=bias)
+
+        def merge(out, gate=None):
+            with scope("attn.core"):      # the heads' merge belongs to it
+                if path:
+                    out = heads_last(out, x.shape[0], x.shape[1])
+                out = out.reshape(out.shape[:-2] + (local_heads * head_dim,))
+            if gate is None:
+                return out
+            with scope("attn.gate"):
+                return out * nn.sigmoid(gate)
+
         if self.gated:
             with scope("attn.gate"):
                 gate = ColumnParallelDense(
                     self.num_heads * head_dim, dtype=self.dtype,
                     use_bias=self.use_bias, axis_name=self.axis_name,
                     name="gate")(x)
-                out = out * nn.sigmoid(gate)
+            # On path 1 the backward pass merges the heads again from the
+            # kernels' output, which the pass keeps, where the product
+            # would keep the merged copy (PERF.md, PR 38).
+            out = (jax.checkpoint(merge) if path else merge)(out, gate)
+        else:
+            out = merge(out)
         with scope("attn.out"):
             return RowParallelDense(self.hidden_size, dtype=self.dtype,
                                     use_bias=self.use_bias,

@@ -181,3 +181,90 @@ def test_the_scans_three_kernels_compile_for_v5e(one_chip, monkeypatch):
     for kernel in ("hvd_ssm_fwd", "hvd_ssm_states", "hvd_ssm_bwd"):
         assert f"{kernel}_128x512x128" in hlo, \
             f"{kernel} is not in the compiled program"
+
+
+# -- attention's prologue pass (PR 38) ----------------------------------------
+
+# (batch, length, heads, kv heads, normed, window or None for no rotation):
+# the calls of the two cells whose layers take the pass, at their real
+# shapes
+_PROLOGUE_CALLS = {
+    "trinity_mini_window_layer": (2, 8192, 32, 4, True, 2048),
+    "trinity_mini_full_layer": (2, 8192, 32, 4, True, None),
+    "smallthinker_window_layer": (2, 8192, 28, 4, False, 4096),
+}
+
+
+@pytest.mark.parametrize("call", sorted(_PROLOGUE_CALLS))
+def test_the_prologue_kernels_compile_for_v5e(one_chip, fa, monkeypatch,
+                                              call):
+    """``ops/pallas/attn_prologue.py``'s ``attention`` forward and backward
+    (its two kernels around the flash kernels) at the calls of
+    ``trinity_mini_ep16_8k_1chip`` (32 query heads on 4, the norm with and
+    without the rotation) and ``smallthinker_ep4_8k_1chip`` (28 on 4, the
+    rotation alone) lowers and compiles for a v5e, in the row tile the rule
+    picks (256). A slice off the (8, 128) tiling or too much scoped VMEM
+    fails here and not on the chip."""
+    from horovod_tpu.ops.pallas import attn_prologue as kernels
+    from horovod_tpu.parallel import tp
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    b, length, heads, kv, normed, window = _PROLOGUE_CALLS[call]
+    d = 128
+    path, tile = tp.prologue_path((b, length, (heads + 2 * kv) * d), heads,
+                                  kv, d, 2, flash=True, normed=normed,
+                                  rotated=window is not None)
+    assert (path, tile) == (1, 256)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def forward_and_backward(qkv, q_scale, k_scale, do):
+        out, pull = jax.vjp(
+            lambda *a: kernels.attention(*a, heads, kv,
+                                         1e-5 if normed else None,
+                                         1e4 if window else None, tile,
+                                         True, window),
+            qkv, q_scale, k_scale)
+        return out, pull(do)
+    scale = shape((d,), jnp.float32)
+    hlo = jax.jit(forward_and_backward).lower(
+        shape((b, length, (heads + 2 * kv) * d)), scale, scale,
+        shape((b * heads, length, d))).compile().as_text()
+    for kernel in ("hvd_attn_prologue_fwd", "hvd_attn_prologue_bwd",
+                   "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert kernel in hlo, f"{kernel} is not in the compiled program"
+
+
+@pytest.mark.parametrize("cell,taken", [
+    ("gpt2m_1chip", False), ("nemotron_tt_ep16_8k_1chip", False),
+    ("trinity_mini_ep16_8k_1chip", True),
+    ("smallthinker_ep4_8k_1chip", True)])
+def test_which_cells_steps_hold_the_prologue(one_chip, fa, monkeypatch, cell,
+                                             taken):
+    """The loss and gradient of each cell's model, lowered at its real size
+    for a v5e: ``gpt2_medium`` (heads of 64, no norm, no positions) and
+    ``nemotron_twotower_30b_a3b_ep16`` (no norm, no positions) hold no
+    prologue kernel, so their steps are the parent's; the two models whose
+    layers norm or rotate heads of 128 hold both."""
+    import os
+    from benchmark import run as bench
+    from benchmark.harness import manifest, program, reference, traffic
+    from horovod_tpu.ops.pallas import attn_prologue as kernels
+    from horovod_tpu.ops.pallas import grouped_matmul as gmm
+    from horovod_tpu.ops.pallas import ssm_scan
+    for mod in (kernels, gmm, ssm_scan):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    _, workload, cfg = bench.load_cell(manifest.load(root), cell, False)
+    model, loss_fn = program.load_model_builder(cfg["model"])(cfg)
+    batch = traffic.Batches(cfg, workload, 7).next()
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(tuple(s), jnp.float32,
+                                       sharding=one_chip),
+        reference.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    batch = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), batch)
+    text = jax.jit(jax.grad(loss_fn)).lower(params, batch).as_text()
+    assert "hvd_flash_fwd" in text
+    for kernel in ("hvd_attn_prologue_fwd", "hvd_attn_prologue_bwd"):
+        assert (kernel in text) == taken, kernel
