@@ -384,8 +384,10 @@ ATTN_LAYER = REGISTRY.gauge(
     "hvd_attn_layer",
     "Sizes of the last traced TPSelfAttention call that norms its query "
     "and key heads or gates its output, by kind: heads, kv_heads, head_dim, "
-    "window (0: every earlier key), normed and gated (1 or 0). Set while "
-    "the call is traced; a layer with neither records nothing.",
+    "window (0: every earlier key), normed and gated (1 or 0); of a "
+    "TPLatentAttention call: heads, qk_head_dim, v_head_dim, q_lora_rank, "
+    "kv_lora_rank and rope_head_dim. Set while the call is traced; a "
+    "TPSelfAttention layer with neither norm nor gate records nothing.",
     ("kind",))
 ATTN_PROLOGUE_LAYERS = REGISTRY.gauge(
     "hvd_attn_prologue_layers",
@@ -869,6 +871,20 @@ def record_attn_layer(heads, kv_heads, head_dim, window, normed, gated):
     for kind, n in (("heads", heads), ("kv_heads", kv_heads),
                     ("head_dim", head_dim), ("window", window),
                     ("normed", int(normed)), ("gated", int(gated))):
+        ATTN_LAYER.labels(kind).set(n)
+
+
+def record_latent_attn_layer(heads, qk_head_dim, v_head_dim, q_lora_rank,
+                             kv_lora_rank, rope_head_dim):
+    """The widths of one trace of a ``parallel.mla.TPLatentAttention``
+    call: known while the call is traced, so set there once and not per
+    step."""
+    if not _enabled:
+        return
+    for kind, n in (("heads", heads), ("qk_head_dim", qk_head_dim),
+                    ("v_head_dim", v_head_dim), ("q_lora_rank", q_lora_rank),
+                    ("kv_lora_rank", kv_lora_rank),
+                    ("rope_head_dim", rope_head_dim)):
         ATTN_LAYER.labels(kind).set(n)
 
 

@@ -20,6 +20,11 @@ operates below the model level); this kernel is part of the TPU build's
 model-level capability, in the spirit of the reference's hand-written CUDA
 hot loops (reference: horovod/common/ops/cuda/cuda_kernels.cu).
 
+Queries and keys may be wider than values (latent attention: 192 against
+128): the score products contract over the query/key width, ``p v`` and
+the value gradients over the value width, and the output is as wide as the
+values. Nothing is padded to make the widths equal.
+
 Backward pass: custom VJP using the saved per-row logsumexp, fused as two
 Pallas kernels on TPU (a dQ pass tiled over query blocks and a dK/dV pass
 tiled over key blocks, each recomputing its score tile in VMEM) — O(L)
@@ -649,7 +654,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
 def _fa_forward(q, k, v, causal, sm_scale, block_q=None, block_k=None,
                 q_offset=None, kv_valid=None, heads=None, kv_heads=None,
                 window=None):
-    """(B*H, Lq, D) x (B*KV, Lk, D)^2 -> (o, lse).
+    """(B*H, Lq, Dqk) x (B*KV, Lk, Dqk) x (B*KV, Lk, Dv) -> (o, lse), o of
+    (B*H, Lq, Dv).
 
     ``block_q``/``block_k`` default to :func:`_pick_tiles`' choice for
     the forward kernel. ``q_offset``/``kv_valid`` override the end-aligned
@@ -737,7 +743,7 @@ _CALL_STATICS = ("causal", "sm_scale", "tiles", "block", "chunks",
 def _fwd_call(q, k, v, *, causal, sm_scale, tiles, block, chunks, q_offset,
               kv_valid, window, group, interpret):
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, d_v = k.shape[1], v.shape[2]
     q_chunk, k_chunk = chunks
     swept = functools.partial(_fetched, by_key=False, block=block,
                               chunk=k_chunk, n=lk, q_offset=q_offset,
@@ -768,18 +774,18 @@ def _fwd_call(q, k, v, *, causal, sm_scale, tiles, block, chunks, q_offset,
         in_specs=[
             pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, k_chunk, d), kv_map),
-            pl.BlockSpec((1, k_chunk, d), kv_map),
+            pl.BlockSpec((1, k_chunk, d_v), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, q_chunk, d_v), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, lq, d_v), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 1, lq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[_scratch((q_chunk, 1)), _scratch((q_chunk, 1)),
-                        _scratch((q_chunk, d))],
+                        _scratch((q_chunk, d_v))],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v)
@@ -998,7 +1004,7 @@ def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
 def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
               q_offset, kv_valid, window, interpret):
     bh, lq, d = q.shape
-    lk = k.shape[1]
+    lk, d_v = k.shape[1], v.shape[2]
     # The row statistics travel as (BH, 1, Lq) rows: see _fa_kernel.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]
@@ -1020,15 +1026,19 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
 
     # dQ: grid over query chunks; key chunks stream innermost.
     q_chunk, k_chunk = chunks[0]
-    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, i, 0))
+    # Each operand's block is its whole width: q and k Dqk, v and dO Dv.
+    q_blk, o_blk = (pl.BlockSpec((1, q_chunk, w), lambda b, i, j: (b, i, 0))
+                    for w in (d, d_v))
     r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i))
     keys = swept(False, k_chunk, lk)
-    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, keys(i, j), 0))
+    k_blk, v_blk = (pl.BlockSpec((1, k_chunk, w),
+                                 lambda b, i, j: (b, keys(i, j), 0))
+                    for w in (d, d_v))
     dq = pl.pallas_call(
         kernel(_fa_bwd_dq_kernel, tiles[0], chunks[0]),
         name="hvd_flash_bwd_dq",
         grid=(bh, lq // q_chunk, lk // k_chunk),
-        in_specs=[q_blk, k_blk, k_blk, q_blk, r_blk, r_blk],
+        in_specs=[q_blk, k_blk, v_blk, o_blk, r_blk, r_blk],
         out_specs=q_blk,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
         scratch_shapes=[_scratch((q_chunk, d))],
@@ -1038,18 +1048,21 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
     # dK/dV: grid over key chunks; query chunks stream innermost.
     q_chunk, k_chunk = chunks[1]
     rows = swept(True, q_chunk, lq)
-    q_blk = pl.BlockSpec((1, q_chunk, d), lambda b, i, j: (b, rows(i, j), 0))
+    q_blk, o_blk = (pl.BlockSpec((1, q_chunk, w),
+                                 lambda b, i, j: (b, rows(i, j), 0))
+                    for w in (d, d_v))
     r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, rows(i, j)))
-    k_blk = pl.BlockSpec((1, k_chunk, d), lambda b, i, j: (b, i, 0))
+    k_blk, v_blk = (pl.BlockSpec((1, k_chunk, w), lambda b, i, j: (b, i, 0))
+                    for w in (d, d_v))
     dk, dv = pl.pallas_call(
         kernel(_fa_bwd_dkv_kernel, tiles[1], chunks[1]),
         name="hvd_flash_bwd_dkv",
         grid=(bh, lk // k_chunk, lq // q_chunk),
-        in_specs=[q_blk, k_blk, k_blk, q_blk, r_blk, r_blk],
-        out_specs=[k_blk, k_blk],
+        in_specs=[q_blk, k_blk, v_blk, o_blk, r_blk, r_blk],
+        out_specs=[k_blk, v_blk],
         out_shape=[jax.ShapeDtypeStruct((bh, lk, d), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((bh, lk, d), v.dtype, vma=vma)],
-        scratch_shapes=[_scratch((k_chunk, d)), _scratch((k_chunk, d))],
+                   jax.ShapeDtypeStruct((bh, lk, d_v), v.dtype, vma=vma)],
+        scratch_shapes=[_scratch((k_chunk, d)), _scratch((k_chunk, d_v))],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -1163,7 +1176,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
     """Tiled attention over (B, L, H, D) tensors (the layout used throughout
-    this codebase, e.g. parallel/sequence.py).
+    this codebase, e.g. parallel/sequence.py). ``v`` may be narrower than
+    ``q`` and ``k`` (latent attention); the output has ``v``'s width, and
+    ``sm_scale`` defaults to 1/sqrt of ``q``'s.
 
     ``window`` (with ``causal``): a sliding window, query t sees the keys j
     with t - window < j <= t (the query counts among its window); tiles
@@ -1210,7 +1225,8 @@ def flash_attention(q, k, v, causal=False, sm_scale=None, window=None):
 
     def to3(t, pad):
         nh = t.shape[2]
-        t3 = jnp.moveaxis(t, 2, 1).reshape(t.shape[0] * nh, t.shape[1], d)
+        t3 = jnp.moveaxis(t, 2, 1).reshape(t.shape[0] * nh, t.shape[1],
+                                           t.shape[3])
         if pad:
             t3 = jnp.pad(t3, ((0, 0), (0, pad), (0, 0)))
         return t3

@@ -94,6 +94,18 @@ SCOPES = (
           "the gate's projection, its sigmoid and the product with the "
           "heads' output"),
     Scope("attn.out", "leaf", "attention", "the output projection"),
+    # latent attention (parallel/mla.py TPLatentAttention)
+    Scope("attn.q_latent", "leaf", "attention",
+          "the query's down product, its latent norm, the up product to "
+          "the heads and the rotation of their rotary part"),
+    Scope("attn.kv_latent", "leaf", "attention",
+          "the key-value latent's down product and norm, the up product "
+          "to the heads' keys and values, the shared rotary key's "
+          "rotation and the keys assembled"),
+    # multi-token prediction (models/joyai_flash.py)
+    Scope("mtp", "container", "compiled_dp_step",
+          "the multi-token-prediction module: its norms, the projection of "
+          "[embedding | hidden], its block and its pass through the head"),
     # the Mamba-2 mixer (parallel/ssm.py)
     Scope("ssm.mixer", "container", "state_space", "one Mamba2Mixer call"),
     Scope("ssm.in_proj", "leaf", "state_space",

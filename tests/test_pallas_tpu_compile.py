@@ -93,6 +93,27 @@ def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
         assert kernel in hlo, f"{kernel} is not in the compiled program"
 
 
+def test_latent_attention_kernels_compile_for_v5e(one_chip, fa):
+    """joyai_flash_ep32_8k_1chip's call: 2 x 8192 causal, 32 heads whose
+    queries and keys are 192 wide and values 128 (a full-width block, no
+    zero columns), by block kind; the output and dV at the value width."""
+    b, length, h, dqk, dv = 2, 8192, 32, 192, 128
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((b, length, h, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True)
+        assert out.shape == (b, length, h, dv)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(dqk), shape(dqk), shape(dv)).compile().as_text()
+    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+        assert kernel in hlo, f"{kernel} is not in the compiled program"
+
+
 # -- DroplessMoE's grouped products (PR 34) -----------------------------------
 
 # (tokens, experts, a token, held, hidden, expert width, form, kernels): the
