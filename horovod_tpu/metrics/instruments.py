@@ -67,8 +67,10 @@ Catalogue (names shown without the ``HOROVOD_METRICS_PREFIX``, default
   step, padding included (gauge, as above)
 - ``hvd_flash_tiles{kernel,kind}``                  score tiles per
   (batch, head) of the last traced flash-attention call
-  (kernel=fwd|bwd_dq|bwd_dkv; kind=total|visited|masked, masked = visited
-  with mask code, and blocks_inside|blocks_diagonal|blocks_edge|
+  (kernel=fwd|bwd_dqkv|bwd_dq|bwd_dkv: the backward is the one kernel
+  bwd_dqkv, or the pair bwd_dq + bwd_dkv for a call past its dQ's VMEM
+  budget; kind=total|visited|masked, masked = visited with mask code,
+  and blocks_inside|blocks_diagonal|blocks_edge|
   blocks_skipped: the 1024 x 1024 blocks of each kind where a long causal
   call runs its static schedule by block kind, 0 elsewhere; gauge, set
   while the call is traced)
@@ -331,9 +333,11 @@ FUSED_ALLREDUCE_BYTES = REGISTRY.gauge(
 FLASH_TILES = REGISTRY.gauge(
     "hvd_flash_tiles",
     "Score tiles per (batch, head) of the last traced flash-attention "
-    "call, by kernel (fwd|bwd_dq|bwd_dkv) and kind: total, visited (not "
-    "wholly masked) and masked (visited with mask code: the diagonal, "
-    "the window's or the padding edge crosses the tile); blocks_inside, "
+    "call, by kernel (fwd; bwd_dqkv, the one backward kernel, or the pair "
+    "bwd_dq + bwd_dkv for a call past its dQ's VMEM budget) and kind: "
+    "total, visited (not wholly masked) and masked (visited with mask "
+    "code: the diagonal, the window's or the padding edge crosses the "
+    "tile); blocks_inside, "
     "blocks_diagonal, blocks_edge, blocks_skipped: the 1024 x 1024 blocks "
     "of each kind where a causal call past 1024 runs the static schedule "
     "by block kind (0 on every other call). From the functions that give "

@@ -25,11 +25,15 @@ Queries and keys may be wider than values (latent attention: 192 against
 the value gradients over the value width, and the output is as wide as the
 values. Nothing is padded to make the widths equal.
 
-Backward pass: custom VJP using the saved per-row logsumexp, fused as two
-Pallas kernels on TPU (a dQ pass tiled over query blocks and a dK/dV pass
-tiled over key blocks, each recomputing its score tile in VMEM) — O(L)
-memory end to end. Interpret mode (CPU tests) keeps the plain jnp backward,
-which doubles as the numerical oracle for the kernels.
+Backward pass: custom VJP using the saved per-row logsumexp, one Pallas
+kernel on TPU (hvd_flash_bwd_dqkv: tiled over key blocks, each score tile
+computed once in VMEM for dK, dV and dQ, dQ of the whole (batch, head)
+accumulating in VMEM) — O(L) memory end to end. A call whose dQ does not
+fit that kernel's VMEM budget (backward_path, read off the call's shapes)
+takes two kernels instead, a dQ pass tiled over query blocks and a dK/dV
+pass tiled over key blocks, each recomputing its score tiles. Interpret
+mode (CPU tests) keeps the plain jnp backward, which doubles as the
+numerical oracle for the kernels.
 
 On CPU (tests, no TPU) the kernel runs through the Pallas interpreter.
 Sequence lengths with no aligned block size are padded to the next block
@@ -84,9 +88,16 @@ _OUTER_CHUNK = 1024
 # 1024 x 64 (PERF.md, PR 27: 14.2 + 9.9 + 11.6 ms a step against 17.8 + 9.9
 # + 13.4 with 256 x 256 in all three). A tile costs a fixed ~240 cycles per
 # 128 rows beside ~5 a vreg, so the forward kernel likes wide key tiles; the
-# dK/dV kernel likes its tile and both accumulators in registers.
+# dK/dV kernel likes its tile and both accumulators in registers. The one
+# backward kernel (bwd_dqkv: dK/dV with dQ beside) took 1.505 ms a call at
+# 128 x 128 against the pair's 1.446, and 1.253 at 256 x 256 (16 heads x 8
+# x 1024 x 64 on a v5e: PERF.md section 6).
 _CAUSAL_TILE = {"fwd": (128, 512), "bwd_dq": (256, 256),
-                "bwd_dkv": (128, 128)}
+                "bwd_dkv": (128, 128), "bwd_dqkv": (256, 256)}
+
+# The kernels that write key tiles and sweep query tiles: the dK/dV kernel
+# and the one backward kernel, which is the dK/dV kernel with dQ added.
+_BY_KEY = ("bwd_dkv", "bwd_dqkv")
 
 
 def _pick_tiles(lq, lk, causal, kernel="fwd"):
@@ -129,6 +140,7 @@ _BLOCK_TILE = {
     "fwd": {"crossed": (1024, 1024), "inside": (1024, 1024)},
     "bwd_dq": {"crossed": (256, 256), "inside": (1024, 1024)},
     "bwd_dkv": {"crossed": (256, 256), "inside": (1024, 1024)},
+    "bwd_dqkv": {"crossed": (256, 256), "inside": (1024, 1024)},
 }
 
 
@@ -338,7 +350,7 @@ def _visited_tiles(kernel, lq, lk, q_offset, kv_valid, block_q, block_k,
     ``kernel`` visits, masked = with mask code, from the bounds the
     kernels sweep by: the forward and dQ kernels rows of tiles, the dK/dV
     kernel columns."""
-    by_key = kernel == "bwd_dkv"
+    by_key = kernel in _BY_KEY
     n_qt, n_kt = lq // block_q, lk // block_k
     for o in range(n_kt if by_key else n_qt):
         # Either way the four bounds are: masked, plain, masked.
@@ -886,9 +898,9 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal,
-                       tiles, block, q_chunk, k_chunk, q_offset, n_qc,
-                       n_kc, kv_valid, masked, window):
+                       *refs, sm_scale, causal, tiles, block, q_chunk,
+                       k_chunk, q_offset, n_qc, n_kc, kv_valid, masked,
+                       window, fused):
     """dK/dV pass: (key-chunk, query-chunk) grid; per-key-chunk
     accumulators in scratch across query chunks; per key tile register
     sweeps over the chunk's query tiles: first those the mask edge
@@ -903,6 +915,16 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     and mosaic transposes p and ds through the XLU on every tile (dK/dV at
     128 x 128 on a v5e: 15.8 ms a step that way, 11.6 this way: PERF.md,
     PR 27). lse and delta arrive as (1, q_chunk) rows for it."""
+    # ``fused`` (hvd_flash_bwd_dqkv): dQ from the same ds, into a float32
+    # accumulator of the whole (batch, head) held TRANSPOSED, (Dqk, Lq), in
+    # VMEM across both inner grid axes: each tile adds k^T ds, whose k^T is
+    # taken once per key tile, so that no tile is transposed; the
+    # accumulator is transposed once, into the dQ block, at the (batch,
+    # head)'s last step.
+    if fused:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = refs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
     ic = 0 if n_kc == 1 else pl.program_id(1)      # key chunk (written)
     jc = 0 if n_qc == 1 else pl.program_id(2)      # query chunk (swept)
 
@@ -911,6 +933,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     _when(jc == 0, _init)
+    if fused:
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        _when((ic == 0) & (jc == 0), _init_dq)
 
     def column_of_tiles(cols, rows0, block_q, bounds, mask):
         """The key tile at ``cols`` of its chunk against query tiles of
@@ -921,6 +948,9 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         def _compute():
             kb = k_ref[0, cols, :].astype(jnp.float32)             # (BK, D)
             vb = v_ref[0, cols, :].astype(jnp.float32)
+            if fused:
+                kt = kb.T                                          # (D, BK)
+                q0 = _from(jc * q_chunk, rows0)    # of the whole sequence
 
             def body(t, carry, crossed):
                 dk, dv = carry
@@ -947,6 +977,11 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk = dk + jax.lax.dot_general(
                     ds, qb, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)
+                if fused:
+                    at = pl.ds(_from(q0, t * block_q), block_q)
+                    dq_acc[:, at] += jax.lax.dot_general(
+                        kt, ds, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
                 return dk, dv
 
             carry = _sweep(t_first, t_plain,
@@ -971,38 +1006,66 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     _when(jc == n_qc - 1, _finalize)
+    if fused:
+        def _store_dq():
+            dq_ref[0] = dq_acc[...].T.astype(dq_ref.dtype)
+
+        _when((ic == n_kc - 1) & (jc == n_qc - 1), _store_dq)
+
+
+# VMEM the fused backward may give the dQ of one (batch, head): its float32
+# accumulator and the two buffers of its output block, whose lanes pad to
+# 128. Compiled for a v5e, the rest of that kernel at 1024 x 1024 tiles asks
+# 5-6 MiB of _compiler_params' 64 MiB (34816 x 192 asked 65 MiB for 59.5
+# held, 65536 x 128 69 for 64), so 40 MiB: 40960 queries at 128 wide,
+# 22528 at 192, in bfloat16.
+_DQ_VMEM_BUDGET = 40 * 1024 * 1024
+
+
+def backward_path(lq, dqk, itemsize):
+    """The backward kernels of a call whose queries are ``lq`` long and
+    ``dqk`` wide, of ``itemsize`` bytes: ("bwd_dqkv",), the one kernel,
+    where its dQ of a whole (batch, head) fits _DQ_VMEM_BUDGET; else the
+    pair ("bwd_dq", "bwd_dkv"), which recomputes each score tile for dQ."""
+    lanes = -(-dqk // 128) * 128
+    held = lq * dqk * 4 + 2 * lq * lanes * itemsize
+    return ("bwd_dqkv",) if held <= _DQ_VMEM_BUDGET else ("bwd_dq", "bwd_dkv")
 
 
 def _fa_backward(q, k, v, o, lse, do, causal, sm_scale, block_q=None,
                  block_k=None, q_offset=None, kv_valid=None, window=None):
-    """Fused O(L)-memory backward: (dq, dk, dv) via two pallas_calls, each
-    with :func:`_pick_tiles`' tile shape for it unless one is given."""
+    """O(L)-memory backward: (dq, dk, dv) by the kernels
+    :func:`backward_path` names, each with :func:`_pick_tiles`' tile shape
+    for it unless one is given."""
     lq, lk = q.shape[1], k.shape[1]
     if q_offset is None:
         q_offset = lk - lq
     if kv_valid is None:
         kv_valid = lk
-    (dq_tiles, block), (dkv_tiles, _) = (
+    kernels = backward_path(lq, q.shape[2], q.dtype.itemsize)
+    tiles, blocks = zip(*(
         _schedule(kernel, lq, lk, q_offset, kv_valid, causal, window,
-                  block_q, block_k) for kernel in ("bwd_dq", "bwd_dkv"))
-    (dq_q, dq_k), (dkv_q, dkv_k) = (
-        (block, block) if block else t for t in (dq_tiles, dkv_tiles))
-    # Each kernel streams the axis it accumulates over in chunks of up to
-    # 4096 and writes the other in chunks of up to _OUTER_CHUNK.
+                  block_q, block_k) for kernel in kernels))
+    block = blocks[0]
+
+    def chunks(kernel, tile):
+        # Each kernel streams the axis it accumulates over in chunks of up
+        # to 4096 and writes the other in chunks of up to _OUTER_CHUNK.
+        bq, bk = (block, block) if block else tile
+        if kernel in _BY_KEY:
+            return _pick_chunk(lq, bq), _pick_chunk(lk, bk, _OUTER_CHUNK)
+        return _pick_chunk(lq, bq, _OUTER_CHUNK), _pick_chunk(lk, bk)
     return _bwd_call(
         q, k, v, o, lse, do, causal=causal, sm_scale=sm_scale,
-        tiles=(dq_tiles, dkv_tiles), block=block,
-        chunks=((_pick_chunk(lq, dq_q, _OUTER_CHUNK),
-                 _pick_chunk(lk, dq_k)),
-                (_pick_chunk(lq, dkv_q),
-                 _pick_chunk(lk, dkv_k, _OUTER_CHUNK))),
+        kernels=kernels, tiles=tiles, block=block,
+        chunks=tuple(map(chunks, kernels, tiles)),
         q_offset=q_offset, kv_valid=kv_valid, window=window,
         interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
-def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
-              q_offset, kv_valid, window, interpret):
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("kernels",))
+def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, kernels, tiles,
+              block, chunks, q_offset, kv_valid, window, interpret):
     bh, lq, d = q.shape
     lk, d_v = k.shape[1], v.shape[2]
     # The row statistics travel as (BH, 1, Lq) rows: see _fa_kernel.
@@ -1011,42 +1074,46 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
     lse = lse[:, None, :]
     vma = _vma(q, k, v, do)
 
-    def kernel(body, tile, chunk):
+    def kernel(body, tile, chunk, **kw):
         q_chunk, k_chunk = chunk
         return functools.partial(
             body, sm_scale=sm_scale, causal=causal, tiles=tile,
             block=block, q_chunk=q_chunk, k_chunk=k_chunk,
             n_qc=lq // q_chunk, n_kc=lk // k_chunk, q_offset=q_offset,
-            kv_valid=kv_valid, masked=kv_valid < lk, window=window)
+            kv_valid=kv_valid, masked=kv_valid < lk, window=window, **kw)
 
     def swept(by_key, chunk, n):
         return functools.partial(_fetched, by_key=by_key, block=block,
                                  chunk=chunk, n=n, q_offset=q_offset,
                                  window=window)
 
-    # dQ: grid over query chunks; key chunks stream innermost.
-    q_chunk, k_chunk = chunks[0]
-    # Each operand's block is its whole width: q and k Dqk, v and dO Dv.
-    q_blk, o_blk = (pl.BlockSpec((1, q_chunk, w), lambda b, i, j: (b, i, 0))
-                    for w in (d, d_v))
-    r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i))
-    keys = swept(False, k_chunk, lk)
-    k_blk, v_blk = (pl.BlockSpec((1, k_chunk, w),
-                                 lambda b, i, j: (b, keys(i, j), 0))
-                    for w in (d, d_v))
-    dq = pl.pallas_call(
-        kernel(_fa_bwd_dq_kernel, tiles[0], chunks[0]),
-        name="hvd_flash_bwd_dq",
-        grid=(bh, lq // q_chunk, lk // k_chunk),
-        in_specs=[q_blk, k_blk, v_blk, o_blk, r_blk, r_blk],
-        out_specs=q_blk,
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
-        scratch_shapes=[_scratch((q_chunk, d))],
-        compiler_params=_compiler_params(interpret),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
-    # dK/dV: grid over key chunks; query chunks stream innermost.
-    q_chunk, k_chunk = chunks[1]
+    if kernels[0] == "bwd_dq":
+        # dQ: grid over query chunks; key chunks stream innermost.
+        q_chunk, k_chunk = chunks[0]
+        # Each operand's block is its whole width: q and k Dqk, v and dO Dv.
+        q_blk, o_blk = (pl.BlockSpec((1, q_chunk, w),
+                                     lambda b, i, j: (b, i, 0))
+                        for w in (d, d_v))
+        r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, i))
+        keys = swept(False, k_chunk, lk)
+        k_blk, v_blk = (pl.BlockSpec((1, k_chunk, w),
+                                     lambda b, i, j: (b, keys(i, j), 0))
+                        for w in (d, d_v))
+        dq = pl.pallas_call(
+            kernel(_fa_bwd_dq_kernel, tiles[0], chunks[0]),
+            name="hvd_flash_bwd_dq",
+            grid=(bh, lq // q_chunk, lk // k_chunk),
+            in_specs=[q_blk, k_blk, v_blk, o_blk, r_blk, r_blk],
+            out_specs=q_blk,
+            out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma),
+            scratch_shapes=[_scratch((q_chunk, d))],
+            compiler_params=_compiler_params(interpret),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
+    # dK/dV: grid over key chunks; query chunks stream innermost. Fused,
+    # dQ too: its block is the (batch, head)'s whole dQ, written once.
+    fused = kernels[-1] == "bwd_dqkv"
+    q_chunk, k_chunk = chunks[-1]
     rows = swept(True, q_chunk, lq)
     q_blk, o_blk = (pl.BlockSpec((1, q_chunk, w),
                                  lambda b, i, j: (b, rows(i, j), 0))
@@ -1054,18 +1121,25 @@ def _bwd_call(q, k, v, o, lse, do, *, causal, sm_scale, tiles, block, chunks,
     r_blk = pl.BlockSpec((1, 1, q_chunk), lambda b, i, j: (b, 0, rows(i, j)))
     k_blk, v_blk = (pl.BlockSpec((1, k_chunk, w), lambda b, i, j: (b, i, 0))
                     for w in (d, d_v))
-    dk, dv = pl.pallas_call(
-        kernel(_fa_bwd_dkv_kernel, tiles[1], chunks[1]),
-        name="hvd_flash_bwd_dkv",
+    dq_out = [pl.BlockSpec((1, lq, d), lambda b, i, j: (b, 0, 0))] * fused
+    grads = pl.pallas_call(
+        kernel(_fa_bwd_dkv_kernel, tiles[-1], chunks[-1], fused=fused),
+        name="hvd_flash_" + kernels[-1],
         grid=(bh, lk // k_chunk, lq // q_chunk),
         in_specs=[q_blk, k_blk, v_blk, o_blk, r_blk, r_blk],
-        out_specs=[k_blk, v_blk],
+        out_specs=[k_blk, v_blk] + dq_out,
         out_shape=[jax.ShapeDtypeStruct((bh, lk, d), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((bh, lk, d_v), v.dtype, vma=vma)],
-        scratch_shapes=[_scratch((k_chunk, d)), _scratch((k_chunk, d_v))],
+                   jax.ShapeDtypeStruct((bh, lk, d_v), v.dtype, vma=vma)]
+        + [jax.ShapeDtypeStruct((bh, lq, d), q.dtype, vma=vma)] * fused,
+        scratch_shapes=[_scratch((k_chunk, d)), _scratch((k_chunk, d_v))]
+        + [_scratch((d, lq))] * fused,
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
+    if fused:
+        dk, dv, dq = grads
+    else:
+        dk, dv = grads
     return dq, dk, dv
 
 

@@ -178,3 +178,17 @@ def shared_cluster():
 @pytest.fixture()
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture(params=["bwd_dqkv", "pair"])
+def flash_backward(request, monkeypatch):
+    """Each backward path of the flash kernels, whatever the call's shapes
+    (``backward_path`` picks it from them): ``bwd_dqkv``, the one kernel a
+    call takes while its dQ fits the VMEM budget, and ``pair`` (``bwd_dq``
+    + ``bwd_dkv``), which a call past that budget takes."""
+    import importlib
+    fa = importlib.import_module("horovod_tpu.ops.pallas.flash_attention")
+    kernels = (("bwd_dqkv",) if request.param == "bwd_dqkv"
+               else ("bwd_dq", "bwd_dkv"))
+    monkeypatch.setattr(fa, "backward_path", lambda *shape: kernels)
+    return request.param

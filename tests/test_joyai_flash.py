@@ -420,19 +420,22 @@ class TestFlashAtTwoWidths:
                                        rtol=2e-4, atol=2e-5,
                                        err_msg=f"d{nm}")
 
-    def test_by_block_kind_at_2048(self, rng, monkeypatch):
-        """Forward, dQ and dK/dV of a causal call of 2048 (two blocks of
-        1024: the schedule by block kind) through the interpreter at
-        Dqk 192, Dv 128, against the jnp oracles."""
+    def test_by_block_kind_at_2048(self, rng, monkeypatch, flash_backward):
+        """Forward and backward (the one kernel, and the pair) of a causal
+        call of 2048 (two blocks of 1024: the schedule by block kind)
+        through the interpreter at Dqk 192, Dv 128, against the jnp
+        oracles."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
         assert fa._by_block(2048, 2048, 0, 2048, True, None)
         self._against_oracles(fa, *self._operands(rng, 1, 2048, 2048))
-        assert _flash_gauge()["bwd_dkv"]["blocks_diagonal"] == 2
+        last = "bwd_dqkv" if flash_backward == "bwd_dqkv" else "bwd_dkv"
+        assert _flash_gauge()[last]["blocks_diagonal"] == 2
 
-    def test_rolled_tiles_and_chunks(self, rng, monkeypatch):
+    def test_rolled_tiles_and_chunks(self, rng, monkeypatch, flash_backward):
         """Small tiles in chunks whose bounds follow the grid (no block
-        kind): 256 causal in tiles of 32 x 64, chunks of 128."""
+        kind): 256 causal in tiles of 32 x 64, chunks of 128; the one
+        backward kernel, and the pair."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
         pick = fa._pick_chunk
@@ -472,8 +475,7 @@ class TestFlashAtTwoWidths:
     # its gauge per (batch, head), recorded at equal widths
     CELL_CALLS = {
         "gpt2m": ((8, 1024, 16, 16, 64, None),
-                  {"fwd": (16, 12, 8), "bwd_dq": (16, 10, 4),
-                   "bwd_dkv": (64, 36, 8)}),
+                  {"fwd": (16, 12, 8), "bwd_dqkv": (16, 10, 4)}),
         "sparse_causal_8192": ((2, 8192, 28, 4, 128, None), None),
         "window_4096": ((2, 8192, 28, 4, 128, 4096), None),
         "window_2048": ((2, 8192, 32, 4, 128, 2048), None),
@@ -487,7 +489,8 @@ class TestFlashAtTwoWidths:
         tiles and counts at their equal widths as at 192 / 128 (the
         recorded ``gpt2m_*`` counts, total / visited / masked, as
         PERF.md's table has them), and the cells' 8192 calls go by block
-        kind with the blocks the table names."""
+        kind with the blocks the table names. Every cell's backward is
+        the one kernel, at both widths."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
         (b, length, h, kv, d, window), recorded = self.CELL_CALLS[call]
@@ -505,10 +508,10 @@ class TestFlashAtTwoWidths:
                     for t in (k, v))
             r = jax.ShapeDtypeStruct((b * h, length), jnp.float32)
             o = jax.ShapeDtypeStruct((b * h, length, dv), jnp.bfloat16)
+            assert fa.backward_path(length, dqk, 2) == ("bwd_dqkv",)
             jax.eval_shape(lambda *a: fa._fa_backward(
                 *a, True, 0.1, window=window), q, k, v, o, r, o)
-            out.update({kernel: _flash_gauge()[kernel]
-                        for kernel in ("bwd_dq", "bwd_dkv")})
+            out["bwd_dqkv"] = _flash_gauge()["bwd_dqkv"]
             return out
 
         equal = gauges(d, d)

@@ -165,10 +165,12 @@ class TestFlashAttention:
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("lq,lk", [(64, 64), (32, 64), (64, 32)])
-    def test_fused_backward_kernels_match_jnp(self, rng, causal, lq, lk):
+    def test_fused_backward_kernels_match_jnp(self, rng, flash_backward,
+                                              causal, lq, lk):
         """The TPU backward kernels (_fa_backward, run here through the
-        interpreter) must reproduce the jnp backward that CPU mode uses —
-        the jnp path is the oracle the kernels are pinned to."""
+        interpreter; the one kernel and the pair) must reproduce the jnp
+        backward that CPU mode uses — the jnp path is the oracle the
+        kernels are pinned to."""
         import importlib
         # the package re-exports the same-named function, shadowing the
         # submodule attribute — import the module explicitly
@@ -331,7 +333,7 @@ class TestTileSchedule:
         assert by_rows == expected
         assert by_cols == expected
         kinds = list(expected.values())
-        for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        for kernel in ("fwd", "bwd_dq", "bwd_dkv", "bwd_dqkv"):
             assert fa.tile_counts(kernel, lq, lk, q_offset, kv_valid, bq,
                                   bk, causal) == {
                 "total": n_qt * n_kt,
@@ -382,6 +384,9 @@ class TestTileSchedule:
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
         assert [_fa()._pick_tiles(lq, lk, causal, kernel)
                 for kernel in ("fwd", "bwd_dq", "bwd_dkv")] == want
+        # the one backward kernel takes the dQ kernel's tiles up to 1024
+        # causal (measured for it), the dK/dV kernel's, equal, elsewhere
+        assert _fa()._pick_tiles(lq, lk, causal, "bwd_dqkv") == want[1]
 
     @pytest.mark.parametrize("length,want", [
         (1024, 1024), (2048, 1024), (1536, 512), (384, 128), (1152, 128),
@@ -402,13 +407,19 @@ class TestTileSchedule:
         assert fa._pick_tiles(1024, 1024, True, "bwd_dq") == (256, 256)
         assert fa._pick_tiles(1024, 1024, False) == (512, 512)
 
-    def test_gauge_reads_the_schedule_at_1024(self, monkeypatch):
+    def test_gauge_reads_the_schedule_at_1024(self, monkeypatch,
+                                              flash_backward):
         """A traced causal call at 1024 sets hvd_flash_tiles to what the
         schedule function says: visited < total, masked < visited; a
-        non-causal aligned call reads visited = total, masked = 0."""
+        non-causal aligned call reads visited = total, masked = 0. The
+        backward is the one kernel, ``bwd_dqkv``, with the dK/dV
+        schedule's counts, or past its budget the pair."""
         from horovod_tpu import metrics
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        kernels = ("fwd",) + fa.backward_path(1024, 64, 2)
+        assert kernels[1:] == (("bwd_dqkv",) if flash_backward == "bwd_dqkv"
+                               else ("bwd_dq", "bwd_dkv"))
 
         def gauge():
             series = metrics.snapshot()["hvd_flash_tiles"]["series"]
@@ -429,16 +440,17 @@ class TestTileSchedule:
                                    "blocks_edge", "blocks_skipped"), 0)
         trace(True)
         got = gauge()
-        for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+        for kernel in kernels:
             c = got[kernel]
             assert c == {**no_blocks, **fa.tile_counts(
                 kernel, 1024, 1024, 0, 1024,
                 *fa._pick_tiles(1024, 1024, True, kernel), True)}
             assert c["masked"] < c["visited"] < c["total"]
         trace(False)
-        for kernel, c in gauge().items():
-            assert c == {"total": 1, "visited": 1, "masked": 0,
-                         **no_blocks}, kernel
+        got = gauge()
+        for kernel in kernels:
+            assert got[kernel] == {"total": 1, "visited": 1, "masked": 0,
+                                   **no_blocks}, kernel
 
 
 def _flash_gauge():
@@ -454,7 +466,8 @@ class TestBlockSchedule:
     """Past _OUTER_CHUNK a causal call whose lengths, offset and window are
     whole blocks runs each block's static schedule by its kind."""
 
-    @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+    @pytest.mark.parametrize("kernel",
+                             ["fwd", "bwd_dq", "bwd_dkv", "bwd_dqkv"])
     @pytest.mark.parametrize("window", [None, 4096, 2048])
     @pytest.mark.parametrize("length", [2048, 4096, 8192])
     def test_tiles_cover_the_kept_pairs_once(self, monkeypatch, kernel,
@@ -462,9 +475,13 @@ class TestBlockSchedule:
         """The tiles the schedule visits cover every kept pair exactly
         once, none of them is wholly masked, and the masked ones are
         exactly those an edge crosses; block_counts, the per-kind
-        tile_counts and the gauge of a traced call say the same."""
+        tile_counts and the gauge of a traced call say the same. The
+        one backward kernel's are the dK/dV kernel's; the pair's gauge is
+        read from a call past the one kernel's budget (a budget of 0)."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        if kernel in ("bwd_dq", "bwd_dkv"):
+            monkeypatch.setattr(fa, "_DQ_VMEM_BUDGET", 0)
         block = fa._OUTER_CHUNK
         assert fa._by_block(length, length, 0, length, True, window)
         tiles = fa._block_tiles(kernel)
@@ -520,6 +537,10 @@ class TestBlockSchedule:
                 q, k, v, o, lse, do, True, 0.125, window=window),
                 q, q, q, q, r, q)
         assert _flash_gauge()[kernel] == counts
+        if kernel == "bwd_dqkv":
+            assert counts == fa.block_counts(
+                "bwd_dkv", length, length, 0, window,
+                fa._block_tiles("bwd_dkv"), block)
 
     def test_the_cell_reads_the_blocks_the_issue_names(self, monkeypatch):
         """smallthinker_ep4_8k_1chip, forward, per (batch, head): inside /
@@ -575,18 +596,21 @@ class TestBlockSchedule:
         (1024, 1024, None, 2, 2), (1024, 1024, 512, 28, 4),
         (1024, 2048, None, 4, 2), (896, 1024, 256, 2, 1),
         (1024, 1024, 128, 2, 2), (1024, 2048, 1024, 2, 1)])
-    def test_kernels_match_the_jnp_oracles(self, rng, monkeypatch, lq, lk,
-                                           window, heads, kv_heads):
+    def test_kernels_match_the_jnp_oracles(self, rng, monkeypatch,
+                                           flash_backward, lq, lk, window,
+                                           heads, kv_heads):
         """Forward, dQ and dK/dV on the path by block kind through the
-        interpreter: _OUTER_CHUNK shrunk to 128 so that 1024 tokens are
-        eight blocks, four to a grid step, each cut in several tiles."""
+        interpreter (the backward as the one kernel and as the pair):
+        _OUTER_CHUNK shrunk to 128 so that 1024 tokens are eight blocks,
+        four to a grid step, each cut in several tiles."""
         fa = _fa()
         monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
         monkeypatch.setattr(fa, "_OUTER_CHUNK", 128)
         monkeypatch.setattr(fa, "_BLOCK_TILE", {
             "fwd": {"crossed": (32, 64), "inside": (64, 128)},
             "bwd_dq": {"crossed": (64, 32), "inside": (64, 64)},
-            "bwd_dkv": {"crossed": (32, 32), "inside": (64, 32)}})
+            "bwd_dkv": {"crossed": (32, 32), "inside": (64, 32)},
+            "bwd_dqkv": {"crossed": (32, 32), "inside": (64, 32)}})
         pick = fa._pick_chunk
         monkeypatch.setattr(
             fa, "_pick_chunk",
@@ -613,6 +637,8 @@ class TestBlockSchedule:
                                    rtol=2e-4, atol=2e-5)
         got = fa._fa_backward(q, kw, vw, o_ref, lse_ref, do, True, sm,
                               window=window)
+        assert _flash_gauge()[fa.backward_path(lq, D, 4)[-1]][
+            "blocks_diagonal"] == lq // 128
         want = fa._jnp_block_bwd(q, kw, vw, o_ref, lse_ref, do, True, sm,
                                  window=window)
         for a, b, nm in zip(got, want, "qkv"):
@@ -685,10 +711,11 @@ class TestTiledKernels:
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("lq,lk,q_offset,kv_valid,bq,bk,chunk", _TILED)
     def test_forward_and_gradients_match_oracles(
-            self, rng, monkeypatch, lq, lk, q_offset, kv_valid, bq, bk,
-            chunk, causal):
+            self, rng, monkeypatch, flash_backward, lq, lk, q_offset,
+            kv_valid, bq, bk, chunk, causal):
         """_fa_forward and _fa_backward (called directly: CPU's custom VJP
-        takes the jnp backward) against the jnp oracles."""
+        takes the jnp backward; the one kernel and the pair) against the
+        jnp oracles."""
         fa = _fa()
         if chunk:
             pick = fa._pick_chunk
@@ -746,6 +773,144 @@ class TestTiledKernels:
                                    rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                    rtol=2e-4, atol=2e-5)
+
+
+class TestOneBackwardKernel:
+    """The backward as one kernel (``hvd_flash_bwd_dqkv``: dQ beside dK
+    and dV from each score tile computed once) or, past its dQ's VMEM
+    budget, as the pair; the path is read off the call's shapes."""
+
+    # (query length, Dqk): each cell's call
+    @pytest.mark.parametrize("lq,dqk", [
+        (1024, 64),         # gpt2m_1chip, gpt2m_dp4
+        (8192, 128),        # smallthinker, nemotron_tt, trinity_mini
+        (8192, 192)])       # joyai_flash
+    def test_every_cells_call_takes_the_one_kernel(self, lq, dqk):
+        assert _fa().backward_path(lq, dqk, 2) == ("bwd_dqkv",)
+
+    @pytest.mark.parametrize("lq,lk,causal,tiles", [
+        (1024, 1024, True, (256, 256)), (512, 512, True, (256, 256)),
+        (256, 256, True, (128, 128)), (256, 1024, True, (128, 256)),
+        (96, 96, True, (96, 96)), (1024, 1024, False, (1024, 1024)),
+        (2048, 2048, False, (1024, 1024))])
+    def test_the_one_kernels_tiles(self, monkeypatch, lq, lk, causal, tiles):
+        """What reaches the jitted call: up to 1024 causal the one kernel
+        sweeps the dQ kernel's 256 x 256 tiles (the dK/dV kernel's 128 x
+        128 made it slower than the pair there), in one chunk a side; a
+        non-causal call its largest divisor up to 1024."""
+        fa = _fa()
+        monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
+        seen = {}
+        monkeypatch.setattr(fa, "_bwd_call",
+                            lambda *a, **kw: seen.update(kw))
+        q, k = (jax.ShapeDtypeStruct((2, n, 64), jnp.bfloat16)
+                for n in (lq, lk))
+        r = jax.ShapeDtypeStruct((2, lq), jnp.float32)
+        fa._fa_backward(q, k, k, q, r, q, causal, 0.1)
+        assert seen["kernels"] == ("bwd_dqkv",)
+        assert seen["tiles"] == (tiles,)
+        assert seen["block"] is None
+        if max(lq, lk) <= 1024:
+            assert seen["chunks"] == ((lq, lk),)
+
+    @pytest.mark.parametrize("lq,dqk,itemsize,fused", [
+        (40960, 128, 2, True), (41984, 128, 2, False),
+        (22528, 192, 2, True), (23552, 192, 2, False),
+        (26624, 128, 4, True), (27648, 128, 4, False),
+        (49152, 64, 2, True), (65536, 64, 2, False)])
+    def test_a_call_past_the_budget_takes_the_pair(self, lq, dqk, itemsize,
+                                                   fused):
+        """The float32 accumulator and two buffers of the bfloat16 (or
+        float32) dQ block, its lanes padded to 128, against 40 MiB."""
+        fa = _fa()
+        held = lq * dqk * 4 + 2 * lq * -(-dqk // 128) * 128 * itemsize
+        assert (held <= fa._DQ_VMEM_BUDGET) == fused
+        assert fa.backward_path(lq, dqk, itemsize) == (
+            ("bwd_dqkv",) if fused else ("bwd_dq", "bwd_dkv"))
+
+    def test_the_call_reaches_the_kernels_the_rule_names(self, monkeypatch,
+                                                         flash_backward):
+        """What ``_fa_backward`` hands the jitted call: the rule's kernels
+        with their tiles and chunks; the one kernel's are the dK/dV
+        kernel's."""
+        fa = _fa()
+        seen = {}
+        monkeypatch.setattr(fa, "_bwd_call",
+                            lambda *a, **kw: seen.update(kw))
+        q = jax.ShapeDtypeStruct((2, 8192, 128), jnp.bfloat16)
+        r = jax.ShapeDtypeStruct((2, 8192), jnp.float32)
+        fa._fa_backward(q, q, q, q, r, q, True, 0.1)
+        tiles = fa._block_tiles("bwd_dkv")
+        if flash_backward == "bwd_dqkv":
+            assert seen["kernels"] == ("bwd_dqkv",)
+            assert seen["tiles"] == (tiles,)
+            assert seen["chunks"] == ((4096, 1024),)
+        else:
+            assert seen["kernels"] == ("bwd_dq", "bwd_dkv")
+            assert seen["tiles"] == (fa._block_tiles("bwd_dq"), tiles)
+            assert seen["chunks"] == ((1024, 4096), (4096, 1024))
+        assert seen["block"] == 1024
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("q_offset,kv_valid,tiles", [
+        (17, 128, (32, 32)), (-40, 100, (32, 64)), (200, 128, None)])
+    def test_a_hop_against_the_rings_logsumexp(self, rng, flash_backward,
+                                               causal, q_offset, kv_valid,
+                                               tiles):
+        """Ring attention's hop contract: the gradient of one hop's keys
+        against the logsumexp and output of the whole ring (here this hop
+        and one more block of keys), at an offset off the tile grid, equals
+        the jnp oracle's for the same ``lse``."""
+        fa = _fa()
+        H, L, D = 2, 128, 16
+        q, do = (jnp.asarray(rng.standard_normal((H, L, D)), np.float32)
+                 for _ in range(2))
+        k0, v0, k, v = (jnp.asarray(rng.standard_normal((H, L, D)),
+                                    np.float32) for _ in range(4))
+        sm = 1.0 / D ** 0.5
+        o0, lse0 = fa._jnp_block_fwd(q, k0, v0, False, sm)
+        o1, lse1 = fa._jnp_block_fwd(q, k, v, causal, sm, q_offset,
+                                     kv_valid)
+        lse = jnp.logaddexp(lse0, lse1)
+        o = (o0 * jnp.exp(lse0 - lse)[..., None]
+             + o1 * jnp.exp(lse1 - lse)[..., None])
+        got = fa._fa_backward(q, k, v, o, lse, do, causal, sm,
+                              *(tiles or (None, None)), q_offset, kv_valid)
+        want = fa._jnp_block_bwd(q, k, v, o, lse, do, causal, sm, q_offset,
+                                 kv_valid)
+        for a, b, nm in zip(got, want, "qkv"):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5,
+                                       err_msg=f"d{nm}")
+
+    @pytest.mark.parametrize("window", [None, 40])
+    def test_grouped_kv_through_the_backward_rule(self, rng, monkeypatch,
+                                                  flash_backward, window):
+        """``_flash_bwd``'s grouped-query path (the narrow K/V broadcast,
+        the kernels, dK/dV summed back onto the kv heads) with the
+        kernels in place of the jnp backward the CPU takes, against the
+        oracle on the repeated K/V."""
+        fa = _fa()
+        oracle = fa._jnp_block_bwd
+        monkeypatch.setattr(fa, "_jnp_block_bwd", fa._fa_backward)
+        B, H, KV, L, D = 2, 4, 2, 128, 16
+        q, do = (jnp.asarray(rng.standard_normal((B * H, L, D)), np.float32)
+                 for _ in range(2))
+        k, v = (jnp.asarray(rng.standard_normal((B * KV, L, D)), np.float32)
+                for _ in range(2))
+        sm = 1.0 / D ** 0.5
+        kw, vw = (fa.gqa_repeat3(t, B, KV, H // KV) for t in (k, v))
+        o, lse = fa._jnp_block_fwd(q, kw, vw, True, sm, window=window)
+        got = fa._flash_bwd(True, sm, None, None, None, None, H, KV, window,
+                            (q, k, v, o, lse), do)
+        dq, dk, dv = oracle(q, kw, vw, o, lse, do, True, sm, window=window)
+        want = (dq, fa.gqa_fold3(dk, B, KV, H // KV),
+                fa.gqa_fold3(dv, B, KV, H // KV))
+        for a, b, nm in zip(got, want, "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5,
+                                       err_msg=f"d{nm}")
 
 
 # (length, window): shorter than, equal to and longer than the sequence, and
@@ -882,7 +1047,7 @@ class TestSlidingWindow:
     @pytest.mark.parametrize("chunk", [None, 64])
     @pytest.mark.parametrize("length,window", _WINDOWS)
     def test_backward_kernels_match_the_jnp_oracle(
-            self, rng, monkeypatch, length, window, chunk):
+            self, rng, monkeypatch, flash_backward, length, window, chunk):
         """The TPU kernels through the interpreter (the CPU's custom VJP
         takes the jnp backward), 32 x 32 tiles so that skipped, plain and
         twice-masked tiles all occur; with a chunk cap every bound comes
@@ -912,7 +1077,8 @@ class TestSlidingWindow:
                                        rtol=2e-4, atol=2e-5,
                                        err_msg=f"d{nm}")
 
-    @pytest.mark.parametrize("kernel", ["fwd", "bwd_dq", "bwd_dkv"])
+    @pytest.mark.parametrize("kernel",
+                             ["fwd", "bwd_dq", "bwd_dkv", "bwd_dqkv"])
     @pytest.mark.parametrize(
         "lq,lk,q_offset,kv_valid,bq,bk,window",
         [(128, 128, 0, 128, 32, 32, 48), (128, 128, 0, 100, 16, 32, 40),
@@ -943,7 +1109,7 @@ class TestSlidingWindow:
         masked."""
         fa = _fa()
         want = {"fwd": (16, 12, 8), "bwd_dq": (16, 10, 4),
-                "bwd_dkv": (64, 36, 8)}
+                "bwd_dkv": (64, 36, 8), "bwd_dqkv": (16, 10, 4)}
         for kernel, counts in want.items():
             got = fa.tile_counts(kernel, 1024, 1024, 0, 1024,
                                  *fa._pick_tiles(1024, 1024, True, kernel),

@@ -14,6 +14,7 @@ test file), and all such tests live in this one file.
 """
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,16 @@ def fa(monkeypatch):
     monkeypatch.setattr(mod, "_interpret", lambda: False)
     monkeypatch.delenv("HVD_FLASH_BLOCK", raising=False)
     return mod
+
+
+def _flash_kernels(hlo):
+    """The flash kernels a compiled program holds, by their whole names
+    (``hvd_flash_bwd_dq`` is a prefix of ``hvd_flash_bwd_dqkv``)."""
+    return set(re.findall(r"hvd_flash_[a-z]+(?:_[a-z]+)*", hlo))
+
+
+FUSED = {"hvd_flash_fwd", "hvd_flash_bwd_dqkv"}
+PAIR = {"hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"}
 
 
 # (batch, lq, lk, heads, kv heads, head size, causal, dtype[, window])
@@ -71,6 +82,9 @@ _CALLS = {
     # two 1024-blocks wide
     "gqa_32_on_4_8192_window_2048": (2, 8192, 8192, 32, 4, 128, True,
                                      "bfloat16", 2048),
+    # past the fused backward's VMEM budget for dQ: the pair
+    "causal_49152_past_the_budget": (1, 49152, 49152, 1, 1, 128, True,
+                                     "bfloat16"),
 }
 
 
@@ -89,8 +103,8 @@ def test_forward_and_backward_compile_for_v5e(one_chip, fa, call):
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         shape(lq, h), shape(lk, kv), shape(lk, kv)).compile().as_text()
-    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
-        assert kernel in hlo, f"{kernel} is not in the compiled program"
+    assert _flash_kernels(hlo) == (PAIR if "past_the_budget" in call
+                                   else FUSED)
 
 
 def test_latent_attention_kernels_compile_for_v5e(one_chip, fa):
@@ -110,8 +124,33 @@ def test_latent_attention_kernels_compile_for_v5e(one_chip, fa):
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         shape(dqk), shape(dqk), shape(dv)).compile().as_text()
-    for kernel in ("hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
-        assert kernel in hlo, f"{kernel} is not in the compiled program"
+    assert _flash_kernels(hlo) == FUSED
+
+
+@pytest.mark.parametrize("length,dqk,dv,causal,dtype", [
+    (22528, 192, 128, True, "bfloat16"), (22528, 192, 128, False, "bfloat16"),
+    (40960, 128, 128, True, "bfloat16"), (26624, 128, 128, True, "float32")])
+def test_the_longest_fused_backward_compiles_for_v5e(one_chip, fa, length,
+                                                     dqk, dv, causal, dtype):
+    """The longest calls ``backward_path`` gives the one kernel, at two
+    head widths, causal or not, in bfloat16 and float32: their dQ of a
+    whole (batch, head) takes most of the budget, and the kernel still
+    fits the scoped VMEM limit (past about 1.5 times the budget it does
+    not: 34816 x 192 asks 65 MiB of 64)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    assert fa.backward_path(length, dqk, itemsize) == ("bwd_dqkv",)
+    assert fa.backward_path(length + 1024, dqk, itemsize) \
+        == ("bwd_dq", "bwd_dkv")
+
+    def shape(width):
+        return jax.ShapeDtypeStruct((1, length, width), jnp.dtype(dtype),
+                                    sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((1, length), jnp.float32, sharding=one_chip)
+    hlo = jax.jit(lambda q, k, v, o, lse, do: fa._fa_backward(
+        q, k, v, o, lse, do, causal, 0.1)).lower(
+        shape(dqk), shape(dqk), shape(dv), shape(dv), lse,
+        shape(dv)).compile().as_text()
+    assert _flash_kernels(hlo) == {"hvd_flash_bwd_dqkv"}
 
 
 # -- DroplessMoE's grouped products (PR 34) -----------------------------------
@@ -251,9 +290,9 @@ def test_the_prologue_kernels_compile_for_v5e(one_chip, fa, monkeypatch,
     hlo = jax.jit(forward_and_backward).lower(
         shape((b, length, (heads + 2 * kv) * d)), scale, scale,
         shape((b * heads, length, d))).compile().as_text()
-    for kernel in ("hvd_attn_prologue_fwd", "hvd_attn_prologue_bwd",
-                   "hvd_flash_fwd", "hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"):
+    for kernel in ("hvd_attn_prologue_fwd", "hvd_attn_prologue_bwd"):
         assert kernel in hlo, f"{kernel} is not in the compiled program"
+    assert _flash_kernels(hlo) == FUSED
 
 
 @pytest.mark.parametrize("cell,taken", [
