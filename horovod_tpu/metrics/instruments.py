@@ -430,6 +430,24 @@ SSM_SCAN_PATH = REGISTRY.gauge(
     "state (zeros on path 0). Picked from the call's shapes "
     "(parallel.ssm.scan_path). Set while the call is traced.",
     ("kind",))
+KDA_LAYER = REGISTRY.gauge(
+    "hvd_kda_layer",
+    "The last traced parallel.kda.KDAMixer call, by kind: kernels (1: the "
+    "delta rule runs in ops/pallas/kda.py, the decays, the chunk's solve "
+    "and the carried state in VMEM; 0: the jax.numpy chunked form; picked "
+    "from the call's shapes by parallel.kda.kda_path), heads, head_dim, "
+    "chunk (the rule's chunk length) and chunks (chunks a sequence). Set "
+    "while the call is traced.",
+    ("kind",))
+KDA_CHUNK_STATE_BYTES = REGISTRY.gauge(
+    "hvd_kda_chunk_state_bytes",
+    "Bytes of chunk states that one traced KDAMixer call writes to HBM for "
+    "its backward pass: one float32 (head_dim, head_dim) state a sequence, "
+    "chunk and head, the state each chunk opens with (the kernels' first "
+    "backward sweep, or what the jax.numpy form's scan keeps). By the "
+    "chips the sequence is split over (1: the rule has no exchange). Set "
+    "while the call is traced.",
+    ("axis_size",))
 AUTOPILOT_DECISIONS = REGISTRY.counter(
     "autopilot_decisions_total",
     "Autopilot controller decisions per lever and outcome "
@@ -930,6 +948,19 @@ def record_ssm_scan_path(path, blocks):
     for kind, n in zip(("kernels", "positions", "channels", "state"),
                        (path, *blocks)):
         SSM_SCAN_PATH.labels(kind).set(n)
+
+
+def record_kda_layer(path, heads, head_dim, chunk, chunks, chunk_state_bytes,
+                     axis_size=1):
+    """What one trace of ``parallel.kda.KDAMixer`` makes: known while the
+    call is traced, so set there once and not per step."""
+    if not _enabled:
+        return
+    for kind, n in (("kernels", path), ("heads", heads),
+                    ("head_dim", head_dim), ("chunk", chunk),
+                    ("chunks", chunks)):
+        KDA_LAYER.labels(kind).set(n)
+    KDA_CHUNK_STATE_BYTES.labels(axis_size).set(chunk_state_bytes)
 
 
 def record_flash_tiles(kernel, counts):

@@ -20,6 +20,9 @@ from horovod_tpu.models.afmoe import (  # noqa: F401
 from horovod_tpu.models.joyai_flash import (  # noqa: F401
     JoyAIFlash, JoyAIFlashBlock, JoyAIFlashConfig,
 )
+from horovod_tpu.models.kimi_linear import (  # noqa: F401
+    KimiLinear, KimiLinearBlock, KimiLinearConfig,
+)
 from horovod_tpu.models.t5 import (  # noqa: F401
     T5, T5Config, t5_beam_decode, t5_generate, t5_greedy_decode,
 )

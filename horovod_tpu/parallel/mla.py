@@ -8,6 +8,11 @@ Queries and keys reach the heads through low-rank latents, each normed:
     [c_kv | k_pe] = x W_kva;  c_kv <- RMSNorm(c_kv)   (kv_lora_rank | rope)
     [k_nope_h | v_h] = c_kv W_kvb                  (per head: nope | v)
 
+With ``q_lora_rank=None`` the query has no latent: ``[q_nope_h | q_pe_h]
+= x W_q``, one column-parallel product (Kimi-Linear's form). With
+``rope=False`` nothing is rotated: ``q_pe_h`` and ``k_pe`` enter the
+scores as they are (no layer has positions).
+
 ``k_pe`` is ONE rotary key shared by every head. The rotation is RoPE on
 interleaved pairs ``(2j, 2j+1)``, by ``pos * theta^(-2j / rope_dim)``, of
 ``q_pe_h`` and ``k_pe`` alone (computed as rotate-half over the pairs
@@ -26,7 +31,8 @@ full-sequence path alone: with ``decode=True`` (the absorbed form and a
 latent cache are not built) or an ``sp_axis`` it raises.
 
 Scopes (``trace/scopes.py``): ``attn.q_latent`` (the query's down product,
-its norm, the up product and the rotation), ``attn.kv_latent`` (the key and
+its norm, the up product and the rotation; the one query product where
+there is no latent), ``attn.kv_latent`` (the key and
 value latent's, the shared rotary key, and ``k`` assembled), ``attn.core``
 (the kernels and the heads' merge) and ``attn.out``.
 """
@@ -56,10 +62,11 @@ def rope_pairs(x, positions, theta):
 
 class TPLatentAttention(nn.Module):
     """Latent attention (module docstring), causal over the full
-    sequence. ``rms_eps`` is the two latent norms' epsilon."""
+    sequence. ``rms_eps`` is the latent norms' epsilon; ``q_lora_rank``
+    None gives the query no latent, ``rope`` False rotates nothing."""
     num_heads: int
     hidden_size: int
-    q_lora_rank: int
+    q_lora_rank: Optional[int]
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -71,6 +78,7 @@ class TPLatentAttention(nn.Module):
     use_flash: bool = False
     sp_axis: Optional[str] = None
     decode: bool = False
+    rope: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -87,7 +95,7 @@ class TPLatentAttention(nn.Module):
                           self.v_head_dim)
         from horovod_tpu.metrics import instruments as hvd_metrics
         hvd_metrics.record_latent_attn_layer(
-            self.num_heads, nope + rope, dv, self.q_lora_rank,
+            self.num_heads, nope + rope, dv, self.q_lora_rank or 0,
             self.kv_lora_rank, rope)
         b, length = x.shape[0], x.shape[1]
         positions = jnp.arange(length, dtype=jnp.int32)
@@ -101,13 +109,18 @@ class TPLatentAttention(nn.Module):
                             name=name)
 
         with scope("attn.q_latent"):
-            c_q = norm("q_a_norm")(down(self.q_lora_rank, "q_a")(x))
+            if self.q_lora_rank is None:
+                c_q, name = x, "q"
+            else:
+                c_q = norm("q_a_norm")(down(self.q_lora_rank, "q_a")(x))
+                name = "q_b"
             q = ColumnParallelDense(
                 self.num_heads * (nope + rope), use_bias=False,
                 dtype=self.dtype, axis_name=self.axis_name,
-                name="q_b")(c_q).reshape(b, length, heads, nope + rope)
-            q = jnp.concatenate([q[..., :nope], rope_pairs(
-                q[..., nope:], positions, self.rope_theta)], -1)
+                name=name)(c_q).reshape(b, length, heads, nope + rope)
+            if self.rope:
+                q = jnp.concatenate([q[..., :nope], rope_pairs(
+                    q[..., nope:], positions, self.rope_theta)], -1)
         with scope("attn.kv_latent"):
             c_kv, k_pe = jnp.split(
                 down(self.kv_lora_rank + rope, "kv_a")(x),
@@ -117,8 +130,9 @@ class TPLatentAttention(nn.Module):
                 dtype=self.dtype, axis_name=self.axis_name,
                 name="kv_b")(norm("kv_a_norm")(c_kv)).reshape(
                     b, length, heads, nope + dv)
-            k_pe = rope_pairs(k_pe[:, :, None], positions,
-                              self.rope_theta)
+            k_pe = k_pe[:, :, None]
+            if self.rope:
+                k_pe = rope_pairs(k_pe, positions, self.rope_theta)
             k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
                 k_pe, (b, length, heads, rope))], -1)
             v = kv[..., nope:]

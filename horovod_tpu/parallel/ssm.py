@@ -156,10 +156,11 @@ def chunked_scan(x, dt, A, B, C, D, chunk):
 class CausalConv1d(nn.Module):
     """Depthwise convolution over the sequence axis of (b, L, C): position
     ``t`` reads ``t - taps + 1 .. t`` (zeros before a sequence's start),
-    tap ``taps - 1`` its own position; with a bias. A fresh kernel is
-    uniform in +-1 / sqrt(taps)."""
+    tap ``taps - 1`` its own position; with a bias unless ``use_bias`` is
+    False. A fresh kernel is uniform in +-1 / sqrt(taps)."""
     taps: int
     dtype: Any = jnp.float32
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -168,10 +169,12 @@ class CausalConv1d(nn.Module):
             "kernel", lambda key, shape: jax.random.uniform(
                 key, shape, jnp.float32, -bound, bound),
             (self.taps, channels))
-        bias = self.param("bias", nn.initializers.zeros, (channels,))
         length = x.shape[1]
         padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        out = jnp.asarray(bias, self.dtype)
+        out = 0
+        if self.use_bias:
+            out = jnp.asarray(self.param("bias", nn.initializers.zeros,
+                                         (channels,)), self.dtype)
         for k in range(self.taps):
             out = out + padded[:, k:k + length] \
                 * jnp.asarray(kernel[k], self.dtype)

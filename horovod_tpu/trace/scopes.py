@@ -117,6 +117,20 @@ SCOPES = (
     Scope("ssm.gate_norm", "leaf", "state_space",
           "the gated group RMS norm"),
     Scope("ssm.out_proj", "leaf", "state_space", "the output projection"),
+    # the Kimi Delta Attention mixer (parallel/kda.py)
+    Scope("kda.mixer", "container", "delta_rule", "one KDAMixer call"),
+    Scope("kda.in_proj", "leaf", "delta_rule",
+          "the fused q, k, v projection, beta's projection and sigmoid, "
+          "and the two low-rank gate paths"),
+    Scope("kda.conv", "leaf", "delta_rule",
+          "the causal convolution of q, k and v, SiLU, the split into "
+          "heads and the L2 norms"),
+    Scope("kda.core", "leaf", "delta_rule",
+          "the forget gate (softplus, decay) and kda_core, kernels or "
+          "chunked form"),
+    Scope("kda.gate_norm", "leaf", "delta_rule",
+          "the heads' RMS norm and the sigmoid output gate"),
+    Scope("kda.out_proj", "leaf", "delta_rule", "the output projection"),
     # the expert layer (parallel/moe.py DroplessMoE; the shared expert in
     # models/)
     Scope("moe.route", "leaf", "expert_layer",

@@ -243,6 +243,38 @@ def test_the_scans_three_kernels_compile_for_v5e(one_chip, monkeypatch):
             f"{kernel} is not in the compiled program"
 
 
+# -- the delta rule's kernels (Kimi Delta Attention) --------------------------
+
+def test_the_delta_rules_three_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """``ops.pallas.kda.kda`` and its backward pass at the call of
+    ``kimi_linear_ep32_8k_1chip`` (2 x 8192, 32 heads of 128, bfloat16)
+    lower and compile for a v5e in the kernels' chunks of 128: the
+    forward sweep, the backward pass's states sweep and the reverse sweep
+    with the chunk's VJP inside it, one kernel each. A slice off the (8,
+    128) tiling, an op Mosaic cannot lower or too much scoped VMEM fails
+    here and not on the chip."""
+    from horovod_tpu.ops.pallas import kda as kernels
+    from horovod_tpu.parallel import kda
+    monkeypatch.setattr(kernels, "_interpret", lambda: False)
+    b, length, heads, d = 2, 8192, 32, 128
+    chunk = kernels.CHUNK
+    assert kda.kda_path((b, length, heads, d), 2) == (1, chunk)
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def forward_and_backward(do, *operands):
+        o, pull = jax.vjp(lambda *a: kernels.kda(*a, chunk), *operands)
+        return o, pull(do)
+    x = shape((b, length, heads, d))
+    hlo = jax.jit(forward_and_backward).lower(
+        x, x, x, x, shape((b, length, heads, d), jnp.float32),
+        shape((b, length, heads), jnp.float32)).compile().as_text()
+    for kernel in ("hvd_kda_fwd", "hvd_kda_states", "hvd_kda_bwd"):
+        assert f"{kernel}_{chunk}x128" in hlo, \
+            f"{kernel} is not in the compiled program"
+
+
 # -- attention's prologue pass (PR 38) ----------------------------------------
 
 # (batch, length, heads, kv heads, normed, window or None for no rotation):
